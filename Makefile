@@ -6,7 +6,7 @@
 
 PY ?= python
 
-.PHONY: test test-paranoia test-shard22 test-matrix analyze typecheck bench perfsnapshot measure measure-resize measure-spmd validate-tpu soak soak-spmd check doccheck doccheck-fill native clean
+.PHONY: test test-paranoia test-shard22 test-matrix analyze typecheck bench perfsnapshot measure measure-resize measure-spmd validate-tpu chip-smoke soak soak-spmd check doccheck doccheck-fill native clean
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -22,15 +22,15 @@ analyze:
 typecheck:
 	$(PY) tools/typecheck.py
 
-native:  # pre-build all four C++ fast paths (they also self-build lazily)
-	$(PY) -c "from pilosa_tpu.ops import hostkernels as hk; \
-	from pilosa_tpu.storage import roaring; \
-	from pilosa_tpu.pql import native as pqlnative; \
-	from pilosa_tpu import csvload; \
-	print('bitcount:', hk.native_available()); \
-	print('roaring :', roaring.native_available()); \
-	print('pql     :', pqlnative.available()); \
-	print('csv     :', csvload.available())"
+# build all four C++ fast paths from the committed .cpp files (they
+# also self-build lazily); a build/ left by another image is dropped
+# first, and a library that does not build fails the target
+native:
+	rm -rf pilosa_tpu/native/build
+	$(PY) -c "import sys; from pilosa_tpu import native_loader; \
+	st = native_loader.status(); \
+	[print(f'{n:<14}', 'native' if v['loaded'] else 'FAILED: ' + str(v['error'])) for n, v in st.items()]; \
+	sys.exit(0 if all(v['loaded'] for v in st.values()) else 1)"
 
 # sanitizer tier: every fragment mutation re-validates invariants
 test-paranoia:
@@ -55,7 +55,7 @@ doccheck-fill:
 bench:
 	$(PY) bench.py
 
-# dated chip capture with measured per-engine bw_util (perfobs), plus
+# dated capture (chiprun_out/) with measured per-engine bw_util (perfobs), plus
 # a full metric-family sweep against a throwaway live server (usage:
 # make perfsnapshot CAPTURE_ARGS="--profile --compare BENCH_r10.json")
 perfsnapshot:
@@ -68,7 +68,7 @@ perfsnapshot:
 	cm.check_families(t, cm.ALL_FAMILIES); s.close(); \
 	print('metric families: ok')"
 
-# all BASELINE.md configs, one JSON line each
+# all BASELINE.json configs, one JSON line each
 measure:
 	$(PY) benchmarks/measure.py
 
@@ -82,9 +82,13 @@ MEASURE_PROCS ?= 2
 measure-spmd:
 	$(PY) benchmarks/measure_spmd.py --procs $(MEASURE_PROCS)
 
-# on-chip Pallas validation (no-op skip without a TPU)
+# on-chip Pallas validation (fails without a TPU)
+# chip-smoke: the whole served path on the chip, validator included
 validate-tpu:
 	$(PY) benchmarks/validate_tpu.py
+
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # long randomized differential soak (usage: make soak SOAK_SECONDS=1500)
 SOAK_SECONDS ?= 300

@@ -10,11 +10,7 @@ directly — the analog of the reference's functional ServerOptions
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: same API under the old name
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field, fields
 
 

@@ -16,7 +16,7 @@ map-reduce fan-out):
   the jitted callable's ``_cache_size``, falling back to first-seen
   shape keys on jax versions without it) and times the first-lowering
   call, keyed per (kernel, canonical operand shape).
-- **Host→device transfer bursts** — ``ops/bitmap.chunked_device_put``
+- **Host→device transfer bursts** — ``ops/bitmap.device_put``
   (the one staging funnel for fragment matrices, BSI planes, and field
   row stacks) reports bytes/chunks per labeled owner through
   :func:`note_transfer`.
@@ -143,32 +143,33 @@ class DeviceObserver:
 
     @staticmethod
     def device_memory() -> list[dict]:
-        """Per-device memory stats where the backend reports them (TPU
-        does; CPU returns none — the entry still lists the device so
-        the operator sees the topology)."""
-        out = []
-        try:
-            import jax
+        """Per-device identity plus memory stats where the backend
+        reports them (TPU does; CPU returns none — the entry still
+        lists the device so the operator sees the topology).  A backend
+        that fails to enumerate raises: a device page that quietly
+        lists nothing would hide a dead accelerator."""
+        import jax
 
-            for d in jax.devices():
-                entry: dict = {"id": d.id, "platform": d.platform}
-                try:
-                    ms = d.memory_stats()
-                except Exception:  # noqa: BLE001
-                    ms = None
-                if ms:
-                    entry["bytesInUse"] = ms.get("bytes_in_use")
-                    entry["bytesLimit"] = ms.get("bytes_limit")
-                    entry["peakBytesInUse"] = ms.get("peak_bytes_in_use")
-                out.append(entry)
-        except Exception:  # noqa: BLE001 — backend init failure ≠ 500
-            pass
+        out = []
+        for d in jax.devices():
+            entry: dict = {"id": d.id, "platform": d.platform,
+                           "kind": d.device_kind}
+            ms = d.memory_stats()
+            if ms:
+                entry["bytesInUse"] = ms.get("bytes_in_use")
+                entry["bytesLimit"] = ms.get("bytes_limit")
+                entry["peakBytesInUse"] = ms.get("peak_bytes_in_use")
+            out.append(entry)
         return out
 
     def snapshot(self) -> dict:
-        """The /debug/devices document: per-kernel/per-shape compiles,
-        per-label transfers, residency accounting, device memory."""
+        """The /debug/devices document: which backend this process is
+        on (and whether it computes on the host instead), which native
+        libraries loaded, per-kernel/per-shape compiles, per-label
+        transfers, residency accounting, device memory."""
+        from pilosa_tpu import native_loader
         from pilosa_tpu.runtime import residency
+        from pilosa_tpu.runtime.startup import backend_info
 
         with self._lock:
             kernels = {}
@@ -206,6 +207,8 @@ class DeviceObserver:
                 },
                 "oomRetries": self.oom_retries,
             }
+        out["backend"] = backend_info()
+        out["native"] = native_loader.status()
         out["residency"] = residency.manager().stats()
         # tiered residency: the promotion pool's live state joins the
         # manager's tier split (/debug/devices answers "is the working

@@ -567,7 +567,7 @@ class Field:
                 devobs.note_transfer(stack.nbytes, len(local),
                                      "field.shard_stack")
                 return pmesh.shard_stack(pmesh.local_device_mesh(), stack)
-            return bm.chunked_device_put(stack, local[0],
+            return bm.device_put(stack, local[0],
                                          label="field.stack")
         from pilosa_tpu.parallel import meshexec
 
@@ -1052,14 +1052,14 @@ class Field:
         if bm.host_mode():
             return np.ascontiguousarray(pool)
         if jax.process_count() > 1:
-            return bm.chunked_device_put(pool, jax.local_devices()[0],
+            return bm.device_put(pool, jax.local_devices()[0],
                                          label="field.containers")
         from pilosa_tpu.parallel import meshexec
 
         if meshexec.active():
             return meshexec.place_replicated(pool,
                                              label="field.containers")
-        return bm.chunked_device_put(pool, label="field.containers")
+        return bm.device_put(pool, label="field.containers")
 
     def flush_deltas(self, shards=None) -> int:
         """Merge every pending delta of this field's fragments into

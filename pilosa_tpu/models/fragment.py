@@ -95,7 +95,7 @@ def _plane_promote(gen: int):
 
     def promote(P: np.ndarray):
         dev = (P if bm.host_mode()
-               else bm.chunked_device_put(P, label="fragment.planes"))
+               else bm.device_put(P, label="fragment.planes"))
         return (gen, dev)
 
     return promote
@@ -1298,7 +1298,7 @@ class Fragment:
 
             dev = (np.ascontiguousarray(matrix) if bm.host_mode()
                    # pilosa-lint: allow(blocking-under-lock) -- upload under the fragment lock is the residency design: it serializes per-fragment uploads so one generation uploads once; nothing re-enters
-                   else bm.chunked_device_put(matrix,
+                   else bm.device_put(matrix,
                                               label="fragment.matrix"))
             self._device_cache[key] = (self._gen, ids, dev)
             residency.manager().admit(self._device_cache, key,
@@ -1432,7 +1432,7 @@ class Fragment:
 
             dev = (P if bm.host_mode()
                    # pilosa-lint: allow(blocking-under-lock) -- same residency design as device_matrix: per-fragment upload serialization under the owning lock
-                   else bm.chunked_device_put(P, label="fragment.planes"))
+                   else bm.device_put(P, label="fragment.planes"))
             self._device_cache[key] = (self._gen, dev)
             residency.manager().admit(
                 self._device_cache, key, P.nbytes, token=self._gen,

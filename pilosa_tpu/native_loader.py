@@ -5,13 +5,16 @@ dance used by every native module (roaring codec, libpql), including
 stale-binary recovery: if the on-disk .so fails to dlopen (foreign ABI,
 torn write), it is rebuilt once from source and retried.  Build failures
 latch — callers fall back to their Python implementations for the rest
-of the process."""
+of the process — but never silently: the failure is printed once on
+stderr and kept for ``status()`` (the ``native`` section of
+``/debug/devices``)."""
 
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 
@@ -25,6 +28,9 @@ class NativeLib:
         self.so = so
         self.setup = setup
         self.extra_flags = tuple(extra_flags)
+        self.name = os.path.splitext(os.path.basename(src))[0]
+        #: why the library is not native (None while it is, or untried)
+        self.error: str | None = None
         self._lib = None
         self._failed = False
         self._lock = threading.Lock()
@@ -64,10 +70,34 @@ class NativeLib:
                     lib = ctypes.CDLL(self.so)
                 self.setup(lib)
                 self._lib = lib
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — any build/load/ABI
+                # failure means the Python implementation serves
                 self._failed = True
                 self._lib = None
+                detail = getattr(e, "stderr", None)
+                if isinstance(detail, bytes):
+                    detail = detail.decode(errors="replace")
+                self.error = (f"{type(e).__name__}: {e}"
+                              + (f": {detail.strip()[-400:]}"
+                                 if detail else ""))
+                print(f"native: {self.name} did not build/load "
+                      f"({self.error}); the Python implementation "
+                      "serves for the life of this process",
+                      file=sys.stderr)
             return self._lib
 
     def available(self) -> bool:
         return self.load() is not None
+
+
+def status() -> dict[str, dict]:
+    """Load the four native libraries and say which are native:
+    ``{name: {"loaded": bool, "error": str | None}}``."""
+    from pilosa_tpu import csvload
+    from pilosa_tpu.ops import hostkernels
+    from pilosa_tpu.pql import native as pqlnative
+    from pilosa_tpu.storage import roaring
+
+    return {lib.name: {"loaded": lib.available(), "error": lib.error}
+            for lib in (hostkernels._NATIVE, roaring._NATIVE,
+                        pqlnative._NATIVE, csvload._NATIVE)}

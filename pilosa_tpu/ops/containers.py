@@ -2,7 +2,7 @@
 
 Fragments have lived on device as fully dense bit planes, so a sparse
 row spends ~all of its HBM traffic reading zero words and HBM capacity
-caps the column count per chip (BENCH_r05: bw_util 0.148).  The
+caps the column count per chip.  The
 reference's entire performance story is container specialization
 (Chambi et al., "Better bitmap performance with Roaring bitmaps";
 Lemire et al., "Consistently faster and smaller compressed bitmaps
@@ -54,6 +54,7 @@ from typing import Any
 
 import numpy as np
 
+from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
 
 #: Container geometry: 2^16 bits = 1024 uint64 = 2048 uint32 words —
@@ -758,6 +759,11 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
             leaves.append(pair[1])
             nodemap[i] = ("dfuse", ("leaf", bi), ("leaf", si),
                           ("leaf", ci))
+            # same flight-record note as the dense delta fuse
+            # (executor._fused_row_leaf): this read met a pending delta
+            rec = _observe.current()
+            if rec is not None:
+                rec.note_delta(1)
 
     def subst(node: tuple) -> tuple:
         if node[0] == "leaf":

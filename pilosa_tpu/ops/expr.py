@@ -302,7 +302,7 @@ def _build_mesh_program(meshkey: tuple, counts: bool) -> Callable[..., Any]:
         return out
 
     sm = shard_map(body, mesh=mesh, in_specs=(leaf_spec,) * n_leaves,
-                   out_specs=out_spec, check_rep=False)
+                   out_specs=out_spec, check_vma=False)
 
     def run(*leaves: Any) -> Any:
         return sm(*leaves)
@@ -355,7 +355,7 @@ def _build_mesh_gather_program(meshkey: tuple,
     sm = shard_map(body, mesh=mesh,
                    in_specs=(pool_spec,) * n_leaves
                    + (idx_spec,) * n_leaves,
-                   out_specs=out_spec, check_rep=False)
+                   out_specs=out_spec, check_vma=False)
 
     def run(*args: Any) -> Any:
         return sm(*args)

@@ -5,10 +5,9 @@ The fused compiler (ops/expr.py) erases leaf VALUES from a tree, so
 concurrent queries with the same STRUCTURE share one compiled program
 and one launch (parallel/coalescer.py).  Real mixed dashboard traffic
 is structurally diverse, though — many users, many distinct
-Count/Row trees — and BENCH_r05 shows the read path is
-dispatch-bound (1801 qps XLA against a ~20 us trivial-dispatch floor,
-bw_util 0.148), so each distinct shape paying its own launch is the
-single biggest qps-per-chip loss on diverse traffic (ROADMAP item 1).
+Count/Row trees — and every launch pays a fixed dispatch cost, so
+each distinct shape paying its own launch loses qps per chip on
+diverse traffic.
 
 This module erases the STRUCTURE too.  Each tree compiles to a flat
 op-tape — an opcode stream over a register file, leaves pre-loaded
@@ -213,6 +212,13 @@ _lowered: set[tuple] = set()
 #: (B, tape_len, slots, domain) combos the bitmap VM has lowered —
 #: the /debug/ragged "vm" program inventory.
 _vm_lowered: set[tuple] = set()
+#: What the open-time warm-up did — the /debug/ragged "prewarm"
+#: section.  ``state`` is idle | running | done | failed; a failure
+#: keeps its error here (and in the server log) instead of vanishing
+#: into a "skipped" line: on an accelerator a program that does not
+#: compile is a finding, not a detail.
+_prewarm_state: dict[str, Any] = {"state": "idle", "warmed": 0,
+                                  "skipped": [], "error": None}
 
 
 def bump(name: str, value: int = 1) -> None:
@@ -233,6 +239,8 @@ def reset_counters() -> None:
             _counters[k] = 0
         _lowered.clear()
         _vm_lowered.clear()
+        _prewarm_state.update(state="idle", warmed=0, skipped=[],
+                              error=None)
 
 
 def publish_gauges(stats: Any) -> None:
@@ -257,6 +265,8 @@ def debug() -> dict[str, Any]:
         reasons = {k.split(".", 2)[2]: v for k, v in _counters.items()
                    if k.startswith("vm.fallbacks.")}
         return {"counters": dict(_counters), "programs": progs,
+                "prewarm": dict(_prewarm_state,
+                                skipped=list(_prewarm_state["skipped"])),
                 "vm": {"programs": vm_progs,
                        "fallbackReasons": reasons}}
 
@@ -370,7 +380,7 @@ def _mesh_program(counts: bool, mesh: Any) -> Callable[..., Any]:
     sm = shard_map(body, mesh=mesh, in_specs=(P(), leaf_spec),
                    out_specs=(P() if counts
                               else P(None, meshexec.SHARD_AXIS, None)),
-                   check_rep=False)
+                   check_vma=False)
 
     def run(tapes: Any, leaves: Any) -> Any:
         return sm(tapes, leaves)
@@ -620,9 +630,19 @@ def _prewarm_worthwhile() -> bool:
     return jax.devices()[0].platform != "cpu"
 
 
+def note_prewarm(state: str, error: str | None = None) -> None:
+    """Record the open-time warm-up's progress (server/server.py);
+    ``running`` starts a fresh account."""
+    with _lock:
+        if state == "running":
+            _prewarm_state.update(warmed=0, skipped=[])
+        _prewarm_state.update(state=state, error=error)
+
+
 def prewarm(stack_shape: tuple[int, ...], max_batch: int,
             max_tape: int, max_leaves: int,
-            counts: bool = True, mesh: Any = None) -> int:
+            counts: bool = True, mesh: Any = None,
+            budget_bytes: int | None = None) -> int:
     """Lower the bucket programs a serving process will hit first.
     Flushes pad the BATCH axis to pow2(occupancy), so a window
     sealing at 5 queries dispatches a b=8 program — warming only the
@@ -640,10 +660,23 @@ def prewarm(stack_shape: tuple[int, ...], max_batch: int,
     lowers mesh-shaped programs and an N-device mesh never wastes its
     warm-up on programs serving traffic won't run.  ``stack_shape``
     must carry the same device-count-derived padding serving stacks
-    get (models/field._padded_rows).  Called from server open on a
-    background thread; best-effort, and a no-op where lowering is
-    cheap (``_prewarm_worthwhile``).  Returns the number of programs
-    warmed."""
+    get (models/field._padded_rows).
+
+    Warming RUNS each program on zero stacks, so a job holds its
+    operands plus the interpreter's register file in device memory:
+    ``b x (slots + 2 x (slots + tape_len))`` stacks (the scan carries
+    the register file in and out; XLA's own memory analysis of the
+    small class comes to 16 stacks per batch row, so the bound errs on
+    the safe side).  At 256 shards a stack is 32 MiB and the b=32
+    small-class job alone is 20 GiB, so a job larger than
+    ``budget_bytes`` (the device memory the residency budget leaves
+    free — server open passes it) is skipped and listed in the
+    /debug/ragged prewarm section: a batch that cannot be warmed there
+    could not be served there either.
+
+    Called from server open on a background thread; a no-op where
+    lowering is cheap (``_prewarm_worthwhile``).  Returns the number of
+    programs warmed."""
     import jax
 
     if not _prewarm_worthwhile():
@@ -668,7 +701,15 @@ def prewarm(stack_shape: tuple[int, ...], max_batch: int,
     if large != small:
         jobs.append((b_full,) + large)
     warmed = 0
+    stack_bytes = 4 * int(np.prod(stack_shape))
     for b, tape_len, slots in jobs:
+        need = b * (slots + 2 * (slots + tape_len)) * stack_bytes
+        if budget_bytes is not None and need > budget_bytes:
+            with _lock:
+                _prewarm_state["skipped"].append(
+                    {"batch": b, "tapeLen": tape_len, "slots": slots,
+                     "needBytes": need, "budgetBytes": budget_bytes})
+            continue
         tape_rows = np.zeros((b, tape_len, 3), dtype=np.int32)
         tape_rows[:, :, 0] = OP_COPY
         leaves = jnp.zeros((b, slots) + tuple(stack_shape),
@@ -692,5 +733,7 @@ def prewarm(stack_shape: tuple[int, ...], max_batch: int,
             _lowered.add((counts, b, tape_len, slots)
                          + tuple(stack_shape))
         warmed += 1
+        with _lock:
+            _prewarm_state["warmed"] = warmed
     bump("tape.prewarmed", warmed)
     return warmed

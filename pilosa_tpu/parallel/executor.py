@@ -39,6 +39,8 @@ from pilosa_tpu.parallel.cluster import (
     UNOWNED_MARKER,
     ShedByPeerError,
     TransportError,
+    converge_owner_deliveries,
+    refusal_is_unowned,
 )
 from pilosa_tpu.models.timequantum import parse_time
 from pilosa_tpu.models.view import VIEW_STANDARD
@@ -2648,8 +2650,6 @@ class Executor:
         broadcast, re-resolve the owner set, and retry the refused
         deliveries within the PILOSA_TPU_WRITE_RETRY_S budget."""
         from pilosa_tpu.parallel import hints as _hints
-        from pilosa_tpu.parallel.cluster import (
-            converge_owner_deliveries, refusal_is_unowned)
 
         available = (_hints.config().write_policy
                      == _hints.WRITE_POLICY_AVAILABLE)

@@ -303,7 +303,7 @@ def place_stack(stack: np.ndarray, label: str = "field.stack",
     if m is None:
         from pilosa_tpu.ops import bitmap as bm
 
-        return bm.chunked_device_put(stack, label=label)
+        return bm.device_put(stack, label=label)
     from pilosa_tpu import devobs
 
     devobs.note_transfer(stack.nbytes, m.size, mesh_label)
@@ -323,7 +323,7 @@ def place_replicated(arr, mesh=None, label: str = "field.containers"):
     if m is None:
         from pilosa_tpu.ops import bitmap as bm
 
-        return bm.chunked_device_put(arr, label=label)
+        return bm.device_put(arr, label=label)
     from pilosa_tpu import devobs
 
     devobs.note_transfer(arr.nbytes * m.size, m.size, label)

@@ -79,16 +79,15 @@ ALPHA = 0.2
 _clock = time.perf_counter_ns
 
 #: HBM roof (GB/s) per jax ``device_kind`` substring, checked in
-#: order — datasheet ballparks, good enough for a utilization ratio
-#: (an operator with exact numbers sets ``[observe] device-peak-gbps``).
-#: The CPU entry is a host-DDR ballpark so the CPU twin's bw_util stays
-#: a meaningful fraction instead of a lie against an HBM roof.
+#: order — datasheet figures (an operator with exact numbers sets
+#: ``[observe] device-peak-gbps``).  A kind that is not listed — a CPU
+#: host included — has NO roof here: utilization is then reported as
+#: absent (``None``), never against an assumed peak.
 KIND_PEAKS: tuple[tuple[str, float], ...] = (
     ("v5e", 819.0), ("v5 lite", 819.0), ("v5p", 2765.0),
     ("v6", 1640.0), ("v5", 2765.0), ("v4", 1228.0), ("v3", 900.0),
-    ("v2", 700.0), ("cpu", 100.0),
+    ("v2", 700.0),
 )
-DEFAULT_PEAK_GBPS = 819.0  # the committed capture's roof (ROADMAP 1)
 
 
 # ---------------------------------------------------------------- runtime cfg
@@ -188,39 +187,38 @@ def reset() -> None:
             _counters[k] = 0
 
 
+#: 0.0 caches "this device kind has no known roof"
 _peak_cached: float | None = None
 
 
-def device_peak_gbps() -> float:
-    """The configured bandwidth roof, or the per-device-kind default —
-    cached until the next configure/reset (jax device lookup is not
-    free and this is read per sample)."""
+def device_peak_gbps() -> float | None:
+    """The configured bandwidth roof, else the ``KIND_PEAKS`` entry for
+    this process's ``device_kind``, else ``None`` (no roof known: no
+    utilization figure) — cached until the next configure/reset (jax
+    device lookup is not free and this is read per sample)."""
     global _peak_cached
     p = _peak_cached
     if p is not None:
-        return p
+        return p or None
     with _cfg_lock:
         explicit = _cfg.peak_gbps
     if explicit > 0:
         _peak_cached = explicit
         return explicit
-    kind = ""
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-        if devs:
-            kind = (devs[0].device_kind or devs[0].platform or "")
-    except Exception:  # noqa: BLE001 — no backend ≠ no observatory
-        pass
-    kind = kind.lower()
-    peak = DEFAULT_PEAK_GBPS
+    kind = (jax.devices()[0].device_kind or "").lower()
+    peak = 0.0
     for sub, gbps in KIND_PEAKS:
         if sub in kind:
             peak = gbps
             break
     _peak_cached = peak
-    return peak
+    return peak or None
+
+
+def _bw_util(gbps: float, peak: float | None) -> float | None:
+    return round(gbps / peak, 4) if peak else None
 
 
 # ------------------------------------------------------------------- counters
@@ -267,12 +265,14 @@ def publish_gauges(stats: Any) -> None:
         stats.gauge(name, value)
     stats.gauge("cost.cells", cells)
     stats.gauge("cost.shadow", 1 if config().shadow else 0)
-    stats.gauge("engine.peak_gbps", device_peak_gbps())
+    # 0 = no roof known for this device kind (then no bw_util either)
+    stats.gauge("engine.peak_gbps", device_peak_gbps() or 0.0)
     for eng, s in engine_summary().items():
         tagged = stats.with_tags(f"engine:{eng}")
         tagged.gauge("engine.wall_us", s["wallUs"])
         tagged.gauge("engine.gbps", s["gbps"])
-        tagged.gauge("engine.bw_util", s["bwUtil"])
+        if s["bwUtil"] is not None:
+            tagged.gauge("engine.bw_util", s["bwUtil"])
 
 
 # ----------------------------------------------------------------- cost table
@@ -496,7 +496,7 @@ def engine_summary() -> dict[str, dict]:
         agg["wallUs"] = round(agg.pop("_us") / n, 3)
         agg["bytes"] = int(agg.pop("_bytes") / n)
         agg["gbps"] = round(gbps, 3)
-        agg["bwUtil"] = round(gbps / peak, 4) if peak > 0 else 0.0
+        agg["bwUtil"] = _bw_util(gbps, peak)
     return out
 
 
@@ -511,8 +511,7 @@ def cost_debug() -> dict:
              "samples": c.count, "wallUs": round(c.ewma_us, 3),
              "devUs": round(c.dev_us, 3),
              "bytes": int(c.ewma_bytes), "gbps": round(c.ewma_gbps, 3),
-             "bwUtil": (round(c.ewma_gbps / peak, 4)
-                        if peak > 0 else 0.0),
+             "bwUtil": _bw_util(c.ewma_gbps, peak),
              "lastUs": round(c.last_us, 3)}
             for (eng, size, sp), c in sorted(_table.items())
         ]

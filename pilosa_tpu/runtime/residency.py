@@ -85,19 +85,25 @@ def _default_budget() -> int:
     env = os.environ.get("PILOSA_TPU_DEVICE_BUDGET_BYTES")
     if env:
         return int(env)
-    # Probe the backend for real memory limits (works on TPU); fall
-    # back to a conservative figure that keeps CPU test runs light.
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            # leave headroom for executables, collectives and live
-            # intermediates; caches may take at most 60%
-            return int(stats["bytes_limit"] * 0.6) * len(jax.devices())
-    except Exception:
-        pass
-    return 2 << 30
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        # no HBM to size against: a conservative figure that keeps CPU
+        # hosts and test runs light
+        return 2 << 30
+    # An accelerator that cannot report its memory limit is an error,
+    # not a 2 GiB chip: a silent default would evict a working set the
+    # device could hold.
+    stats = devs[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{devs[0].platform} device {devs[0].device_kind!r} reports no "
+            "memory limit (memory_stats() has no bytes_limit); set "
+            "PILOSA_TPU_DEVICE_BUDGET_BYTES to size the residency budget")
+    # leave headroom for executables, collectives and live
+    # intermediates; caches may take at most 60%
+    return int(stats["bytes_limit"] * 0.6) * len(devs)
 
 
 #: The HBM budget never feedback-shrinks below this floor — a storm of

@@ -651,10 +651,15 @@ class Handler:
 
     @route("GET", "/status")
     def handle_status(self, req, params, path, body):
+        from pilosa_tpu.runtime.startup import backend_info
+
         self._json(req, {
             "state": self.api.state(),
             "nodes": self.api.hosts(),
             "localID": self.api.cluster.local_id,
+            # which engine answers here: an accelerator, or the numpy
+            # host engine of a one-CPU-device process
+            "backend": backend_info(),
         })
 
     @route("GET", "/hosts")
@@ -1449,7 +1454,9 @@ class Handler:
 
     @route("GET", "/debug/devices")
     def handle_debug_devices(self, req, params, path, body):
-        """Device-runtime telemetry (pilosa_tpu.devobs): per-kernel /
+        """Device-runtime telemetry (pilosa_tpu.devobs): the backend
+        this process is on (platform, device kind and count, host mode
+        or device), which native libraries loaded, per-kernel /
         per-canonical-shape XLA compile counts and wall times,
         host→device transfer bytes and chunk counts by owner,
         residency usage/budget/evictions/high-water, and per-device

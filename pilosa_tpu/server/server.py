@@ -581,8 +581,8 @@ class Server:
         self.hint_replayer.start()
         if self._ragged_prewarm:
             # lower the ragged bucket interpreter programs off the
-            # serving path ([ragged] prewarm): best-effort, background,
-            # a no-op in host mode or with the coalescer/ragged off
+            # serving path ([ragged] prewarm): background, a no-op in
+            # host mode or with the coalescer/ragged off
             t = threading.Thread(target=self._prewarm_ragged,
                                  daemon=True, name="ragged-prewarm")
             t.start()
@@ -594,10 +594,15 @@ class Server:
 
         co = self.node.executor.coalescer
         if co is None or not (co.enabled and co.ragged) or bm.host_mode():
+            _tape.note_prewarm("done")
             return
+        _tape.note_prewarm("running")
         try:
+            import jax
+
             from pilosa_tpu.models.field import _padded_rows
             from pilosa_tpu.parallel import meshexec
+            from pilosa_tpu.runtime import residency as _residency
 
             # the leaf stack shape every fused read stages: the widest
             # index's shard fan-out, padded exactly as serving stacks
@@ -608,20 +613,33 @@ class Server:
             # active mesh, single-device ones otherwise — a 1-device
             # process never lowers mesh-shaped programs and an
             # N-device mesh never wastes the warm-up on single-device
-            # ones.  An empty holder warms a nominal 1-shard stack —
-            # the program structure still lowers; a different shard
-            # count later re-specializes only the cheap outer shapes.
+            # ones.  An empty holder warms a nominal 1-shard stack.
             n_shards = max(
                 [len(idx.available_shards())
                  for idx in self.holder.indexes.values()] or [1])
             stack = (_padded_rows(max(1, n_shards)),
                      bm.n_words(SHARD_WIDTH))
+            # warm-up jobs RUN on zero stacks: bound them by the device
+            # memory the residency budget leaves free (CPU backends,
+            # which report no limit, never warm — _prewarm_worthwhile)
+            devs = jax.local_devices()
+            ms = devs[0].memory_stats() or {}
+            free = None
+            if "bytes_limit" in ms:
+                free = max(0, ms["bytes_limit"] * len(devs)
+                           - _residency.manager().budget)
             _tape.prewarm(stack, co.max_batch, co.max_tape,
                           co.max_leaves,
-                          mesh=meshexec.active_mesh())
-        except Exception as e:  # noqa: BLE001 — prewarm must never
-            # break serving; the first ragged window pays the compile
-            self.logger.printf("ragged prewarm skipped: %r", e)
+                          mesh=meshexec.active_mesh(),
+                          budget_bytes=free)
+            _tape.note_prewarm("done")
+        except Exception as e:  # noqa: BLE001 — a warm-up failure must
+            # not stop the server (the first ragged window pays the
+            # compile, or fails the same way where it can be seen), but
+            # it is never a quiet "skipped": /debug/ragged carries it
+            _tape.note_prewarm("failed", f"{type(e).__name__}: {e}")
+            self.logger.printf("ragged prewarm FAILED: %r", e)
+
     def _join_via_seeds(self) -> None:
         client = self._client
         me = self.cluster.local_node.to_dict()

@@ -286,7 +286,7 @@ class TestBlockingUnderLock:
             class Holder:
                 def upload(self, m):
                     with self._lock:
-                        return bm.chunked_device_put(m)
+                        return bm.device_put(m)
         """, "models/holder.py", self.PASSES)
         assert _active(findings, "blocking-under-lock")
 

@@ -188,22 +188,20 @@ class TestCompileAttribution:
 
 
 class TestTransferMetering:
-    def test_chunked_put_reports_bytes_and_chunks(self, monkeypatch):
+    def test_put_reports_bytes_under_its_label(self):
         obs = devobs.reset()
-        monkeypatch.setenv("PILOSA_TPU_STAGE_CHUNK_MB", "0.01")
         stack = np.random.randint(
             0, 2**32, size=(64, 256), dtype=np.uint64).astype(np.uint32)
-        dev = bm.chunked_device_put(stack, label="test.stack")
+        dev = bm.device_put(stack, label="test.stack")
         assert np.array_equal(np.asarray(dev), stack)
         snap = obs.snapshot()["transfer"]
         assert snap["bytes"] == stack.nbytes
-        assert snap["chunks"] > 1  # 64 KiB stack in 10 KB chunks
         assert snap["byLabel"]["test.stack"]["puts"] == 1
 
-    def test_unchunked_put_counts_one_chunk(self):
+    def test_unlabelled_put_counts_one_chunk(self):
         obs = devobs.reset()
         stack = np.zeros((4, 8), dtype=np.uint32)
-        bm.chunked_device_put(stack)
+        bm.device_put(stack)
         snap = obs.snapshot()["transfer"]
         assert snap["chunks"] == 1
         assert "other" in snap["byLabel"]

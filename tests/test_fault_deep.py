@@ -453,6 +453,59 @@ class TestStaleViewImport:
         assert not refusal_is_unowned(TransportError("connection refused"))
 
 
+class TestRemoteSubQueryErrors:
+    """What a remote sub-query's failure becomes at the origin
+    (executor._map_shards' fan-in): a transport error fails over, an
+    unowned-shard refusal fails over without feeding the breaker, and
+    anything else — a device error on a peer, say — is the query's own
+    error.  The third branch read ``refusal_is_unowned`` from a name
+    only imported inside another method, so it raised NameError."""
+
+    @staticmethod
+    def _fail_remotes(transport, exc_for):
+        real = transport.query_node
+        hit = []
+
+        def query_node(node, index, pql, shards, **kw):
+            exc = exc_for(node, shards)
+            if exc is not None:
+                hit.append(node.id)
+                raise exc
+            return real(node, index, pql, shards, **kw)
+
+        transport.query_node = query_node
+        return hit
+
+    def test_peer_device_error_surfaces_as_itself(self, tmp_path):
+        transport, nodes = make_cluster(tmp_path, n=3, replica_n=2)
+        _seed(nodes[0])
+        hit = self._fail_remotes(
+            transport,
+            lambda node, shards: RuntimeError(
+                "RESOURCE_EXHAUSTED: out of memory on the peer's device")
+            if node.id != "node0" else None)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            nodes[0].executor.execute("i", "Count(Row(f=1))")
+        assert hit, "no sub-query went remote: the test reached nothing"
+
+    def test_unowned_refusal_fails_over_to_another_replica(self, tmp_path):
+        from pilosa_tpu.parallel.executor import UnownedShardError
+
+        transport, nodes = make_cluster(tmp_path, n=3, replica_n=2)
+        cols = _seed(nodes[0])
+        hit = self._fail_remotes(
+            transport,
+            lambda node, shards: UnownedShardError(shards[0])
+            if node.id == "node1" else None)
+        # every shard has two owners: the ones node1 refuses are read
+        # from their other replica, and the count stays exact
+        assert nodes[0].executor.execute("i", "Count(Row(f=1))") == \
+            [len(cols)]
+        assert hit == ["node1"] * len(hit) and hit
+        # a refusal is proof of life, not a transport failure
+        assert nodes[0].cluster.peer_allows("node1")
+
+
 class TestGrayFailure:
     """Slow-but-alive node (gray failure): no TransportError fires, so
     nothing fails over — correctness must come from the write path

@@ -306,6 +306,31 @@ class TestTapeMesh:
         assert all(isinstance(k, bool) for k in tape._programs)
         tape._programs.clear()
 
+    def test_prewarm_skips_jobs_over_the_memory_budget(self, monkeypatch):
+        """Warming RUNS each program on zero stacks: a job whose
+        operands + register file exceed the device memory the caller
+        leaves free is skipped and listed, not attempted (at 256
+        shards the b=32 job alone is tens of GiB)."""
+        monkeypatch.setattr(tape, "_prewarm_worthwhile", lambda: True)
+        tape._programs.clear()
+        tape.reset_counters()
+        stack = (8, 64)
+        stack_bytes = 8 * 64 * 4
+        # room for the b=2 small-class job only: 2 x (4 + 2 x 8) stacks
+        budget = 2 * (4 + 2 * (4 + 4)) * stack_bytes
+        n = tape.prewarm(stack, max_batch=8, max_tape=4, max_leaves=4,
+                         mesh=None, budget_bytes=budget)
+        assert n == 1
+        pw = tape.debug()["prewarm"]
+        assert pw["warmed"] == 1
+        assert [j["batch"] for j in pw["skipped"]] == [4, 8]
+        assert all(j["needBytes"] > j["budgetBytes"] == budget
+                   for j in pw["skipped"])
+        tape._programs.clear()
+        tape.reset_counters()
+        assert tape.debug()["prewarm"] == {
+            "state": "idle", "warmed": 0, "skipped": [], "error": None}
+
     def test_coalesced_distinct_shapes_share_mesh_launch(self):
         """16 structurally distinct concurrent Counts through the
         ragged coalescer on the mesh: <= 2 launches, bit-exact, and a

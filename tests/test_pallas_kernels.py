@@ -227,3 +227,75 @@ def test_pallas_routing_honors_chip_winners(monkeypatch):
     monkeypatch.setenv("PILOSA_TPU_PALLAS", "0")
     assert not any(pk._use_pallas(False, 1 << 30, kernel=n)
                    for n in winners)
+
+
+def _lowering_cases():
+    """(id, kernel name, abstract args, static kwargs) for every Pallas
+    entry point, at a small shape and at the shape a 256-shard
+    (268M-column) index hands it: 32768-word shard rows, 2048-word
+    containers, megapools of ~10^5 rows, a full [vm] max-prefetch
+    directory."""
+    import jax
+    import jax.numpy as jnp
+
+    def a(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i32, u16 = jnp.int32, jnp.uint16
+    cw = pk.CONTAINER_WORDS
+    shard_w = 256 * 32768  # 256 shards x 2^20 columns, in words
+    return [
+        ("row_counts_masked-small", "_row_counts_masked_pallas",
+         (a((7, 100)), a((100,))), {}),
+        ("row_counts_masked-256shards", "_row_counts_masked_pallas",
+         (a((64, shard_w)), a((shard_w,))), {}),
+        ("count_and-small", "_count_and_pallas",
+         (a((4, 512)), a((4, 512))), {}),
+        ("count_and-256shards", "_count_and_pallas",
+         (a((256, 32768)), a((256, 32768))), {}),
+        ("gathered_count_and-small", "_gathered_count_and_pallas",
+         (a((9, cw)), a((5,), i32), a((3, cw)), a((5,), i32)), {}),
+        ("gathered_count_and-256shards", "_gathered_count_and_pallas",
+         (a((1024, cw)), a((512,), i32), a((1024, cw)),
+          a((512,), i32)), {}),
+        ("vm_counts-small", "_vm_counts_pallas",
+         (a((12, cw)), a((2, 4, 3), i32), a((4, 2, 8), i32)), {}),
+        ("vm_counts-256shards", "_vm_counts_pallas",
+         (a((131072, cw)), a((16, 8, 3), i32), a((4, 16, 1024), i32)),
+         {}),
+        ("vm_counts-max-bucket", "_vm_counts_pallas",
+         (a((65536, cw)), a((32, 32, 3), i32), a((16, 32, 128), i32)),
+         {}),
+        ("vm_counts_kinds-small", "_vm_counts_kinds_pallas",
+         (a((8, cw)), a((8, 16), u16), a((8,), i32), a((4, 8), u16),
+          a((2, 4, 3), i32), a((4, 2, 8), i32)), {}),
+        ("vm_counts_kinds-256shards", "_vm_counts_kinds_pallas",
+         (a((16384, cw)), a((16384, 4096), u16), a((16384,), i32),
+          a((4096, 512), u16), a((16, 8, 3), i32),
+          a((4, 16, 1024), i32)), {}),
+        ("masked_matrix_counts-small", "_mmc_pallas",
+         (a((5, 300)), a((3, 300))), {}),
+        ("masked_matrix_counts-256shards", "_mmc_pallas",
+         (a((64, shard_w)), a((64, shard_w))), {}),
+        ("bsi_compare-small", "_bsi_compare_pallas",
+         (a((6, 100)), a((100,)), a((4, 1))), {"depth": 4}),
+        ("bsi_compare-256shards", "_bsi_compare_pallas",
+         (a((22, shard_w)), a((shard_w,)), a((20, 1))), {"depth": 20}),
+    ]
+
+
+@pytest.mark.parametrize(
+    "kernel,args,kwargs",
+    [pytest.param(k, a, kw, id=i) for i, k, a, kw in _lowering_cases()])
+def test_every_pallas_entry_point_lowers_for_tpu(kernel, args, kwargs):
+    """Lower each jitted Pallas kernel for the TPU from this CPU host,
+    from abstract shapes (no data).  The Pallas TPU lowering rejects
+    illegal block shapes here, which is how a `(1, 2048)` block over an
+    `(R, 2048)` pool and a `(1, 1)` SMEM output block were found
+    without a chip.  It does NOT replace the on-chip validation
+    (benchmarks/validate_tpu.py, run by chip_smoke.py): Mosaic's own
+    compile — VMEM/SMEM limits, unsupported vector ops — and the
+    counts themselves are only checked on a TPU."""
+    lowered = getattr(pk, kernel).trace(*args, **kwargs).lower(
+        lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()

@@ -1,5 +1,5 @@
 """Scale smoke test: a 256-shard (268M-column) index answers the
-north-star query exactly through the fused executor path (BASELINE.md
+north-star query exactly through the fused executor path (BASELINE.json
 config 2 shape at quarter scale; the full 1024-shard/1.07B-column run
 passes identically — kept smaller here for suite time)."""
 

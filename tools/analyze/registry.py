@@ -377,7 +377,7 @@ BLOCKING_CALL_SUFFIXES = (
 )
 BLOCKING_ATTRS = ("join", "result", "wait", "block_until_ready",
                   "urlopen")
-DEVICE_DISPATCH_NAMES = ("chunked_device_put", "device_put")
+DEVICE_DISPATCH_NAMES = ("device_put",)
 CONDITION_ATTRS = ("_snap_done",)
 
 # ----------------------------------------------------- P4: recompile model
