@@ -181,8 +181,12 @@ class API:
 
             # the parsed Query skips the executor's re-parse, so the
             # sentinel gate must apply here too (remote-only spellings)
-            q = (_parse(pql, allow_internal=remote)
-                 if isinstance(pql, str) else pql)
+            q = pql
+            if isinstance(pql, str):
+                from pilosa_tpu import observe as _observe
+
+                with _observe.span("pql.parse"):
+                    q = _parse(pql, allow_internal=remote)
             if isinstance(q, Query) and (
                     q.write_call_n() > self.max_writes_per_request):
                 raise ApiError(

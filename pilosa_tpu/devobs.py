@@ -424,6 +424,24 @@ def instrument(name: str, fn):
     return _InstrumentedJit(name, fn)
 
 
+def jit(name: str, fn, **jit_kwargs):
+    """``jax.jit`` for a program built in a closure: named for its
+    engine first — the compiled program is ``jit_<name>`` with dots as
+    underscores (no shape in it, so the names stay few) instead of the
+    closure's ``jit_run``, and its operations sit under
+    ``jax.named_scope(name)`` — then :func:`instrument`-ed under the
+    same name.  The device trace, ``/debug/devices`` and the flight
+    record then call one program one thing."""
+    import jax
+
+    def named(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    named.__name__ = named.__qualname__ = name.replace(".", "_")
+    return instrument(name, jax.jit(named, **jit_kwargs))
+
+
 # ------------------------------------------------------------------ sampler
 
 

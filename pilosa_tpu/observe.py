@@ -4,10 +4,12 @@ serving path.
 The round-5 verdict's last big unknown is the dispatch window — the
 committed chip number understates the engine ~5.6x — yet process-wide
 stats (count/sum/min/max) cannot attribute latency to a QUERY.  This
-module holds one ``QueryRecord`` per in-flight query: stage timings at
-the executor's map/reduce boundaries, per-shard and per-node map
-timings, the device-launch count from the ``ops/bitmap.py`` dispatch
-hook, coalescer batch occupancy and queue-wait vs launch split, the
+module holds one ``QueryRecord`` per in-flight query: a SPAN TREE of
+the request's host phases from the handler's dispatch to the response
+(``observe.span``: one recording primitive, both times on one clock,
+parent ids, the same spans on the profiler's timeline while a capture
+runs), per-shard and per-node map timings, the device-launch count from
+the ``ops/bitmap.py`` dispatch hook, coalescer batch occupancy, the
 fused-vs-fallback expression path, and result sizes — the per-stage
 timing discipline DrJAX (arxiv 2403.07128) and Ragged Paged Attention
 (arxiv 2604.15464) use to diagnose TPU dispatch overhead, applied to
@@ -25,11 +27,12 @@ Exposure (server/handler.py):
 
 Lock discipline: the record is assembled THREAD-LOCALLY (``attach``
 installs it on worker threads for the duration of one shard's
-evaluation; list appends are GIL-atomic) — no lock on the per-stage /
-per-launch hot path.  The recorder's own lock is touched once at
-begin and once at publish (keeping the active table and ring buffer
-safely iterable from /debug/queries), plus the stats registry's on
-the latency-histogram observation.  The recorder must stay under 1%
+evaluation; list appends and ``next()`` on the span-id counter are
+GIL-atomic) — no lock on the per-span / per-launch hot path.  The
+recorder's own lock is touched once at begin and once at publish
+(keeping the active table and ring buffer safely iterable from
+/debug/queries), plus the stats registry's on the latency-histogram
+observation.  The recorder must stay under 1%
 of the coalesced Count path — benchmarked by ``bench.py``
 (extras.observe).
 """
@@ -43,9 +46,35 @@ from collections import Counter, deque
 
 from pilosa_tpu import lockcheck as _lockcheck
 from pilosa_tpu import tracing as _tracing
-from pilosa_tpu.serve.deadline import tls_scope as _tls_scope
 
-_tls = threading.local()  # .rec: active QueryRecord; .last: last published
+# .rec: active QueryRecord; .req: the handler's Request; .open: id of
+# the innermost open span on this thread; .last: last published record
+_tls = threading.local()
+
+#: The span clock: ``time.perf_counter_ns`` (CLOCK_MONOTONIC on Linux —
+#: what ``t0_ns``, the harness's ``time.monotonic()`` and the two
+#: clocks ``/debug/profiler/start`` returns all read).
+clock_ns = time.perf_counter_ns
+_thread_id = threading.get_ident
+
+#: True while a device-profiler capture runs (``perfobs.profiler_start``
+#: / ``stop`` flip it through :func:`set_capturing`): every span then
+#: also enters a ``jax.profiler.TraceAnnotation`` carrying the record's
+#: trace id, so the capture's host plane shows the same spans on the
+#: device plane's timeline.  With no capture the annotation class is
+#: never touched.
+capturing = False
+_annotation = None  # jax.profiler.TraceAnnotation while capturing
+
+
+def set_capturing(on: bool) -> None:
+    global capturing, _annotation
+    if on and _annotation is None:
+        import jax
+
+        _annotation = jax.profiler.TraceAnnotation
+    capturing = bool(on)
+
 
 #: PQL longer than this is truncated in records (a query string is
 #: operator-facing debug data, not an archive).
@@ -60,6 +89,9 @@ MAX_PQL = 2048
 #: loop cannot grow a record without bound.
 MAX_SHARD_TIMINGS = 4096
 MAX_LAUNCHES = 65536
+#: Spans per request (a per-shard map over thousands of shards opens
+#: a few per shard); past it spans still time and parent, unrecorded.
+MAX_SPANS = 4096
 
 
 def current() -> "QueryRecord | None":
@@ -69,32 +101,208 @@ def current() -> "QueryRecord | None":
     return getattr(_tls, "rec", None)
 
 
-class attach(_tls_scope):
+class attach:
     """Install a record (or None) as this thread's active record for a
-    scope.  Re-entrant: restores whatever was active before, so a
-    remote re-execution beginning its OWN record inside an IO thread
-    shadows rather than clobbers."""
+    scope, with the span its children hang under: ``parent`` when given
+    (a pool worker passes the :func:`open_span` it captured on the
+    submitting thread), else the record's ``exec`` span.  Re-entrant:
+    restores whatever was active before, so a remote re-execution
+    beginning its OWN record inside an IO thread shadows rather than
+    clobbers."""
+
+    __slots__ = ("rec", "parent", "_prev", "_prev_open")
+
+    def __init__(self, rec: "QueryRecord | None",
+                 parent: int | None = None):
+        self.rec = rec
+        self.parent = parent
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "rec", None)
+        self._prev_open = getattr(_tls, "open", 0)
+        rec = _tls.rec = self.rec
+        if self.parent is not None:
+            _tls.open = self.parent
+        elif rec is not None:
+            _tls.open = rec.exec_id
+        return rec
+
+    def __exit__(self, *exc):
+        _tls.rec = self._prev
+        _tls.open = self._prev_open
+        return False
+
+
+def open_span() -> int:
+    """Id of the innermost span open on this thread (0: none) — what a
+    caller captures before handing work to another thread."""
+    return getattr(_tls, "open", 0)
+
+
+class Request:
+    """The handler's side of one request: the root ``http.request``
+    span, and what happens before ``Executor.execute`` opens the flight
+    record (admission wait, body read, parse) or after it publishes
+    (serialisation).  Installed as a thread-local for the request's
+    scope; ``FlightRecorder.begin`` ADOPTS it — the record takes over
+    this object's span list, id counter and admission stamp, so both
+    write one tree and no second record is opened.  ``arrived_ns`` is
+    the clock when the request line had been read: the root starts
+    there, and what the HTTP server did until the handler's dispatch
+    (header parsing) is the ``http.parse`` span."""
+
+    __slots__ = ("spans", "ids", "admission", "start_ns", "end_ns",
+                 "_prev", "_prev_open", "_ann")
+
+    def __init__(self, arrived_ns: int = 0):
+        self.spans: list[tuple] = []
+        self.ids = itertools.count(3)  # 1 is the root, 2 http.parse
+        self.admission: dict | None = None
+        self.start_ns = arrived_ns
+        self.end_ns = 0  # 0 while the request is open
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "req", None)
+        self._prev_open = getattr(_tls, "open", 0)
+        _tls.req = self
+        _tls.open = 1
+        self._ann = None
+        if capturing:
+            self._ann = _annotation("http.request")
+            self._ann.__enter__()
+        now = clock_ns()
+        if self.start_ns:
+            self.spans.append((2, 1, "http.parse", self.start_ns, now,
+                               _thread_id(), None))
+        else:
+            self.start_ns = now
+        return self
+
+    def __exit__(self, *exc):
+        self.end_ns = clock_ns()
+        self.spans.append((1, 0, "http.request", self.start_ns,
+                           self.end_ns, _thread_id(), None))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _tls.req = self._prev
+        _tls.open = self._prev_open
+        return False
+
+
+class _NoSpan:
+    """What :func:`span` returns when nothing records: one shared
+    object, no clock read."""
 
     __slots__ = ()
+    id = start_ns = end_ns = 0
 
-    def __init__(self, rec: "QueryRecord | None"):
-        super().__init__(_tls, "rec", rec)
+    def __enter__(self):
+        return self
 
+    def __exit__(self, *exc):
+        return False
 
-class admission_scope(_tls_scope):
-    """Install an admission stamp ({"class", "queue_wait_ns"}) for a
-    request's scope; ``FlightRecorder.begin`` copies it onto every
-    record begun inside (the handler admits BEFORE the executor opens
-    the record, so the handoff is this thread-local).  Re-entrant."""
+    def note(self, **counts) -> None:
+        pass
 
-    __slots__ = ()
-
-    def __init__(self, info: dict | None):
-        super().__init__(_tls, "admission", info)
+    def note_engine(self) -> None:
+        pass
 
 
-def current_admission() -> dict | None:
-    return getattr(_tls, "admission", None)
+NOSPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("sink", "name", "counts", "timer", "export", "id",
+                 "parent", "start_ns", "end_ns", "_x", "_ann")
+
+    def __init__(self, sink, name, start_ns, timer, export, counts):
+        self.sink = sink
+        self.name = name
+        self.start_ns = start_ns
+        self.timer = timer
+        self.export = export
+        self.counts = counts
+        self.id = self.parent = self.end_ns = 0
+
+    def note(self, **counts) -> None:
+        """Counts known only once the work is done (``putBytes``,
+        ``engine``, ``batch``)."""
+        if self.counts is None:
+            self.counts = counts
+        else:
+            self.counts.update(counts)
+
+    def note_engine(self) -> None:
+        """Stamp a ``launch`` span with the engine its record was just
+        attributed to (``perfobs.sample``: last launch wins)."""
+        engine = getattr(self.sink, "engine", None)
+        if engine is not None:
+            self.note(engine=engine)
+
+    def __enter__(self):
+        sink = self.sink
+        if sink is not None:
+            self.id = next(sink.ids)
+            self.parent = getattr(_tls, "open", 0)
+            _tls.open = self.id
+        self._x = self._ann = None
+        if self.export is not None:
+            self._x = _tracing.start_span(self.export)
+            for k, v in (self.counts or {}).items():
+                self._x.set_tag(k, v)
+            self._x.__enter__()
+        if capturing:
+            tid = getattr(sink, "trace_id", None)
+            self._ann = (_annotation(self.name, rid=tid) if tid
+                         else _annotation(self.name))
+            self._ann.__enter__()
+        if not self.start_ns:
+            self.start_ns = clock_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.end_ns = end = clock_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._x is not None:
+            self._x.__exit__(*exc)
+        sink = self.sink
+        if sink is not None:
+            _tls.open = self.parent
+            if len(sink.spans) < MAX_SPANS:
+                sink.spans.append((self.id, self.parent, self.name,
+                                   self.start_ns, end, _thread_id(),
+                                   self.counts))
+        if self.timer is not None:
+            self.timer[0].timing(self.timer[1], end - self.start_ns)
+        return False
+
+
+def span(name: str, start_ns: int = 0, timer: tuple | None = None,
+         export: str | None = None, **counts):
+    """Time one host phase of the request this thread serves.
+
+    On exit ONE tuple ``(id, parent id, name, start_ns, end_ns, thread,
+    counts)`` is appended to the thread's current flight record (or,
+    before/after it, to the handler's :class:`Request`); the parent is
+    the innermost span open on this thread.  No lock; list appends and
+    ``next()`` on the id counter are GIL-atomic.  While a profiler
+    capture runs the span is also a ``TraceAnnotation``.
+
+    ``timer=(stats, name)`` feeds the same duration to a stats timing;
+    ``export`` opens the ``tracing.py`` span of that name when a
+    recording tracer is installed (OTLP export), tagged with the
+    counts; ``start_ns`` backdates the start to a clock read the caller
+    already took.  With no record, no timer and no recording tracer
+    this returns the shared :data:`NOSPAN` and reads no clock."""
+    sink = getattr(_tls, "rec", None) or getattr(_tls, "req", None)
+    if export is not None and not isinstance(
+            _tracing.global_tracer(), _tracing.MemTracer):
+        export = None
+    if sink is None and timer is None and export is None:
+        return NOSPAN
+    return _Span(sink, name, start_ns, timer, export, counts or None)
 
 
 def take_last() -> "QueryRecord | None":
@@ -187,7 +395,8 @@ class QueryRecord:
 
     __slots__ = (
         "qid", "trace_id", "index", "pql", "start_unix", "t0_ns",
-        "elapsed_ns", "shards_n", "stages", "shard_ns", "node_ns",
+        "elapsed_ns", "shards_n", "spans", "ids", "exec_id",
+        "exec_parent", "req", "shard_ns", "node_ns",
         "launches", "path", "coalesce", "result_sizes", "error", "slow",
         "admission", "outcome", "compiles", "cached", "cache_key",
         "delta_notes", "compacted", "hedged", "hedge_wins",
@@ -203,10 +412,19 @@ class QueryRecord:
         now_ns = time.time_ns()
         self.trace_id = trace_id or f"{now_ns:016x}{qid & 0xFFFF:04x}"
         self.start_unix = now_ns / 1e9
-        self.t0_ns = time.perf_counter_ns()
+        self.t0_ns = clock_ns()
         self.elapsed_ns: int | None = None  # None while in flight
         self.shards_n = 0
-        self.stages: list[tuple[str, int]] = []       # (name, ns)
+        # the span tree: (id, parent, name, start_ns, end_ns, thread,
+        # counts) tuples appended as spans CLOSE (observe.span).  A
+        # record begun under a handler Request adopts its list, id
+        # counter and root (FlightRecorder.begin); begun anywhere
+        # else, its own ``exec`` span is the root
+        self.spans: list[tuple] = []
+        self.ids = itertools.count(1)
+        self.exec_id = 1
+        self.exec_parent = 0
+        self.req: Request | None = None
         self.shard_ns: list[tuple[int, int]] = []     # (shard, ns)
         self.node_ns: list[tuple[str, int, int]] = [] # (node, ns, n_shards)
         self.launches: list[str] = []
@@ -294,8 +512,17 @@ class QueryRecord:
 
     # ------------------------------------------------------------ notes
 
-    def note_stage(self, name: str, ns: int) -> None:
-        self.stages.append((name, ns))
+    def add_span(self, name: str, start_ns: int, end_ns: int,
+                 parent: int | None = None, **counts) -> int:
+        """Append a span whose two times were read elsewhere (a
+        follower's view of its batch leader's launch) -> its id."""
+        sid = next(self.ids)
+        if len(self.spans) < MAX_SPANS:
+            self.spans.append((
+                sid, getattr(_tls, "open", 0) if parent is None
+                else parent, name, start_ns, end_ns, _thread_id(),
+                counts or None))
+        return sid
 
     def note_launch(self, name: str) -> None:
         """One kernel launch (called from ops/bitmap.note_dispatch).
@@ -359,8 +586,50 @@ class QueryRecord:
             return self.elapsed_ns
         return time.perf_counter_ns() - self.t0_ns
 
+    #: span -> the ``stages`` entry it renders as (``call.<Name>``
+    #: renders as ``execute.<Name>``); spans not named here are detail
+    #: the flat stage list never had
+    _STAGES = ("translate", "translateResults", "map", "map.fused")
+
+    def stages(self) -> list[tuple[str, int]]:
+        """The flat (name, ns) stage list, in order of completion,
+        rendered from the spans."""
+        out = []
+        for sp in sorted(self.spans, key=lambda sp: sp[4]):
+            name = sp[2]
+            if name.startswith("call."):
+                name = "execute." + name[5:]
+            elif name not in self._STAGES:
+                continue
+            out.append((name, sp[4] - sp[3]))
+        return out
+
+    def spans_dict(self) -> tuple[int, list[dict]]:
+        """(root start on the span clock, the spans with times relative
+        to it, by start).  A handler root that is still open — the
+        inline ``?profile=1`` rendering happens inside it — is shown to
+        this instant and marked ``open``."""
+        spans = list(self.spans)
+        req = self.req
+        if req is not None and not req.end_ns:
+            spans.append((1, 0, "http.request", req.start_ns, clock_ns(),
+                          0, {"open": True}))
+        root = min((sp[3] for sp in spans if not sp[1]),
+                   default=self.t0_ns)
+        out = []
+        for sid, parent, name, start, end, thread, counts in sorted(
+                spans, key=lambda sp: (sp[3], sp[0])):
+            d = {"id": sid, "parent": parent, "name": name,
+                 "startNs": start - root, "endNs": end - root,
+                 "thread": thread}
+            if counts:
+                d.update(counts)
+            out.append(d)
+        return root, out
+
     def to_dict(self) -> dict:
         ms = 1e6
+        root_ns, spans = self.spans_dict()
         d = {
             "id": self.qid,
             "traceID": self.trace_id,
@@ -371,7 +640,11 @@ class QueryRecord:
             "active": self.elapsed_ns is None,
             "shards": self.shards_n,
             "stages": [{"name": n, "ms": round(v / ms, 3)}
-                       for n, v in self.stages],
+                       for n, v in self.stages()],
+            # the span tree; rootStartNs is the root's start on the
+            # clock /debug/profiler/start returns as perfCounterNs
+            "rootStartNs": root_ns,
+            "spans": spans,
             "shardTimings": [{"shard": s, "ms": round(v / ms, 3)}
                              for s, v in self.shard_ns],
             "nodeTimings": [{"node": n, "ms": round(v / ms, 3),
@@ -507,9 +780,19 @@ class FlightRecorder:
     def begin(self, index: str, pql: str,
               trace_id: str | None = None) -> QueryRecord:
         rec = QueryRecord(next(self._seq), index, pql, trace_id)
-        # the admission gate runs before the executor opens the record;
-        # its stamp (class + queue wait) rides a thread-local scope
-        rec.admission = current_admission()
+        # the handler's Request (root span, admission wait, body read,
+        # parse) precedes the record: adopt its span list, id counter
+        # and admission stamp, so handler and executor write ONE tree.
+        # A record begun while another is attached on this thread (an
+        # in-process remote re-execution) keeps a tree of its own.
+        req = getattr(_tls, "req", None)
+        if req is not None and getattr(_tls, "rec", None) is None:
+            rec.req = req
+            rec.spans = req.spans
+            rec.ids = req.ids
+            rec.admission = req.admission
+            rec.exec_parent = getattr(_tls, "open", 0)
+        rec.exec_id = next(rec.ids)
         with self._lock:
             self._active[rec.qid] = rec
         return rec
@@ -566,7 +849,11 @@ class FlightRecorder:
             self._active.pop(rec.qid, None)
 
     def publish(self, rec: QueryRecord, error: str | None = None) -> None:
-        rec.elapsed_ns = time.perf_counter_ns() - rec.t0_ns
+        end_ns = clock_ns()
+        rec.elapsed_ns = end_ns - rec.t0_ns
+        # the ``exec`` span is the record's own two clock reads
+        rec.spans.append((rec.exec_id, rec.exec_parent, "exec",
+                          rec.t0_ns, end_ns, _thread_id(), None))
         if error is not None:
             rec.error = error
         elapsed_s = rec.elapsed_ns / 1e9
@@ -588,7 +875,7 @@ class FlightRecorder:
                 "slow query (%.3fs) trace=%s on %s: %s | stages=%s "
                 "shards=%d launches=%d path=%s engine=%s compiled=%s%s%s",
                 elapsed_s, rec.trace_id, rec.index, rec.pql,
-                ",".join(f"{n}:{v / 1e6:.1f}ms" for n, v in rec.stages),
+                ",".join(f"{n}:{v / 1e6:.1f}ms" for n, v in rec.stages()),
                 rec.shards_n, len(rec.launches), rec.path or "-",
                 rec.engine or "-",
                 "true" if rec.compiles else "false",

@@ -517,10 +517,8 @@ class Plan:
         domain is empty everywhere (zero device work).  ``mesh``
         routes the shard_map gather program (domain axis sharded,
         pools replicated — parallel/meshexec.py)."""
-        from pilosa_tpu.ops import expr
-        from pilosa_tpu.ops import pallas_kernels as pk
-
-        _domains, _bounds, total, idxs = self._stage()
+        with _observe.span("stage", leaves=len(self.leaves)):
+            _domains, _bounds, total, idxs = self._stage()
         if total == 0:
             bump("container.empty_domains")
             # the dense path would still have launched once; tick the
@@ -529,6 +527,17 @@ class Plan:
 
             bm.note_dispatch("fused_gather")
             return None
+        with _observe.span("launch") as sp:
+            out = self._launch(counts, idxs, total, mesh)
+            sp.note_engine()
+        return out
+
+    def _launch(self, counts: bool, idxs: list, total: int,
+                mesh: Any) -> Any:
+        """The one launch of ``_gathered``, by the cheapest arm."""
+        from pilosa_tpu.ops import expr
+        from pilosa_tpu.ops import pallas_kernels as pk
+
         pools = [leaf.pool for leaf in self.leaves]
         # engine-observatory coordinates for this launch: the dense
         # stacks the gather replaced (size-class key) and the fraction
@@ -642,9 +651,10 @@ class Plan:
         _domains, bounds, total, _idxs = self._staged  # set by _gathered
         if out is None:
             return [0] * len(self.shards)
-        cts = np.asarray(out, dtype=np.int64)[:total]
-        return [int(cts[bounds[i]:bounds[i + 1]].sum())
-                for i in range(len(self.shards))]
+        with _observe.span("reduce"):
+            cts = np.asarray(out, dtype=np.int64)[:total]
+            return [int(cts[bounds[i]:bounds[i + 1]].sum())
+                    for i in range(len(self.shards))]
 
     def row_words(self, mesh=None) -> list[tuple[int, np.ndarray]]:
         """Non-empty per-shard result words, scattered back to the
@@ -654,18 +664,19 @@ class Plan:
         if out is None:
             return []
         domains, bounds, total, _idxs = self._staged
-        res = np.asarray(out)[:total]
         partials: list[tuple[int, np.ndarray]] = []
-        for i, s in enumerate(self.shards):
-            dom = domains[i]
-            if len(dom) == 0:
-                continue
-            blocks = res[int(bounds[i]):int(bounds[i + 1])]
-            if not blocks.any():
-                continue
-            words = np.zeros(self.n_words, dtype=np.uint32)
-            words.reshape(self.cpr, CWORDS)[dom] = blocks
-            partials.append((s, words))
+        with _observe.span("reduce"):
+            res = np.asarray(out)[:total]
+            for i, s in enumerate(self.shards):
+                dom = domains[i]
+                if len(dom) == 0:
+                    continue
+                blocks = res[int(bounds[i]):int(bounds[i + 1])]
+                if not blocks.any():
+                    continue
+                words = np.zeros(self.n_words, dtype=np.uint32)
+                words.reshape(self.cpr, CWORDS)[dom] = blocks
+                partials.append((s, words))
         return partials
 
 

@@ -156,7 +156,6 @@ def _build_program(shape: tuple, counts: bool) -> Callable[..., Any]:
     """One jitted program per (canonical shape, root kind).  The
     cache is what makes tree fusion pay: distinct row ids (distinct
     leaf VALUES) reuse the program; only a new tree SHAPE traces."""
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -175,7 +174,7 @@ def _build_program(shape: tuple, counts: bool) -> Callable[..., Any]:
     from pilosa_tpu import devobs as _devobs
 
     name = "expr.fused_counts" if counts else "expr.fused"
-    return _devobs.instrument(name, jax.jit(run))
+    return _devobs.jit(name, run)
 
 
 def _build_gather_program(shape: tuple, counts: bool) -> Callable[..., Any]:
@@ -186,7 +185,6 @@ def _build_gather_program(shape: tuple, counts: bool) -> Callable[..., Any]:
     popcount Count root all cost one launch (ops/containers.py stages
     the pools and pow2-padded indices; see its module docstring for
     the layout).  Argument convention: ``run(*pools, *idxs)``."""
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -206,7 +204,7 @@ def _build_gather_program(shape: tuple, counts: bool) -> Callable[..., Any]:
     from pilosa_tpu import devobs as _devobs
 
     name = "expr.fused_gather_counts" if counts else "expr.fused_gather"
-    return _devobs.instrument(name, jax.jit(run))
+    return _devobs.jit(name, run)
 
 
 def _build_gather_kinds_program(key: tuple,
@@ -222,7 +220,6 @@ def _build_gather_kinds_program(key: tuple,
     (bpool, apool, acard, rpool, ib, ia, ir); arguments flatten in
     leaf order."""
     shape, spec = key
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -257,7 +254,7 @@ def _build_gather_kinds_program(key: tuple,
 
     name = ("expr.fused_gather_kinds_counts" if counts
             else "expr.fused_gather_kinds")
-    return _devobs.instrument(name, jax.jit(run))
+    return _devobs.jit(name, run)
 
 
 def _build_mesh_program(meshkey: tuple, counts: bool) -> Callable[..., Any]:
@@ -276,7 +273,6 @@ def _build_mesh_program(meshkey: tuple, counts: bool) -> Callable[..., Any]:
     ndim, mesh)``: the in_specs tuple length and the shard-axis
     position are static per program."""
     shape, n_leaves, ndim, mesh = meshkey
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -310,7 +306,7 @@ def _build_mesh_program(meshkey: tuple, counts: bool) -> Callable[..., Any]:
     from pilosa_tpu import devobs as _devobs
 
     name = "expr.mesh_counts" if counts else "expr.mesh"
-    return _devobs.instrument(name, jax.jit(run))
+    return _devobs.jit(name, run)
 
 
 def _build_mesh_gather_program(meshkey: tuple,
@@ -326,7 +322,6 @@ def _build_mesh_gather_program(meshkey: tuple,
     Argument convention matches ``_build_gather_program``:
     ``run(*pools, *idxs)``."""
     shape, n_leaves, mesh = meshkey
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
@@ -364,7 +359,7 @@ def _build_mesh_gather_program(meshkey: tuple,
 
     name = ("expr.mesh_gather_counts" if counts
             else "expr.mesh_gather")
-    return _devobs.instrument(name, jax.jit(run))
+    return _devobs.jit(name, run)
 
 
 def _make_compiled(maxsize: int,

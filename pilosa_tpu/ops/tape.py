@@ -58,6 +58,7 @@ from typing import Any
 
 import numpy as np
 
+from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
 from pilosa_tpu.ops import bitmap as bm
 
@@ -339,7 +340,7 @@ def _program(counts: bool) -> Callable[..., Any]:
 
     one = _one_query(counts)
     name = "tape.interpret_counts" if counts else "tape.interpret"
-    prog = devobs.instrument(name, jax.jit(jax.vmap(one)))
+    prog = devobs.jit(name, jax.vmap(one))
     _programs[counts] = prog
     return prog
 
@@ -387,7 +388,7 @@ def _mesh_program(counts: bool, mesh: Any) -> Callable[..., Any]:
 
     name = ("tape.mesh_interpret_counts" if counts
             else "tape.mesh_interpret")
-    prog = devobs.instrument(name, jax.jit(run))
+    prog = devobs.jit(name, run)
     _programs[key] = prog
     return prog
 
@@ -453,8 +454,8 @@ def execute(batch: Sequence[tuple[Tape, tuple]], counts: bool = False,
     bm.note_dispatch("tape")
     bump("tape.executions")
     bump("tape.queries", n)
-    t0 = _perfobs.t0()
     if all(isinstance(lv, np.ndarray) for _, ls in batch for lv in ls):
+        t0 = _perfobs.t0()
         outs = [_host_exec(tp, ls, counts) for tp, ls in batch]
         _perfobs.sample(
             "tape", outs, t0,
@@ -466,35 +467,41 @@ def execute(batch: Sequence[tuple[Tape, tuple]], counts: bool = False,
 
     first = batch[0][1][0]
     stack_shape = tuple(first.shape)
-    zero = jnp.zeros(stack_shape, first.dtype)
     # batch pads to the next power of two, like the coalescer's device
     # batches: the jitted interpreter re-lowers per input shape, and
     # free-running occupancies would each pay a fresh XLA compile in
     # the serving path
     b_pad = _pow2(n)
-    tape_rows = np.zeros((b_pad, tape_len, 3), dtype=np.int32)
-    tape_rows[:, :, 0] = OP_COPY  # pad rows: COPY of leaf slot 0
-    leaf_rows = []
-    pad_leaves = None
-    for qi in range(b_pad):
-        if qi >= n:
-            if pad_leaves is None:
-                pad_leaves = jnp.stack([zero] * slots)
-            leaf_rows.append(pad_leaves)
-            continue
-        tp, ls = batch[qi]
-        for ti, (op, a, b) in enumerate(tp.instrs):
-            tape_rows[qi, ti] = (op, _abs_operand(a, slots),
-                                 _abs_operand(b, slots))
-        final = slots + len(tp.instrs) - 1
-        # short tapes chain COPYs of the final real register forward,
-        # so the LAST register holds the result after the full scan
-        tape_rows[qi, len(tp.instrs):, 1] = final
-        leaf_rows.append(jnp.stack(
-            list(ls) + [zero] * (slots - len(ls))))
-    leaves_arr = jnp.stack(leaf_rows)
+    # the eager stacking of the batch's operands into one register
+    # file: host work plus the jit_broadcast_in_dim / jit_concatenate
+    # programs of the device trace, apart from the launch itself
+    with _observe.span("launch.stack", batch=n, padded=b_pad):
+        zero = jnp.zeros(stack_shape, first.dtype)
+        tape_rows = np.zeros((b_pad, tape_len, 3), dtype=np.int32)
+        tape_rows[:, :, 0] = OP_COPY  # pad rows: COPY of leaf slot 0
+        leaf_rows = []
+        pad_leaves = None
+        for qi in range(b_pad):
+            if qi >= n:
+                if pad_leaves is None:
+                    pad_leaves = jnp.stack([zero] * slots)
+                leaf_rows.append(pad_leaves)
+                continue
+            tp, ls = batch[qi]
+            for ti, (op, a, b) in enumerate(tp.instrs):
+                tape_rows[qi, ti] = (op, _abs_operand(a, slots),
+                                     _abs_operand(b, slots))
+            final = slots + len(tp.instrs) - 1
+            # short tapes chain COPYs of the final real register
+            # forward, so the LAST register holds the result after the
+            # full scan
+            tape_rows[qi, len(tp.instrs):, 1] = final
+            leaf_rows.append(jnp.stack(
+                list(ls) + [zero] * (slots - len(ls))))
+        leaves_arr = jnp.stack(leaf_rows)
     with _lock:
         _lowered.add((counts, b_pad, tape_len, slots) + stack_shape)
+    t0 = _perfobs.t0()
     if mesh is not None:
         from pilosa_tpu.parallel import meshexec
 
@@ -568,27 +575,27 @@ def execute_vm(batch: Sequence[tuple[Tape, list]], pool: Any,
     bm.note_dispatch("vm")
     bump("vm.executions")
     bump("vm.queries", n)
-    t0 = _perfobs.t0()
-    prog = np.zeros((b_pad, tape_len, 3), dtype=np.int32)
-    prog[:, :, 0] = OP_COPY  # pad rows: COPY of leaf slot 0
-    gidx = np.full((slots, b_pad, D), zero_index, dtype=np.int32)
-    for qi, (tp, idxs) in enumerate(batch):
-        for ti, (op, a, b) in enumerate(tp.instrs):
-            prog[qi, ti] = (op, _abs_operand(a, slots),
-                            _abs_operand(b, slots))
-        final = slots + len(tp.instrs) - 1
-        # short tapes chain COPYs of the final real register forward,
-        # exactly like execute() — the LAST register holds the result
-        prog[qi, len(tp.instrs):, 1] = final
-        for li, ix in enumerate(idxs):
-            gidx[li, qi, :len(ix)] = ix
+    with _observe.span("launch.stack", batch=n, padded=b_pad):
+        prog = np.zeros((b_pad, tape_len, 3), dtype=np.int32)
+        prog[:, :, 0] = OP_COPY  # pad rows: COPY of leaf slot 0
+        gidx = np.full((slots, b_pad, D), zero_index, dtype=np.int32)
+        for qi, (tp, idxs) in enumerate(batch):
+            for ti, (op, a, b) in enumerate(tp.instrs):
+                prog[qi, ti] = (op, _abs_operand(a, slots),
+                                _abs_operand(b, slots))
+            final = slots + len(tp.instrs) - 1
+            # short tapes chain COPYs of the final real register
+            # forward, exactly like execute() — the LAST register holds
+            # the result
+            prog[qi, len(tp.instrs):, 1] = final
+            for li, ix in enumerate(idxs):
+                gidx[li, qi, :len(ix)] = ix
     with _lock:
         _vm_lowered.add((b_pad, tape_len, slots, D))
     from pilosa_tpu.ops import pallas_kernels as pk
 
-    cts = np.asarray(pk.vm_counts(pool, prog, gidx,
-                                  interpret=interpret),
-                     dtype=np.int64)
+    t0 = _perfobs.t0()
+    out = pk.vm_counts(pool, prog, gidx, interpret=interpret)
     # what the VM launch actually touches: the gathered container
     # blocks (every directory entry DMAs one pool row), the SMEM
     # directory + programs, and the count outputs — never the dense
@@ -605,9 +612,12 @@ def execute_vm(batch: Sequence[tuple[Tape, list]], pool: Any,
         engine = "vm"
         cwords = int(pool.shape[-1]) if getattr(pool, "ndim", 0) else 0
         touched = gidx.size * cwords * 4
-    _perfobs.sample(engine, cts, t0,
+    # the sample waits for the kernel (launch.ready); the int64 copy to
+    # the host after it is the caller's reduce
+    _perfobs.sample(engine, out, t0,
                     nbytes=touched + gidx.nbytes
-                    + prog.nbytes + cts.nbytes)
+                    + prog.nbytes + out.size * 8)
+    cts = np.asarray(out, dtype=np.int64)
     return [cts[i] for i in range(n)]
 
 

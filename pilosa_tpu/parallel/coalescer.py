@@ -52,7 +52,6 @@ knobs live under ``[coalescer]`` and ``[ragged]``
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future
 
 import numpy as np
@@ -90,7 +89,7 @@ class _Bucket:
     __slots__ = ("items", "full", "sealed",
                  "n_final", "shapes_final", "tape_final", "vm_final",
                  "flush_t0", "launch_ns", "engine", "would_choose",
-                 "flush_trace")
+                 "flush_trace", "launch_span")
 
     def __init__(self):
         # _Entry per enqueued query
@@ -119,6 +118,9 @@ class _Bucket:
         # batch's launch span — a follower's /debug/trace tree can
         # point at the trace that actually owns the shared launch
         self.flush_trace: str | None = None
+        # [the leader record's traceID, the id of its ``launch``
+        # span]: the ``link`` a follower's own launch span carries
+        self.launch_span: list | None = None
 
 
 class _Entry:
@@ -280,11 +282,12 @@ class Coalescer:
             # keep the shard_map interpreter.  Any decline (dense/hot
             # leaf, ineligible tree, oversize) falls through to the
             # existing ragged/fused staging below, all-or-nothing.
-            vmstage = _containers.stage_vm(
-                idx, child, shards, use_delta=use_delta,
-                max_tape=self.max_tape, max_leaves=self.max_leaves,
-                min_domain=self.vm_min_domain,
-                max_prefetch=self.vm_max_prefetch)
+            with _observe.span("stage", vm=True):
+                vmstage = _containers.stage_vm(
+                    idx, child, shards, use_delta=use_delta,
+                    max_tape=self.max_tape, max_leaves=self.max_leaves,
+                    min_domain=self.vm_min_domain,
+                    max_prefetch=self.vm_max_prefetch)
             if vmstage is None:
                 _tape.bump("vm.fallbacks")
         elif self.vm and self.ragged and use_vm:
@@ -305,7 +308,7 @@ class Coalescer:
                                        mesh=mesh)
             entry = _Entry(shape, leaves, tp, Future(), deadline,
                            mesh=mesh)
-        t0 = time.perf_counter_ns()
+        t0 = _observe.clock_ns()
         with self._lock:
             bucket = self._pending.get(key)
             leader = bucket is None
@@ -318,16 +321,27 @@ class Coalescer:
                 del self._pending[key]
                 bucket.full.set()
         if leader:
-            bucket.full.wait(self.window_s)
-            with self._lock:
-                if not bucket.sealed:
-                    bucket.sealed = True
-                    del self._pending[key]
+            # the window, as the leader sits through it; a follower's
+            # coalesce.wait is written below from the bucket's times
+            with _observe.span("coalesce.wait", start_ns=t0):
+                bucket.full.wait(self.window_s)
+                with self._lock:
+                    if not bucket.sealed:
+                        bucket.sealed = True
+                        del self._pending[key]
             self._flush(bucket)
         counts = entry.fut.result()
-        self.stats.timing("coalescer.query_ns",
-                          time.perf_counter_ns() - t0)
+        launch_end = bucket.flush_t0 + bucket.launch_ns
         rec = _observe.current()
+        if rec is not None and not leader:
+            # a follower never dispatched: its launch span is the
+            # LEADER's, by its times, linked to the span that owns it
+            rec.add_span("coalesce.wait", t0, bucket.flush_t0)
+            rec.add_span("launch", bucket.flush_t0, launch_end,
+                         batch=bucket.n_final,
+                         shapes=bucket.shapes_final,
+                         engine=bucket.engine,
+                         link=bucket.launch_span)
         if rec is not None:
             # bucket fields are final once fut resolved (leader writes
             # them before scattering results).  The batch's shared
@@ -366,9 +380,17 @@ class Coalescer:
             # leaf stacks are padded to the device multiple — sum only
             # the live shard rows, in Python ints (int32 could wrap)
             total = int(arr[:len(shards)].sum())
+        end = _observe.clock_ns()
+        self.stats.timing("coalescer.query_ns", end - t0)
+        if rec is not None:
+            # the host tail, from the end of the shared launch: the
+            # leader's scatter to the batch, this member's wake-up and
+            # its own sum over shards
+            rec.add_span("reduce", launch_end, end)
         if cache_fill is not None:
-            rc, key, gens = cache_fill
-            rc.put(key, gens, total, 32, tenant=tenant)
+            with _observe.span("cache.fill"):
+                rc, key, gens = cache_fill
+                rc.put(key, gens, total, 32, tenant=tenant)
         return total
 
     # ------------------------------------------------------------- flush
@@ -400,7 +422,7 @@ class Coalescer:
         for it in live:
             shape_groups[it.shape] = shape_groups.get(it.shape, 0) + 1
         bucket.shapes_final = len(shape_groups)
-        bucket.flush_t0 = time.perf_counter_ns()
+        bucket.flush_t0 = _observe.clock_ns()
         if expired:
             try:
                 self.stats.count("coalescer.deadline_dropped",
@@ -431,11 +453,17 @@ class Coalescer:
             self.stats.histogram("coalescer.batch_occupancy", n)
             self.stats.histogram("coalescer.shape_distinct",
                                  bucket.shapes_final)
-            with tracing.start_span("coalescer.flush") as span:
-                span.set_tag("batch", n)
-                span.set_tag("shapes", bucket.shapes_final)
+            # the batch's ONE launch span, on the leader's record (and,
+            # under a recording tracer, the exported coalescer.flush
+            # span); it starts where the window ended
+            with _observe.span("launch", start_ns=bucket.flush_t0,
+                               timer=(self.stats, "coalescer.launch_ns"),
+                               export="coalescer.flush", batch=n,
+                               shapes=bucket.shapes_final) as span:
                 bucket.flush_trace = tracing.active_trace_id()
-                t_launch = time.perf_counter_ns()
+                lead = _observe.current()
+                if lead is not None:
+                    bucket.launch_span = [lead.trace_id, span.id]
                 from pilosa_tpu.runtime import residency as _residency
 
                 # the batch's workload signature for the engine
@@ -461,7 +489,6 @@ class Coalescer:
                     # ops/pallas_kernels.vm_counts)
                     bucket.tape_final = True
                     bucket.vm_final = True
-                    span.set_tag("vm", True)
                     tb, lb = _tape.size_class(
                         max(len(it.tape.instrs) for it in live),
                         max(len(it.vm.leaves) for it in live))
@@ -532,9 +559,6 @@ class Coalescer:
                     # interpreter buys nothing over a specialized
                     # program)
                     shape = live[0].shape
-                    stacked = tuple(
-                        _stack([it.leaves[j] for it in live])
-                        for j in range(len(live[0].leaves)))
                     # device batches pad to the next power of two: the
                     # jitted program re-lowers per INPUT shape, so
                     # free-running occupancies (2, 3, 5, ...) each pay
@@ -546,9 +570,15 @@ class Coalescer:
                     # rows count to zero and are never scattered back.
                     # Host stacks skip it (the host engine never jits).
                     pad = _pow2(n) - n
-                    if pad and not isinstance(stacked[0], np.ndarray):
-                        stacked = tuple(_pad_batch(s, pad)
-                                        for s in stacked)
+                    with _observe.span("launch.stack", batch=n,
+                                       padded=n + pad):
+                        stacked = tuple(
+                            _stack([it.leaves[j] for it in live])
+                            for j in range(len(live[0].leaves)))
+                        if pad and not isinstance(stacked[0],
+                                                  np.ndarray):
+                            stacked = tuple(_pad_batch(s, pad)
+                                            for s in stacked)
                     bucket.engine = ("mesh" if live[0].mesh is not None
                                      else "dense")
                     with _perfobs.context(work=sig_work):
@@ -570,7 +600,6 @@ class Coalescer:
                     # the (tape_len, slots) size class and every leaf
                     # stack shares one shape
                     bucket.tape_final = True
-                    span.set_tag("tape", True)
                     tb, lb = _tape.size_class(
                         max(len(it.tape.instrs) for it in live),
                         max(it.tape.n_leaves for it in live))
@@ -582,9 +611,7 @@ class Coalescer:
                                 [(it.tape, it.leaves) for it in live],
                                 counts=True, tape_len=tb, slots=lb,
                                 mesh=live[0].mesh))
-                bucket.launch_ns = time.perf_counter_ns() - t_launch
-                self.stats.timing("coalescer.launch_ns",
-                                  bucket.launch_ns)
+                span.note(engine=bucket.engine)
                 # SHADOW cost consult ([cost] shadow): would the table
                 # have routed this batch to a different engine at the
                 # same workload coordinates?  Verdict lands on the
@@ -594,6 +621,7 @@ class Coalescer:
                     bucket.engine,
                     {e: (sig_work, sig_sparsity)
                      for e in ("dense", "tape", "vm", bucket.engine)})
+            bucket.launch_ns = span.end_ns - bucket.flush_t0
         except BaseException as e:  # noqa: BLE001 — every waiter fails
             for it in live:
                 it.fut.set_exception(e)

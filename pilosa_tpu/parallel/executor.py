@@ -61,6 +61,7 @@ from pilosa_tpu.serve.deadline import DeadlineExceededError
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu import faultinject as _fi
 from pilosa_tpu import observe as _observe
+from pilosa_tpu import perfobs as _perfobs
 from pilosa_tpu import stats as _stats
 from pilosa_tpu import tracing
 
@@ -302,7 +303,8 @@ class Executor:
             # sentinel call spellings (_Empty/_Noop/_EmptyRows) only
             # parse with remote semantics: they are the translation
             # layer's wire detail, not public surface
-            query = parse(query, allow_internal=opt.remote)
+            with _observe.span("pql.parse"):
+                query = parse(query, allow_internal=opt.remote)
         if not isinstance(query, Query):
             raise TypeError("query must be a PQL string or Query")
         idx = self.holder.index(index_name)
@@ -340,7 +342,7 @@ class Executor:
                         else str(raw_query))
             rec = self.recorder.begin(index_name, pql_text,
                                       trace_id=tracing.active_trace_id())
-        t0 = _time.perf_counter()
+        t0 = _time.perf_counter() if rec is None else 0.0
         try:
             with _observe.attach(rec), \
                     _residency.no_tiers(not opt.tiers), \
@@ -371,45 +373,36 @@ class Executor:
                 _deadline.check(opt.deadline, "translate")
                 calls = query.calls
                 if not opt.remote:
-                    ts = _time.perf_counter_ns()
-                    calls = [self._translate_call(idx, c) for c in calls]
-                    if rec is not None:
-                        rec.note_stage("translate",
-                                       _time.perf_counter_ns() - ts)
+                    with _observe.span("translate"):
+                        calls = [self._translate_call(idx, c)
+                                 for c in calls]
                 results = []
                 for call in calls:
                     self.stats.count_with_tags(
                         "query", 1, 1.0, [f"index:{index_name}",
                                           f"call:{call.name}"])
-                    # per-op latency via the shared timing surface
-                    # (exception-safe: failed calls record too)
-                    tc = _time.perf_counter_ns()
-                    try:
-                        # implicit parenting on purpose: under the nop
-                        # tracer the active span here is the propagate
-                        # fallback's ContextSpan, not the bare Execute
-                        # span — an explicit traceless parent would
-                        # bury the trace for the whole call (map
-                        # fan-out RPCs, replica writes, hint stamps)
-                        with _stats.Timer(self.stats,
-                                          f"execute.{call.name}"), \
-                                tracing.start_span(
-                                    f"executor.execute{call.name}"):
-                            results.append(
-                                self._execute_call(idx, call, shards, opt))
-                    finally:
-                        if rec is not None:
-                            rec.note_stage(f"execute.{call.name}",
-                                           _time.perf_counter_ns() - tc)
+                    # ONE span per call: the record's ``call.<Name>``
+                    # span (rendered as the ``execute.<Name>`` stage),
+                    # the per-op stats timing (exception-safe: failed
+                    # calls record too) and, under a recording tracer,
+                    # the exported span.  That one parents implicitly
+                    # on purpose: under the nop tracer the active span
+                    # here is the propagate fallback's ContextSpan, not
+                    # the bare Execute span — an explicit traceless
+                    # parent would bury the trace for the whole call
+                    # (map fan-out RPCs, replica writes, hint stamps)
+                    with _observe.span(
+                            "call." + call.name,
+                            timer=(self.stats, "execute." + call.name),
+                            export="executor.execute" + call.name):
+                        results.append(
+                            self._execute_call(idx, call, shards, opt))
                 if not opt.remote:
-                    ts = _time.perf_counter_ns()
-                    results = [
-                        self._translate_result(idx, call, res)
-                        for call, res in zip(calls, results)
-                    ]
-                    if rec is not None:
-                        rec.note_stage("translateResults",
-                                       _time.perf_counter_ns() - ts)
+                    with _observe.span("translateResults"):
+                        results = [
+                            self._translate_result(idx, call, res)
+                            for call, res in zip(calls, results)
+                        ]
         except BaseException as e:
             if rec is not None:
                 if isinstance(e, DeadlineExceededError):
@@ -428,7 +421,10 @@ class Executor:
         if rec is not None:
             rec.result_sizes = [_observe.result_size(r) for r in results]
             self.recorder.publish(rec)
-        elapsed = _time.perf_counter() - t0
+        # the record's exec span is the query's clock; an executor with
+        # the flight recorder off reads its own
+        elapsed = (rec.elapsed_ns / 1e9 if rec is not None
+                   else _time.perf_counter() - t0)
         if (self.long_query_time > 0 and elapsed > self.long_query_time
                 and self.logger is not None):
             # slow-query log (reference cluster.long-query-time,
@@ -551,9 +547,12 @@ class Executor:
             # same way the record does: worker threads must honor the
             # caller's escape and charge the caller's tenant.
             inner = fn
+            # the span the workers' spans hang under: whatever is open
+            # on THIS thread now (the map span)
+            parent = _observe.open_span()
 
             def fn(shard, _inner=inner, _rec=rec, _dl=deadline,
-                   _nt=notiers, _ten=tenant):
+                   _nt=notiers, _ten=tenant, _par=parent):
                 if _fi.armed:
                     # failpoint: the production per-shard map
                     _fi.hit("executor.map_shard")
@@ -564,7 +563,7 @@ class Executor:
                     if _rec is None:
                         return _inner(shard)
                     t0 = _time.perf_counter_ns()
-                    with _observe.attach(_rec):
+                    with _observe.attach(_rec, _par):
                         out = _inner(shard)
                     _rec.note_shard(shard, _time.perf_counter_ns() - t0)
                     return out
@@ -590,8 +589,10 @@ class Executor:
         rec = _observe.current()
         dl = opt.deadline if opt is not None else None
         _deadline.check(dl, "map")
-        t_map = _time.perf_counter_ns() if rec is not None else 0
-        try:
+        # the map stage boundary (reference mapReduce,
+        # executor.go:2455); the enclosing call.<Name> span minus this
+        # is the reduce side
+        with _observe.span("map"):
             partials = self._map_shards_inner(
                 fn, shards, idx, call, opt, adapt, remote_call,
                 local_batch_fn, rec)
@@ -599,12 +600,6 @@ class Executor:
             # flight are dropped here, never folded
             _deadline.check(dl, "reduce")
             return partials
-        finally:
-            if rec is not None:
-                # the map stage boundary (reference mapReduce,
-                # executor.go:2455); the enclosing execute.<Call> stage
-                # minus this is the reduce side
-                rec.note_stage("map", _time.perf_counter_ns() - t_map)
 
     def _map_shards_inner(self, fn, shards, idx, call, opt, adapt,
                           remote_call, local_batch_fn, rec):
@@ -1108,7 +1103,10 @@ class Executor:
         planes on the touched fragments are compacted up front and
         every leaf stays a plain base leaf."""
         leaves: list = []
-        shape = self._fused_shape(idx, call, shards, leaves, use_delta)
+        with _observe.span("stage") as sp:
+            shape = self._fused_shape(idx, call, shards, leaves,
+                                      use_delta)
+            sp.note(leaves=len(leaves))
         return shape, tuple(leaves)
 
     def _fused_row_leaf(self, f, row_id, shards: tuple[int, ...],
@@ -1145,8 +1143,12 @@ class Executor:
                 fname, condition = cond
                 value = (condition.int_slice_value()
                          if condition.op == "><" else condition.value)
-                leaves.append(idx.field(fname).device_range_stack(
-                    condition.op, value, shards))
+                # the range compare dispatches while it stages: its
+                # launch hangs under the stage span
+                leaves.append(_perfobs.launch(
+                    self._raw_engine(self._query_mesh(None)),
+                    lambda: idx.field(fname).device_range_stack(
+                        condition.op, value, shards)))
                 return ("leaf", len(leaves) - 1)
             fname = call.field_arg()
             f = idx.field(fname)
@@ -1200,7 +1202,10 @@ class Executor:
         from pilosa_tpu.ops import expr
 
         shape, leaves = self._fused_expr(idx, call, shards, use_delta)
-        return expr.evaluate(shape, leaves, mesh=mesh)
+        with _observe.span("launch") as sp:
+            out = expr.evaluate(shape, leaves, mesh=mesh)
+            sp.note_engine()
+        return out
 
     @staticmethod
     def _query_mesh(opt: ExecOptions | None):
@@ -1210,6 +1215,27 @@ class Executor:
         from pilosa_tpu.parallel import meshexec
 
         return meshexec.query_mesh(opt is None or opt.mesh)
+
+    @staticmethod
+    def _raw_engine(mesh) -> str:
+        """The engine enum of a raw ``bm``/``bsi`` kernel dispatch (the
+        TopN scan, GroupBy levels, BSI planes, range compares), which
+        passes no perfobs sample site: ``host`` on the numpy + native
+        engine, else where the operand stacks are placed (``mesh`` is
+        the request's ``_query_mesh``)."""
+        if bm.host_mode():
+            return "host"
+        return "mesh" if mesh is not None else "dense"
+
+    def _note_route(self, fused_ok: bool) -> None:
+        """Stamp ``path`` on the flight record; the raw per-shard ops
+        never pass an engine sample site, so that path reads ``host``
+        until a launch says otherwise (note_engine: last launch wins)."""
+        rec = _observe.current()
+        if rec is not None:
+            rec.note_path("fused" if fused_ok else "per-shard")
+            if not fused_ok:
+                rec.note_engine("host")
 
     # ------------------------------------------- result cache (read paths)
 
@@ -1391,6 +1417,31 @@ class Executor:
             rec.cached = True
             rec.note_path("cached")
 
+    def _rc_get(self, idx, kind: str, shards: tuple[int, ...], opt,
+                **probe_kw):
+        """The ``cache.probe`` span: key + generation stamp, then the
+        lookup (single-flight wait on another reader's fill included)
+        -> ``(hit, value, probe)``; ``probe`` is None with caching off
+        and otherwise what :meth:`_rc_put` fills."""
+        with _observe.span("cache.probe") as sp:
+            probe = self._rc_probe(idx, kind, shards, opt, **probe_kw)
+            if probe is None:
+                return False, None, None
+            rc, key, gens = probe
+            hit, val = rc.get(key, gens, self._rc_wait(opt))
+            sp.note(hit=bool(hit))
+            if hit:
+                self._rc_mark_hit()
+            return hit, val, probe
+
+    def _rc_put(self, probe, opt, value, nbytes: int) -> None:
+        """The ``cache.fill`` span: store one computed result under
+        the key and stamp its probe captured before the read."""
+        if probe is not None and self._rc_fill_ok(opt):
+            with _observe.span("cache.fill"):
+                rc, key, gens = probe
+                rc.put(key, gens, value, nbytes)
+
     @staticmethod
     def _rc_wait(opt) -> float:
         """Single-flight wait budget for a cache probe: never park a
@@ -1408,67 +1459,57 @@ class Executor:
         shards = self._target_shards(idx, shards, opt)
         row = Row()
 
-        fused_ok = self._fuse_eligible(idx, shards, call)
+        with _observe.span("plan"):
+            fused_ok = self._fuse_eligible(idx, shards, call)
 
         def batch_fn(group):
             # probe the result cache FIRST (stamp captured before any
             # fragment read); a hit skips the device entirely
             g = tuple(group)
-            probe = self._rc_probe(idx, "row", g, opt, tree=call)
-            if probe is not None:
-                rc, key, gens = probe
-                hit, val = rc.get(key, gens, self._rc_wait(opt))
-                if hit:
-                    self._rc_mark_hit()
-                    # copies both ways (fill and hit): cached words
-                    # must never alias a Row a caller may mutate
-                    return [(s, w.copy()) for s, w in val]
+            hit, val, probe = self._rc_get(idx, "row", g, opt, tree=call)
+            if hit:
+                # copies both ways (fill and hit): cached words
+                # must never alias a Row a caller may mutate
+                return [(s, w.copy()) for s, w in val]
             # sparse trees route the compressed container engine
             # (ops/containers.py): one launch over the pooled
             # directory-matched containers, scattered back to dense
             # per-shard words here
             from pilosa_tpu.ops import containers as _containers
 
-            m = self._query_mesh(opt)
-            cplan = _containers.plan_fused(self, idx, call, g, opt,
-                                           counts=False)
+            with _observe.span("plan"):
+                m = self._query_mesh(opt)
+                cplan = _containers.plan_fused(self, idx, call, g, opt,
+                                               counts=False)
 
             def _dispatch():
                 # the fused Row launch (dense or container-gather),
                 # under the shared RESOURCE_EXHAUSTED evict-and-retry
                 if cplan is not None:
                     return cplan.row_words(mesh=m)
-                # copies: a view would pin the whole stack in memory
-                # for as long as one sparse segment lives
-                stack = np.asarray(self._fused_eval(idx, call, g,
-                                                    use_delta=opt.delta,
-                                                    mesh=m))
-                return [(s, stack[i].copy())
-                        for i, s in enumerate(group)
-                        if stack[i].any()]
+                stack = self._fused_eval(idx, call, g,
+                                         use_delta=opt.delta, mesh=m)
+                with _observe.span("reduce"):
+                    # copies: a view would pin the whole stack in
+                    # memory for as long as one sparse segment lives
+                    stack = np.asarray(stack)
+                    return [(s, stack[i].copy())
+                            for i, s in enumerate(group)
+                            if stack[i].any()]
 
             partials = _residency.run_with_oom_retry(_dispatch)
             if probe is not None and self._rc_fill_ok(opt):
                 value = [(s, w.copy()) for s, w in partials]
-                rc.put(key, gens, value,
-                       sum(w.nbytes for _, w in value) + 32 * len(value))
+                self._rc_put(probe, opt, value,
+                             sum(w.nbytes for _, w in value)
+                             + 32 * len(value))
             return partials
 
-        rec = _observe.current()
-        if rec is not None:
-            rec.note_path("fused" if fused_ok else "per-shard")
-            if not fused_ok:
-                # raw per-shard bm ops never pass an engine sample
-                # site; a fused local_batch_fn group overwrites this
-                # (note_engine is last-launch-wins) with the engine
-                # that actually ran
-                rec.note_engine("host")
+        self._note_route(fused_ok)
         if fused_ok and not self._cluster_active(opt):
             _deadline.check(opt.deadline, "map")
-            t_f = _time.perf_counter_ns()
-            partials = batch_fn(shards)
-            if rec is not None:
-                rec.note_stage("map.fused", _time.perf_counter_ns() - t_f)
+            with _observe.span("map.fused"):
+                partials = batch_fn(shards)
         else:
             def map_fn(shard):
                 return shard, self._bitmap_words_shard(idx, call, shard,
@@ -1673,7 +1714,8 @@ class Executor:
             raise ExecutionError("Count() requires a single bitmap query")
         shards = self._target_shards(idx, shards, opt)
         child = call.children[0]
-        fused_ok = self._fuse_eligible(idx, shards, child)
+        with _observe.span("plan"):
+            fused_ok = self._fuse_eligible(idx, shards, child)
 
         def compute_counts_once(group):
             # the whole tree INCLUDING the popcount root as one compiled
@@ -1689,16 +1731,20 @@ class Executor:
             from pilosa_tpu.ops import containers as _containers
             from pilosa_tpu.ops import expr
 
-            m = self._query_mesh(opt)
-            cplan = _containers.plan_fused(self, idx, child,
-                                           tuple(group), opt)
+            with _observe.span("plan"):
+                m = self._query_mesh(opt)
+                cplan = _containers.plan_fused(self, idx, child,
+                                               tuple(group), opt)
             if cplan is not None:
                 return cplan.counts(mesh=m)
             shape, leaves = self._fused_expr(idx, child, tuple(group),
                                              use_delta=opt.delta)
-            counts = expr.evaluate(shape, leaves, counts=True, mesh=m)
-            return [int(c) for c in
-                    np.asarray(counts, dtype=np.int64)[:len(group)]]
+            with _observe.span("launch") as sp:
+                counts = expr.evaluate(shape, leaves, counts=True, mesh=m)
+                sp.note_engine()
+            with _observe.span("reduce"):
+                return [int(c) for c in
+                        np.asarray(counts, dtype=np.int64)[:len(group)]]
 
         def compute_counts(group):
             # device-dispatch resilience: a backend RESOURCE_EXHAUSTED
@@ -1717,36 +1763,23 @@ class Executor:
             # the remote map path caches on the remote side through
             # the single-node branch below when the sub-query arrives
             g = tuple(group)
-            probe = self._rc_probe(idx, "count_shards", g, opt,
-                                   tree=child)
-            if probe is not None:
-                rc, key, gens = probe
-                hit, val = rc.get(key, gens, self._rc_wait(opt))
-                if hit:
-                    self._rc_mark_hit()
-                    return list(val)
+            hit, val, probe = self._rc_get(idx, "count_shards", g, opt,
+                                           tree=child)
+            if hit:
+                return list(val)
             vals = compute_counts(group)
-            if probe is not None and self._rc_fill_ok(opt):
-                rc.put(key, gens, tuple(vals), 16 * len(vals))
+            self._rc_put(probe, opt, tuple(vals), 16 * len(vals))
             return vals
 
-        rec = _observe.current()
-        if rec is not None:
-            rec.note_path("fused" if fused_ok else "per-shard")
-            if not fused_ok:
-                rec.note_engine("host")
+        self._note_route(fused_ok)
         if fused_ok and not self._cluster_active(opt):
             _deadline.check(opt.deadline, "map")
             # result-cache probe BEFORE the coalescer: a hit answers
             # pre-window and never occupies a batch slot
-            probe = self._rc_probe(idx, "count", tuple(shards), opt,
-                                   tree=child)
-            if probe is not None:
-                rc, ckey, cgens = probe
-                hit, val = rc.get(ckey, cgens, self._rc_wait(opt))
-                if hit:
-                    self._rc_mark_hit()
-                    return val
+            hit, val, probe = self._rc_get(idx, "count", tuple(shards),
+                                           opt, tree=child)
+            if hit:
+                return val
             if (self.coalescer is not None
                     and self.coalescer.eligible(opt)):
                 # the coalescer stamps the record itself (path,
@@ -1766,12 +1799,9 @@ class Executor:
                                             # over compressed pools
                                             use_vm=(opt.vm
                                                     and opt.containers))
-            t_f = _time.perf_counter_ns()
-            total = sum(compute_counts(shards))
-            if rec is not None:
-                rec.note_stage("map.fused", _time.perf_counter_ns() - t_f)
-            if probe is not None and self._rc_fill_ok(opt):
-                rc.put(ckey, cgens, total, 32)
+            with _observe.span("map.fused"):
+                total = sum(compute_counts(shards))
+            self._rc_put(probe, opt, total, 32)
             return total
 
         def map_fn(shard):
@@ -1818,6 +1848,7 @@ class Executor:
         # complete row set.  cache_n=0 demands a complete cache.
         single_shard = len(shards) == 1
         cache_n = n if single_shard and not (ids_arg or attr_name or threshold) else 0
+        engine = self._raw_engine(self._query_mesh(opt))
 
         def map_fn(shard):
             view = f.view(VIEW_STANDARD)
@@ -1840,9 +1871,11 @@ class Executor:
                 # fused jnp otherwise (identical counts)
                 from pilosa_tpu.ops import pallas_kernels as pk
 
-                counts = pk.row_counts_masked(matrix, fw)
+                counts = _perfobs.launch(
+                    engine, lambda: pk.row_counts_masked(matrix, fw))
             else:
-                counts = bm.row_counts(matrix)
+                counts = _perfobs.launch(
+                    engine, lambda: bm.row_counts(matrix))
             counts = np.asarray(counts)
             out = {int(r): int(c) for r, c in zip(row_ids, counts) if c > 0}
             if filter_call is None:
@@ -1859,7 +1892,9 @@ class Executor:
         remote_call.args.pop("threshold", None)
         remote_call.args.pop("tanimotoThreshold", None)
 
-        fused_ok = self._fuse_eligible(idx, shards, filter_call)
+        with _observe.span("plan"):
+            fused_ok = self._fuse_eligible(idx, shards, filter_call)
+        self._note_route(fused_ok)
 
         def batch_fn(group):
             # same hook shape as the Count/Row fused paths: one stacked
@@ -1869,7 +1904,8 @@ class Executor:
 
         if fused_ok and not self._cluster_active(opt):
             _deadline.check(opt.deadline, "map")
-            parts = batch_fn(shards)
+            with _observe.span("map.fused"):
+                parts = batch_fn(shards)
         else:
             parts = self._map_shards(
                 map_fn, shards, idx=idx, call=call, opt=opt,
@@ -1948,20 +1984,16 @@ class Executor:
         when the scan (field matrix + filter leaves) is still at the
         stamped generations, else in ONE device dispatch — the per-
         fragment TopNCache generalized to the whole cross-shard scan."""
-        probe = self._rc_probe(idx, "topn", shards, opt,
-                               tree=filter_call, extra=f.name,
-                               gen_fields=((f, VIEW_STANDARD),))
-        if probe is not None:
-            rc, key, gens = probe
-            hit, val = rc.get(key, gens, self._rc_wait(opt))
-            if hit:
-                self._rc_mark_hit()
-                return dict(val)
+        hit, val, probe = self._rc_get(
+            idx, "topn", shards, opt, tree=filter_call, extra=f.name,
+            gen_fields=((f, VIEW_STANDARD),))
+        if hit:
+            return dict(val)
         totals = self._fused_topn_counts_uncached(idx, f, filter_call,
                                                   shards, opt=opt)
         if probe is not None and self._rc_fill_ok(opt):
-            rc.put(key, gens, dict(totals),
-                   resultcache.result_nbytes(totals))
+            self._rc_put(probe, opt, dict(totals),
+                         resultcache.result_nbytes(totals))
         return totals
 
     def _fused_topn_counts_uncached(self, idx, f, filter_call,
@@ -2001,23 +2033,37 @@ class Executor:
             # drops its cache entry, so the retry restages the query's
             # own largest operand post-eviction instead of
             # re-dispatching against the pinned pre-OOM buffers.
-            stack = f.device_matrix_stack(shards)
+            with _observe.span("stage"):
+                stack = f.device_matrix_stack(shards)
             mat_dev, pos_dev = stack[4], stack[3]
             if mat_dev is None:
                 return stack, None
+            engine = self._raw_engine(self._query_mesh(opt))
             if filter_call is not None:
                 filt = self._fused_eval(
                     idx, filter_call, shards,
                     use_delta=opt is None or opt.delta,
                     mesh=self._query_mesh(opt))
-                return stack, bm.row_counts_gathered(mat_dev, filt,
-                                                     pos_dev)
-            return stack, bm.row_counts(mat_dev)
+                return stack, _perfobs.launch(
+                    engine, lambda: bm.row_counts_gathered(
+                        mat_dev, filt, pos_dev))
+            return stack, _perfobs.launch(
+                engine, lambda: bm.row_counts(mat_dev))
 
         (gens, row_ids, shard_pos, _pos_dev, _mat_dev), counts = \
             _residency.run_with_oom_retry(_scan)
         if counts is None:
             return totals
+        with _observe.span("reduce"):
+            return self._topn_totals(view, shards, filter_call, gens,
+                                     row_ids, shard_pos, counts)
+
+    @staticmethod
+    def _topn_totals(view, shards, filter_call, gens, row_ids,
+                     shard_pos, counts) -> dict[int, int]:
+        """The host tail of the fused TopN scan: counts to the host,
+        summed per row, and every fragment's TopN cache warmed."""
+        totals: dict[int, int] = {}
         n_rows = len(row_ids)
         counts = np.asarray(counts, dtype=np.int64)[:n_rows]
         if filter_call is not None:
@@ -2160,16 +2206,15 @@ class Executor:
         # key, so the post-limit result caches directly
         probe = None
         if not self._cluster_active(opt):
-            probe = self._groupby_cache_probe(idx, call, filter_call,
-                                              tuple(shards), opt)
-            if probe is not None:
-                rc, ckey, cgens = probe
-                hit, val = rc.get(ckey, cgens, self._rc_wait(opt))
+            key_args = self._groupby_cache_args(idx, call, filter_call)
+            if key_args is not None:
+                hit, val, probe = self._rc_get(idx, "groupby",
+                                               tuple(shards), opt,
+                                               **key_args)
                 if hit:
-                    self._rc_mark_hit()
-                    # deep copy: result translation writes row_key onto
-                    # the returned objects and must not mutate the
-                    # cached value
+                    # deep copy: result translation writes row_key
+                    # onto the returned objects and must not mutate
+                    # the cached value
                     return self._copy_group_counts(val)
         child_fields = []
         child_allowed: list[set | None] = []
@@ -2204,6 +2249,9 @@ class Executor:
         # re-evaluating the filter tree per shard.
         filt_stack = None
         shard_pos: dict[int, int] = {}
+        # the cartesian walk is a per-shard map whatever the filter does
+        self._note_route(False)
+        engine = self._raw_engine(self._query_mesh(opt))
         if (filter_call is not None
                 and self._fuse_eligible(idx, shards, filter_call)):
             if self._cluster_active(opt):
@@ -2263,16 +2311,17 @@ class Executor:
             for level, (fname, row_ids, matrix) in enumerate(mats):
                 last = level == len(mats) - 1
                 if masks is None:
-                    cnts = np.asarray(bm.row_counts(matrix))[None, :]
+                    cnts = np.asarray(_perfobs.launch(
+                        engine, lambda: bm.row_counts(matrix)))[None, :]
                 else:
                     # Pallas single-pass kernel on TPU for large
                     # products, bm dispatch (native host / jit)
                     # otherwise — identical counts
                     from pilosa_tpu.ops import pallas_kernels as pk
 
-                    cnts = np.asarray(
-                        pk.masked_matrix_counts(matrix,
-                                                masks))[:len(prefixes)]
+                    cnts = np.asarray(_perfobs.launch(
+                        engine, lambda: pk.masked_matrix_counts(
+                            matrix, masks)))[:len(prefixes)]
                 nz_g, nz_r = np.nonzero(cnts)
                 if len(nz_g) == 0:
                     return {}
@@ -2351,14 +2400,13 @@ class Executor:
         if limit is not None:
             out = out[:limit]
         if probe is not None and self._rc_fill_ok(opt):
-            rc.put(ckey, cgens, self._copy_group_counts(out),
-                   resultcache.result_nbytes(out) * 2)
+            self._rc_put(probe, opt, self._copy_group_counts(out),
+                         resultcache.result_nbytes(out) * 2)
         return out
 
-    def _groupby_cache_probe(self, idx, call: Call, filter_call,
-                             shards: tuple[int, ...],
-                             opt: ExecOptions):
-        """The GroupBy cache key/stamp, or None when ineligible: every
+    def _groupby_cache_args(self, idx, call: Call, filter_call):
+        """What keys and stamps a GroupBy in the result cache (the
+        ``_rc_probe`` arguments), or None when ineligible: every
         child must be a plain standard-view Rows (time-view covers and
         no-standard-view fields change shape under writes in ways the
         per-view stamp would have to chase), the filter absent or a
@@ -2386,9 +2434,8 @@ class Executor:
             return None
         extra = (tuple(sig_children), call.uint_arg("limit"),
                  call.uint_arg("offset"))
-        return self._rc_probe(idx, "groupby", shards, opt,
-                              tree=filter_call, extra=extra,
-                              gen_fields=gen_fields)
+        return {"tree": filter_call, "extra": extra,
+                "gen_fields": gen_fields}
 
     @staticmethod
     def _copy_group_counts(res: list) -> list:
@@ -2433,9 +2480,11 @@ class Executor:
                                             use_delta=opt.delta,
                                             mesh=self._query_mesh(opt))]
 
+        self._note_route(fused_ok)
         if fused_ok and not self._cluster_active(opt):
             _deadline.check(opt.deadline, "map")
-            return batch_fn(shards)[0]
+            with _observe.span("map.fused"):
+                return batch_fn(shards)[0]
 
         filter_row = self._local_filter_row(idx, call, shards, opt)
         local_batch_fn = batch_fn if fused_ok else None
@@ -2479,19 +2528,27 @@ class Executor:
         shard loop is the stack's leading axis)."""
         from pilosa_tpu.ops import bsi as bsi_ops
 
-        P = f.device_plane_stack(shards)
-        consider = P[:, bsi_ops.EXISTS_PLANE]
+        with _observe.span("stage"):
+            P = f.device_plane_stack(shards)
+        filt = None
         if call.children:
             filt = self._fused_eval(idx, call.children[0], shards,
                                     use_delta=use_delta, mesh=mesh)
-            # the filter stack is padded to the same device multiple
-            consider = consider & filt
-        pos, neg, count = bsi_ops.plane_counts_stacked(P, consider)
-        pos = np.asarray(pos, dtype=np.int64).sum(axis=0)
-        neg = np.asarray(neg, dtype=np.int64).sum(axis=0)
-        total_count = int(np.asarray(count, dtype=np.int64).sum())
-        total = sum((1 << i) * (int(p) - int(n))
-                    for i, (p, n) in enumerate(zip(pos, neg)))
+
+        def scan():
+            consider = P[:, bsi_ops.EXISTS_PLANE]
+            if filt is not None:
+                # the filter stack is padded to the same device multiple
+                consider = consider & filt
+            return bsi_ops.plane_counts_stacked(P, consider)
+
+        pos, neg, count = _perfobs.launch(self._raw_engine(mesh), scan)
+        with _observe.span("reduce"):
+            pos = np.asarray(pos, dtype=np.int64).sum(axis=0)
+            neg = np.asarray(neg, dtype=np.int64).sum(axis=0)
+            total_count = int(np.asarray(count, dtype=np.int64).sum())
+            total = sum((1 << i) * (int(p) - int(n))
+                        for i, (p, n) in enumerate(zip(pos, neg)))
         return ValCount(total + total_count * f.options.base, total_count)
 
     def _fused_extreme(self, idx, f, call: Call,
@@ -2503,18 +2560,25 @@ class Executor:
         (fragment.go:1147/1191) and folds with smaller/larger."""
         from pilosa_tpu.ops import bsi as bsi_ops
 
-        P = f.device_plane_stack(shards)
-        consider = P[:, bsi_ops.EXISTS_PLANE]
+        with _observe.span("stage"):
+            P = f.device_plane_stack(shards)
+        filt = None
         if call.children:
-            consider = consider & self._fused_eval(
-                idx, call.children[0], shards, use_delta=use_delta,
-                mesh=mesh)
+            filt = self._fused_eval(idx, call.children[0], shards,
+                                    use_delta=use_delta, mesh=mesh)
         is_min = call.name == "Min"
         want = "min" if is_min else "max"
+
+        def scan():
+            consider = P[:, bsi_ops.EXISTS_PLANE]
+            if filt is not None:
+                consider = consider & filt
+            return bsi_ops.extremes_stacked(P, consider, want)
+
         (signed_cnt, all_cnt, primary_taken, fallback_taken,
          primary_n, fallback_n) = [
             np.asarray(x)
-            for x in bsi_ops.extremes_stacked(P, consider, want)]
+            for x in _perfobs.launch(self._raw_engine(mesh), scan)]
 
         reducer = "smaller" if is_min else "larger"
         out = ValCount()

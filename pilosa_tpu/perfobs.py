@@ -375,18 +375,25 @@ def _ctx() -> "context | None":
 # ------------------------------------------------------------------- sampling
 
 
-def t0() -> int:
-    """Launch-bracket start: the clock when the observatory is on,
-    0 when off — call sites gate the sample on the returned value, so
+def t0() -> Any:
+    """Launch-bracket start, handed to :func:`sample`.  With a flight
+    record on this thread it is the record's ``launch.dispatch`` span,
+    entered — the bracket and the span share their clock reads; with
+    none it is the clock when the observatory is on and 0 when off, so
     a disabled observatory costs one module-bool read per launch."""
-    return _clock() if enabled else 0
+    sp = _observe.span("launch.dispatch")
+    if sp is _observe.NOSPAN:
+        return _clock() if enabled else 0
+    return sp.__enter__()
 
 
-def sample(engine: str, out: Any, t0_ns: int, nbytes: int,
+def sample(engine: str, out: Any, t0_ns: Any, nbytes: int,
            work: int = 0, sparsity: float = 1.0) -> None:
     """Complete one launch sample: block on ``out`` (OUTSIDE any lock
     — the P3 rule), then fold wall/bytes/bandwidth into the cost table
-    and stamp the engine onto the active flight record.
+    and stamp the engine onto the active flight record.  When
+    :func:`t0` opened a ``launch.dispatch`` span, the jitted call has
+    returned: close it and wait inside ``launch.ready``.
 
     ``nbytes`` — analytic bytes the launch touched (operand reads +
     result writes); ``work`` — dense-equivalent uint32 words for the
@@ -396,6 +403,11 @@ def sample(engine: str, out: Any, t0_ns: int, nbytes: int,
     orchestration layer knows better than the ops layer."""
     if not t0_ns:
         return
+    dispatch = None if isinstance(t0_ns, int) else t0_ns
+    if dispatch is not None:
+        dispatch.__exit__(None, None, None)
+        if not enabled:
+            return
     ctx = _ctx()
     if ctx is not None:
         if ctx.engine is not None:
@@ -404,16 +416,46 @@ def sample(engine: str, out: Any, t0_ns: int, nbytes: int,
             sparsity = ctx.sparsity
         if ctx.work is not None:
             work = ctx.work
+    if dispatch is None:
+        _block(out)
+        wall_ns = _clock() - t0_ns
+    else:
+        with _observe.span("launch.ready",
+                           start_ns=dispatch.end_ns) as ready:
+            _block(out)
+        wall_ns = ready.end_ns - dispatch.start_ns
+    record_sample(engine, wall_ns, nbytes, work, sparsity)
+    rec = _observe.current()
+    if rec is not None:
+        rec.note_engine(engine)
+
+
+def launch(engine: str, fn: Any) -> Any:
+    """Bracket one raw-kernel dispatch that passes no engine sample
+    site (the TopN matrix scan, a GroupBy level, the BSI plane ops, a
+    range compare): the flight record's ``launch`` span with its
+    ``launch.dispatch`` / ``launch.ready`` children, and the engine
+    stamp.  No cost-table sample — these shapes are not what the
+    shadow model compares.  With no record it is just ``fn()``."""
+    rec = _observe.current()
+    if rec is None:
+        return fn()
+    with _observe.span("launch", engine=engine):
+        with _observe.span("launch.dispatch") as dispatch:
+            out = fn()
+        with _observe.span("launch.ready", start_ns=dispatch.end_ns):
+            _block(out)
+    rec.note_engine(engine)
+    return out
+
+
+def _block(out: Any) -> None:
     try:
         import jax
 
         jax.block_until_ready(out)
     except Exception:  # noqa: BLE001 — telemetry never fails a query
         pass
-    record_sample(engine, _clock() - t0_ns, nbytes, work, sparsity)
-    rec = _observe.current()
-    if rec is not None:
-        rec.note_engine(engine)
 
 
 def record_sample(engine: str, wall_ns: int, nbytes: int,
@@ -571,7 +613,16 @@ def profiler_start(base_dir: str,
         os.makedirs(out_dir, exist_ok=True)
         import jax
 
-        jax.profiler.start_trace(out_dir)
+        # the host tracer records the spans' TraceAnnotations; the
+        # Python tracer would record every call of every thread and
+        # slow the traced server a hundredfold (PERF.md, PR 23: p90
+        # 2.4 s inside the capture against 19 ms outside it)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+        clocks = _two_clocks()
+        _observe.set_capturing(True)
     except BaseException:
         _prof_lock.release()
         raise
@@ -589,7 +640,7 @@ def profiler_start(base_dir: str,
         _prof["auto_stopped"] = False
     if timer is not None:
         timer.start()
-    return {"dir": out_dir, "maxSeconds": limit}
+    return {"dir": out_dir, "maxSeconds": limit, **clocks}
 
 
 def profiler_stop() -> dict:
@@ -608,6 +659,8 @@ def profiler_stop() -> dict:
         _prof["timer"] = None
     if timer is not None:
         timer.cancel()
+    _observe.set_capturing(False)
+    clocks = _two_clocks()
     try:
         import jax
 
@@ -618,7 +671,17 @@ def profiler_stop() -> dict:
         _prof_lock.release()
     bump("cost.profiles")
     return {"dir": out_dir,
-            "seconds": round(time.time() - since, 3)}
+            "seconds": round(time.time() - since, 3), **clocks}
+
+
+def _two_clocks() -> dict:
+    """The span clock and the wall clock, read together next to the
+    ``start_trace``/``stop_trace`` call: ``perfCounterNs`` places a
+    flight record's spans (``rootStartNs`` + ``startNs``) on the
+    capture without opening the trace, ``unixNs`` places the capture
+    in the world."""
+    return {"perfCounterNs": time.perf_counter_ns(),
+            "unixNs": time.time_ns()}
 
 
 def _profiler_auto_stop() -> None:

@@ -256,7 +256,7 @@ class Ticket:
                                 tenant=self.tenant)
 
     def info(self) -> dict:
-        """The flight-record stamp (observe.admission_scope)."""
+        """The flight-record stamp (rides ``observe.Request``)."""
         d = {"class": self.klass, "queue_wait_ns": self.queue_wait_ns}
         if self.tenant is not None:
             d["tenant"] = self.tenant

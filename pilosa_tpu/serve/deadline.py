@@ -81,8 +81,7 @@ def current() -> Deadline | None:
 class tls_scope:
     """Re-entrant save/set/restore of one attribute on a
     threading.local — the shared base of every per-request scope
-    (deadline.scope here, admission.rpc_class, observe.attach and
-    observe.admission_scope).  ``__enter__`` returns the installed
+    (deadline.scope here, admission.rpc_class, tenant.scope).  ``__enter__`` returns the installed
     value; ``__exit__`` restores whatever was active before, so nested
     scopes shadow rather than clobber."""
 

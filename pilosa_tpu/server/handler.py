@@ -24,6 +24,7 @@ from urllib.parse import parse_qs, urlparse
 
 import numpy as np
 
+from pilosa_tpu import observe
 from pilosa_tpu.api import (
     API,
     ApiError,
@@ -235,6 +236,12 @@ class Handler:
             def log_message(self, fmt, *args):  # quiet by default
                 pass
 
+            def parse_request(self):
+                # the request line has just been read: the request's
+                # root span starts here (observe.Request)
+                self.arrived_ns = observe.clock_ns()
+                return super().parse_request()
+
             def _dispatch(self, method: str):
                 handler_self._handle(self, method)
 
@@ -423,161 +430,181 @@ class Handler:
             match = rx.match(path)
             if match is None:
                 continue
-            if self.stats is not None:
-                self.stats.count_with_tags("http.request", 1, 1.0,
-                                           [f"useragent:{req.headers.get('User-Agent', '')}"])
-            # deadline + admission run BEFORE the body is read: a shed
-            # request must not pay a 256MB body upload first (the
-            # unread body forces the connection closed, like 413)
-            dl_hdr = req.headers.get(_deadline.HEADER)
-            dl = None
-            if dl_hdr is not None:
-                try:
-                    dl = _deadline.parse_header(dl_hdr)
-                except ValueError:
-                    # the body stays unread (like 413/shed): the
-                    # keep-alive connection must close or its bytes
-                    # would parse as the next request
-                    req.close_connection = True
-                    self._error(req, 400,
-                                f"invalid {_deadline.HEADER} header: "
-                                f"{dl_hdr!r}")
-                    return
-            # tenant identity ([tenants] isolation): the
-            # X-Pilosa-Tenant header (authenticated clients), or
-            # ?tenant= (tools and node-to-node sub-query forwarding —
-            # exactly like ?nocache).  A missing/empty id rides the
-            # default tier; the label is an accounting key, never a
-            # credential, so malformed values degrade instead of 400.
-            tenant = _tenant.clean(req.headers.get("X-Pilosa-Tenant")
-                                   or params.get("tenant"))
-            # stash the cleaned label on the request so handle_query's
-            # ExecOptions reuses THIS value — parsing twice invites the
-            # two sites drifting apart (quota charged to one tenant,
-            # cache/residency to another)
-            req._pilosa_tenant = tenant
-            ticket = None
-            if self.admission is not None and klass is not None:
-                k = klass
-                if klass == "internal":
-                    # node-to-node routes accept ONE class re-tag (the
-                    # X-Pilosa-Class stamped by serve.admission
-                    # rpc_class at the call site) so import replica
-                    # deliveries and key allocation ride the ingest
-                    # gate, not internal.  "query" is deliberately NOT
-                    # honored — a header must never let internal
-                    # traffic jump into the highest-priority gate.
-                    if req.headers.get("X-Pilosa-Class") == "ingest":
-                        k = "ingest"
-                if dl is None and self.admission.default_deadline > 0:
-                    dl = _deadline.Deadline(
-                        self.admission.default_deadline)
-                try:
-                    ticket = self.admission.acquire(k, dl,
-                                                    tenant=tenant)
-                except _admission.ShedError as e:
-                    self._record_shed(
-                        match.groupdict().get("index", path), k, e,
-                        headers=req.headers)
-                    req.close_connection = True
-                    # structured shed body: ``reason`` + the tenant id
-                    # let a client tell "I am over quota"
-                    # (tenant-queue-full) from "the server is
-                    # drowning" (queue-full / deadline-unmeetable)
-                    body_obj = {"error": str(e), "reason": e.reason,
-                                "class": e.klass}
-                    if e.tenant is not None:
-                        body_obj["tenant"] = e.tenant
-                    self._json(req, body_obj, e.status,
-                               headers={"Retry-After":
-                                        str(e.retry_after)})
-                    return
-                except ShedByPeerError as e:
-                    # an armed admission.acquire failpoint injects
-                    # error(shed) here — surface it exactly like a
-                    # capacity refusal (503 + Retry-After), never an
-                    # unhandled 500
-                    req.close_connection = True
-                    self._error(req, 503, str(e),
-                                headers={"Retry-After": "1"})
-                    return
-            try:
-                body = b""
-                length = int(req.headers.get("Content-Length") or 0)
-                if length > MAX_REQUEST_BYTES:
-                    # the body stays unread; the keep-alive connection
-                    # must close or its bytes would parse as the next
-                    # request
-                    req.close_connection = True
-                    self._error(req, 413,
-                                f"request body exceeds "
-                                f"{MAX_REQUEST_BYTES} bytes")
-                    return
-                if length:
-                    body = req.rfile.read(length)
-                # trace-context extract + a server span per route (the
-                # reference's tracing middleware, http/handler.go:321);
-                # entering the span makes it the parent of every span
-                # the handler starts (api.*, executor.*)
-                from pilosa_tpu import observe, tracing
-
-                parent = tracing.extract_headers(req.headers)
-                adm = ticket.info() if ticket is not None else None
-                with tracing.start_span(f"http.{name}",
-                                        parent=parent) as span, \
-                        _deadline.scope(dl), \
-                        observe.admission_scope(adm):
-                    span.set_tag("http.path", path)
-                    getattr(self, name)(req, params, match.groupdict(),
-                                        body)
-            except NotFoundError as e:
-                self._error(req, 404, str(e))
-            except ConflictError as e:
-                self._error(req, 409, str(e))
-            except ApiMethodNotAllowedError as e:
-                self._error(req, 405, str(e))
-            except DeadlineExceededError as e:
-                # admitted but expired mid-execution: the executor's
-                # stage checks dropped it before device dispatch
-                if self.admission is not None and ticket is not None:
-                    self.admission.count_expired(ticket.klass)
-                self._error(req, 503, str(e))
-            except ShardsUnavailableError as e:
-                # structured replica exhaustion (chaos round): an
-                # availability condition, not a client error — 503
-                # with the shard list and per-replica causes so
-                # operators (and retrying clients) see WHAT is gone
-                # and WHY, not a flat string
-                self._json(req, {
-                    "error": str(e),
-                    "unavailableShards": e.shards,
-                    "causes": {str(s): e.causes.get(s, {})
-                               for s in e.shards},
-                }, 503, headers={"Retry-After": "1"})
-            except (ApiError, ValueError, KeyError, TypeError) as e:
-                self._error(req, 400, str(e))
-            except ShedByPeerError as e:
-                # a remote sub-request was shed by a peer's admission
-                # gate (and the client's retries are exhausted):
-                # surface overload honestly, with a back-off signal,
-                # instead of masking it as a 500
-                self._error(req, 503, str(e),
-                            headers={"Retry-After": "1"})
-            except Exception as e:  # internal error; keep serving
-                from pilosa_tpu.server.client import ClientError
-
-                if (isinstance(e, ClientError)
-                        and e.status in (429, 503)):
-                    # a shed that reached us as a raw ClientError
-                    # (non-standard transport) still reads as overload
-                    self._error(req, 503, str(e))
-                else:
-                    self._error(req, 500, f"{type(e).__name__}: {e}")
-            finally:
-                if ticket is not None:
-                    ticket.release()
+            # the request's root span opens HERE, before admission: what
+            # the handler does around Executor.execute lands on the
+            # flight record that adopts this Request (observe.Request)
+            recorder = getattr(self.api.executor, "recorder", None)
+            if recorder is not None and recorder.enabled:
+                with observe.Request(
+                        getattr(req, "arrived_ns", 0)) as rq:
+                    self._serve(req, rq, match, name, klass, path, params)
+            else:
+                self._serve(req, None, match, name, klass, path, params)
             return
         self._error(req, 404, "not found")
+
+    def _serve(self, req, rq, match, name: str, klass, path: str,
+               params: dict) -> None:
+        """One matched request: deadline, admission, body, the route's
+        handler, and the error mapping.  ``rq`` is the request's
+        ``observe.Request`` (None with the flight recorder off)."""
+        if self.stats is not None:
+            self.stats.count_with_tags("http.request", 1, 1.0,
+                                       [f"useragent:{req.headers.get('User-Agent', '')}"])
+        # deadline + admission run BEFORE the body is read: a shed
+        # request must not pay a 256MB body upload first (the
+        # unread body forces the connection closed, like 413)
+        dl_hdr = req.headers.get(_deadline.HEADER)
+        dl = None
+        if dl_hdr is not None:
+            try:
+                dl = _deadline.parse_header(dl_hdr)
+            except ValueError:
+                # the body stays unread (like 413/shed): the
+                # keep-alive connection must close or its bytes
+                # would parse as the next request
+                req.close_connection = True
+                self._error(req, 400,
+                            f"invalid {_deadline.HEADER} header: "
+                            f"{dl_hdr!r}")
+                return
+        # tenant identity ([tenants] isolation): the
+        # X-Pilosa-Tenant header (authenticated clients), or
+        # ?tenant= (tools and node-to-node sub-query forwarding —
+        # exactly like ?nocache).  A missing/empty id rides the
+        # default tier; the label is an accounting key, never a
+        # credential, so malformed values degrade instead of 400.
+        tenant = _tenant.clean(req.headers.get("X-Pilosa-Tenant")
+                               or params.get("tenant"))
+        # stash the cleaned label on the request so handle_query's
+        # ExecOptions reuses THIS value — parsing twice invites the
+        # two sites drifting apart (quota charged to one tenant,
+        # cache/residency to another)
+        req._pilosa_tenant = tenant
+        ticket = None
+        if self.admission is not None and klass is not None:
+            k = klass
+            if klass == "internal":
+                # node-to-node routes accept ONE class re-tag (the
+                # X-Pilosa-Class stamped by serve.admission
+                # rpc_class at the call site) so import replica
+                # deliveries and key allocation ride the ingest
+                # gate, not internal.  "query" is deliberately NOT
+                # honored — a header must never let internal
+                # traffic jump into the highest-priority gate.
+                if req.headers.get("X-Pilosa-Class") == "ingest":
+                    k = "ingest"
+            if dl is None and self.admission.default_deadline > 0:
+                dl = _deadline.Deadline(
+                    self.admission.default_deadline)
+            try:
+                with observe.span("admission.wait"):
+                    ticket = self.admission.acquire(k, dl,
+                                                    tenant=tenant)
+            except _admission.ShedError as e:
+                self._record_shed(
+                    match.groupdict().get("index", path), k, e,
+                    headers=req.headers)
+                req.close_connection = True
+                # structured shed body: ``reason`` + the tenant id
+                # let a client tell "I am over quota"
+                # (tenant-queue-full) from "the server is
+                # drowning" (queue-full / deadline-unmeetable)
+                body_obj = {"error": str(e), "reason": e.reason,
+                            "class": e.klass}
+                if e.tenant is not None:
+                    body_obj["tenant"] = e.tenant
+                self._json(req, body_obj, e.status,
+                           headers={"Retry-After":
+                                    str(e.retry_after)})
+                return
+            except ShedByPeerError as e:
+                # an armed admission.acquire failpoint injects
+                # error(shed) here — surface it exactly like a
+                # capacity refusal (503 + Retry-After), never an
+                # unhandled 500
+                req.close_connection = True
+                self._error(req, 503, str(e),
+                            headers={"Retry-After": "1"})
+                return
+        try:
+            body = b""
+            length = int(req.headers.get("Content-Length") or 0)
+            if length > MAX_REQUEST_BYTES:
+                # the body stays unread; the keep-alive connection
+                # must close or its bytes would parse as the next
+                # request
+                req.close_connection = True
+                self._error(req, 413,
+                            f"request body exceeds "
+                            f"{MAX_REQUEST_BYTES} bytes")
+                return
+            if length:
+                with observe.span("http.read", bytes=length):
+                    body = req.rfile.read(length)
+            # trace-context extract + a server span per route (the
+            # reference's tracing middleware, http/handler.go:321);
+            # entering the span makes it the parent of every span
+            # the handler starts (api.*, executor.*)
+            from pilosa_tpu import tracing
+
+            parent = tracing.extract_headers(req.headers)
+            if rq is not None and ticket is not None:
+                # the admission stamp (class + queue wait) rides the
+                # Request onto the record that adopts it
+                rq.admission = ticket.info()
+            with tracing.start_span(f"http.{name}",
+                                    parent=parent) as span, \
+                    _deadline.scope(dl):
+                span.set_tag("http.path", path)
+                getattr(self, name)(req, params, match.groupdict(),
+                                    body)
+        except NotFoundError as e:
+            self._error(req, 404, str(e))
+        except ConflictError as e:
+            self._error(req, 409, str(e))
+        except ApiMethodNotAllowedError as e:
+            self._error(req, 405, str(e))
+        except DeadlineExceededError as e:
+            # admitted but expired mid-execution: the executor's
+            # stage checks dropped it before device dispatch
+            if self.admission is not None and ticket is not None:
+                self.admission.count_expired(ticket.klass)
+            self._error(req, 503, str(e))
+        except ShardsUnavailableError as e:
+            # structured replica exhaustion (chaos round): an
+            # availability condition, not a client error — 503
+            # with the shard list and per-replica causes so
+            # operators (and retrying clients) see WHAT is gone
+            # and WHY, not a flat string
+            self._json(req, {
+                "error": str(e),
+                "unavailableShards": e.shards,
+                "causes": {str(s): e.causes.get(s, {})
+                           for s in e.shards},
+            }, 503, headers={"Retry-After": "1"})
+        except (ApiError, ValueError, KeyError, TypeError) as e:
+            self._error(req, 400, str(e))
+        except ShedByPeerError as e:
+            # a remote sub-request was shed by a peer's admission
+            # gate (and the client's retries are exhausted):
+            # surface overload honestly, with a back-off signal,
+            # instead of masking it as a 500
+            self._error(req, 503, str(e),
+                        headers={"Retry-After": "1"})
+        except Exception as e:  # internal error; keep serving
+            from pilosa_tpu.server.client import ClientError
+
+            if (isinstance(e, ClientError)
+                    and e.status in (429, 503)):
+                # a shed that reached us as a raw ClientError
+                # (non-standard transport) still reads as overload
+                self._error(req, 503, str(e))
+            else:
+                self._error(req, 500, f"{type(e).__name__}: {e}")
+        finally:
+            if ticket is not None:
+                ticket.release()
 
     def _record_shed(self, index: str, klass: str,
                      e: "_admission.ShedError", headers=None) -> None:
@@ -750,8 +777,6 @@ class Handler:
         # execution can never serve a stale profile.
         profile = params.get("profile") == "1"
         if profile:
-            from pilosa_tpu import observe
-
             observe.take_last()
         # ?partial=1 (or the X-Pilosa-Partial header): degraded reads —
         # unavailable shards are accounted in the response
@@ -845,7 +870,8 @@ class Handler:
                 ]
             self._proto(req, proto.encode(proto.QUERY_RESPONSE, pb))
             return
-        resp = {"results": [serialize_result(r) for r in results]}
+        with observe.span("serialize"):
+            resp = {"results": [serialize_result(r) for r in results]}
         if attr_sets is not None:
             resp["columnAttrs"] = attr_sets
         if partial_meta is not None:
@@ -857,8 +883,6 @@ class Handler:
             resp["missingFraction"] = partial_meta.get(
                 "missingFraction", 0.0)
         if profile:
-            from pilosa_tpu import observe
-
             rec = observe.take_last()
             resp["profile"] = rec.to_dict() if rec is not None else None
         self._json(req, resp)

@@ -175,7 +175,7 @@ class MultiStatsClient(StatsClient):
 
 #: Histogram bucket upper bounds: 1 / 2.5 / 5 per decade from 1e-6 to
 #: 5e9 — one fixed ladder wide enough for second-scale latencies
-#: (pilosa_query_latency), nanosecond timings (Timer feeds ns), and
+#: (pilosa_query_latency), nanosecond timings (observe.span feeds ns), and
 #: small value histograms (coalescer batch occupancy 1..32).  Fixed
 #: buckets keep observe() O(log B) with no per-metric configuration.
 BUCKETS: tuple[float, ...] = tuple(
@@ -344,19 +344,3 @@ def _prom_labels(tags: tuple, extra: tuple[str, str] | None = None) -> str:
     if extra is not None:
         pairs.append(f'{extra[0]}="{extra[1]}"')
     return "{" + ",".join(pairs) + "}"
-
-
-class Timer:
-    """Context manager feeding StatsClient.timing."""
-
-    def __init__(self, stats: StatsClient, name: str):
-        self.stats = stats
-        self.name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        self.stats.timing(self.name, time.perf_counter_ns() - self._t0)
-        return False

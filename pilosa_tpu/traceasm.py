@@ -3,28 +3,34 @@
 ``GET /debug/trace/{id}`` fans flight records in from every node
 (``parallel/cluster.fan_in`` + ``client.debug_json``, the
 ``/debug/cluster/*`` machinery) and this module joins them on the
-normalized trace id into ONE tree:
+normalized trace id into ONE tree — the origin record's own ``spans``
+(observe.span: ids, parents, both times), nested as recorded:
 
-    query (origin node)
-      admission.wait
-      coalescer.window
-      stage:translate
-      stage:execute            <- engine enum, launch count, tier notes
-        map                    <- per-node children from nodeTimings
-          node/node1  — remote subtree attached when that node's own
-          node/node2    flight record arrived in the fan-in
-          node/node2 (hedge loser) — the abandoned side of a hedge race
-        reduce                 <- execute minus map
-      stage:translateResults
+    query/<index>              <- the record's root span (http.request
+      admission.wait              behind a handler, else exec)
+      pql.parse
+      exec
+        translate
+        call.Count             <- engine enum, launch count, tier notes
+          map                  <- per-node children from nodeTimings
+            node/node1  — remote subtree attached when that node's own
+            node/node2    flight record arrived in the fan-in
+            node/node2 (hedge loser) — the abandoned side of a race
+          reduce
+        translateResults
+      serialize
       (unattributed)           <- filler so child walls sum EXACTLY
 
 Per-span wall times add up to the observed latency by construction:
 each level carries an explicit ``(unattributed)`` child absorbing the
-gap between the parent's wall and the sum of its measured children, so
-the accounting identity ``observedMs == sum(leaf walls)`` holds and a
-triage reader can see exactly how much time the recorder could NOT
-attribute.  Dead peers degrade to an ``errors`` entry, same contract
-as ``/debug/cluster/*``.
+gap between the parent's wall and the sum of its children, so the
+accounting identity ``observedMs == sum(leaf walls)`` holds and a
+triage reader can see exactly how much time no span names.  Children
+that ran beside their parent's thread (pool workers under ``map``,
+per-node walls that overlap) are shown, marked ``concurrent``, and
+left out of that sum: the parent's own wall already covers them.  Dead
+peers degrade to an ``errors`` entry, same contract as
+``/debug/cluster/*``.
 
 Pure functions over already-fetched JSON sections — no I/O here; the
 handler owns the fan-in and ticks ``observe.bump_trace`` counters.
@@ -47,114 +53,116 @@ def _span(name: str, ms: float, node: str = "", **attrs) -> dict:
     return d
 
 
+def _serial(span: dict) -> list[dict]:
+    return [c for c in span["children"] if not c.get("concurrent")]
+
+
 def _fill(parent: dict) -> None:
     """Append the ``(unattributed)`` child absorbing the gap between
     the parent wall and its children's summed walls — the invariant
     that makes every level's walls add up."""
-    accounted = sum(c["ms"] for c in parent["children"])
-    gap = parent["ms"] - accounted
-    if gap > _MIN_FILLER_MS:
+    kids = _serial(parent)
+    gap = parent["ms"] - sum(c["ms"] for c in kids)
+    if kids and gap > _MIN_FILLER_MS:
         parent["children"].append(
             _span("(unattributed)", gap, parent.get("node", "")))
 
 
 def _leaf_sum(span: dict) -> float:
-    if not span["children"]:
+    kids = _serial(span)
+    if not kids:
         return span["ms"]
-    return sum(_leaf_sum(c) for c in span["children"])
+    return sum(_leaf_sum(c) for c in kids)
 
 
-def _remote_subtree(rec: dict, node: str) -> dict:
-    """A remote node's own flight record rendered as the subtree under
-    the origin's per-node map span."""
-    sub = _span("remote/" + rec.get("index", ""),
-                rec.get("elapsedMs", 0.0), node,
-                pql=rec.get("pql", ""))
-    if rec.get("engine"):
-        sub["engine"] = rec["engine"]
-    sub["children"].extend(_stage_spans(rec, node, {}))
-    if rec.get("deviceLaunches"):
-        sub["launches"] = rec["deviceLaunches"]
-    _fill(sub)
-    return sub
-
-
-def _stage_spans(rec: dict, node: str,
-                 remote_by_node: dict[str, list[dict]]) -> list[dict]:
-    """The record's stage list as sibling spans, order-aware: the
-    recorder appends stages as they FINISH, and the shard fan-out runs
-    inside its execute call — so a ``map``/``map.fused`` entry belongs
-    to the next ``execute.*`` entry and must nest under it (rendering
-    both at the top level would double-count the map wall and break
-    the accounting identity)."""
-    out: list[dict] = []
-    pending_map: dict | None = None
-    for st in rec.get("stages", []):
-        name = st.get("name", "?")
-        if name in ("map", "map.fused"):
-            pending_map = st
+def _record_tree(rec: dict, node: str, name: str,
+                 remote_by_node: dict[str, list[dict]]) -> dict:
+    """One flight record's ``spans`` as a tree under a node called
+    ``name``: spans nest by parent id (one the record does not hold —
+    a span that never closed — hangs its children on the root), a
+    child on another thread than its parent is ``concurrent``, the
+    per-node walls and remote subtrees hang under the ``map`` span
+    that waited for them, hedge losers under its call."""
+    spans = rec.get("spans", [])
+    root_sp = min((s for s in spans if not s.get("parent")),
+                  key=lambda s: s.get("startNs", 0), default=None)
+    root = _span(name, rec.get("elapsedMs", 0.0) if root_sp is None
+                 else (root_sp["endNs"] - root_sp["startNs"]) / 1e6,
+                 node, pql=rec.get("pql", ""))
+    by_id = {}
+    for s in spans:
+        if s is root_sp:
+            by_id[s["id"]] = root
             continue
-        if name.startswith("execute"):
-            out.append(_execute_span(st, pending_map, rec, node,
-                                     remote_by_node))
-            pending_map = None
-        else:
-            out.append(_span("stage:" + name, st.get("ms", 0.0), node))
-    if pending_map is not None:  # map without an execute parent: keep
-        out.append(_span("stage:" + pending_map.get("name", "map"),
-                         pending_map.get("ms", 0.0), node))
-    return out
-
-
-def _execute_span(st: dict, map_st: dict | None, rec: dict, node: str,
-                  remote_by_node: dict[str, list[dict]]) -> dict:
-    """One execute stage: the shard map (per-node children off
-    nodeTimings, remote subtrees attached) plus the derived reduce
-    tail (execute minus map)."""
-    sp = _span("stage:" + st.get("name", "?"), st.get("ms", 0.0), node)
-    sp["engine"] = rec.get("engine", "")
-    if rec.get("deviceLaunches"):
-        sp["launches"] = rec["deviceLaunches"]
-    if rec.get("tier"):
-        sp["tier"] = rec["tier"]
+        extra = {k: v for k, v in s.items()
+                 if k not in ("id", "parent", "name", "startNs",
+                              "endNs", "thread")}
+        by_id[s["id"]] = _span(s["name"],
+                               (s["endNs"] - s["startNs"]) / 1e6, node,
+                               **extra)
+    raw = {s["id"]: s for s in spans}
+    for s in sorted(spans, key=lambda s: s.get("startNs", 0)):
+        if s is root_sp:
+            continue
+        me = by_id[s["id"]]
+        # thread 0 is the open root of an inline ?profile=1 rendering
+        over = raw.get(s.get("parent"), {}).get("thread")
+        if over and over != s.get("thread"):
+            me["concurrent"] = True
+        by_id.get(s.get("parent"), root)["children"].append(me)
+    calls = [sp for sp in by_id.values()
+             if sp["name"].startswith("call.")]
+    for sp in calls:
+        sp["engine"] = rec.get("engine", "")
+        if rec.get("deviceLaunches"):
+            sp["launches"] = rec["deviceLaunches"]
+        if rec.get("tier"):
+            sp["tier"] = rec["tier"]
+    maps = [sp for sp in by_id.values() if sp["name"] == "map"]
     timings = rec.get("nodeTimings", [])
-    # map wall: the recorded map stage when present (covers local
-    # shard work too), else the slowest node group (the scatter-gather
-    # critical path)
-    map_ms = (map_st.get("ms", 0.0) if map_st is not None
-              else max((t.get("ms", 0.0) for t in timings),
-                       default=0.0))
-    if map_st is not None or timings:
-        mp = _span(map_st.get("name", "map") if map_st is not None
-                   else "map", map_ms, node)
+    if timings and maps:
+        mp = maps[0]
+        peers = []
         for t in timings:
             peer = t.get("node", "?")
             child = _span("node/" + peer, t.get("ms", 0.0), node,
                           shards=t.get("shards"))
-            pool = remote_by_node.get(peer)
-            if pool:
-                child["children"].append(_remote_subtree(pool.pop(0),
-                                                         peer))
-                _fill(child)
-            mp["children"].append(child)
-        if mp["children"]:
-            _fill(mp)
-        sp["children"].append(mp)
-        sp["children"].append(
-            _span("reduce", sp["ms"] - map_ms, node))
+            _attach_remote(child, peer, remote_by_node)
+            peers.append(child)
+        if sum(c["ms"] for c in peers) > mp["ms"] + _MIN_FILLER_MS:
+            for c in peers:  # they overlapped: the map wall covers them
+                c["concurrent"] = True
+        mp["children"].extend(peers)
     for loser in rec.get("hedgeLosers", []):
         peer = loser.get("node", "?")
         lost = _span("node/" + peer + " (hedge loser)",
                      loser.get("ms", 0.0), node)
-        pool = remote_by_node.get(peer)
-        if pool:
-            lost["children"].append(_remote_subtree(pool.pop(0), peer))
-            _fill(lost)
+        _attach_remote(lost, peer, remote_by_node)
         # abandoned work is OFF the critical path: report it under the
-        # execute span but exclude it from the wall accounting
+        # call but exclude it from the wall accounting
         lost["offCriticalPath"] = True
-        sp.setdefault("abandoned", []).append(lost)
-    return sp
+        (calls[0] if calls else root).setdefault(
+            "abandoned", []).append(lost)
+    for sp in [root, *by_id.values()]:
+        _fill(sp)
+    return root
+
+
+def _attach_remote(span: dict, peer: str,
+                   remote_by_node: dict[str, list[dict]]) -> None:
+    """Hang the peer's own flight record, if the fan-in brought one,
+    under the span that waited for it."""
+    pool = remote_by_node.get(peer)
+    if pool:
+        rec = pool.pop(0)
+        sub = _record_tree(rec, peer, "remote/" + rec.get("index", ""),
+                           {})
+        if rec.get("engine"):
+            sub["engine"] = rec["engine"]
+        if rec.get("deviceLaunches"):
+            sub["launches"] = rec["deviceLaunches"]
+        span["children"].append(sub)
+        _fill(span)
 
 
 def assemble_trace(sections: dict, errors: dict,
@@ -194,27 +202,9 @@ def assemble_trace(sections: dict, errors: dict,
                              "unaccountedMs": 0.0}
         return out
 
-    root = _span("query/" + origin.get("index", ""),
-                 origin.get("elapsedMs", 0.0), origin_node,
-                 pql=origin.get("pql", ""))
-    adm = origin.get("admission", {})
-    if adm.get("queueWaitMs"):
-        root["children"].append(
-            _span("admission.wait", adm["queueWaitMs"], origin_node,
-                  **{"class": adm.get("class", "")}))
-    co = origin.get("coalescer", {})
-    if co:
-        root["children"].append(
-            _span("coalescer.window", co.get("queueWaitMs", 0.0),
-                  origin_node, batch=co.get("batch"),
-                  leader=co.get("leader")))
-    root["children"].extend(
-        _stage_spans(origin, origin_node, remote_by_node))
-    _fill(root)
-    for child in root["children"]:
-        if child["children"]:
-            _fill(child)
-
+    root = _record_tree(origin, origin_node,
+                        "query/" + origin.get("index", ""),
+                        remote_by_node)
     out["root"] = root
     observed = root["ms"]
     accounted = _leaf_sum(root)

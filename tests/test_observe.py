@@ -568,3 +568,263 @@ class TestCheckMetricsParser:
                 "h_sum 4.5\nh_count 3\n")
         out = check_text(good)
         assert out["samples"] == 4
+
+
+# ---------------------------------------------------------------------------
+# The span tree (observe.span): one recording primitive per request
+# ---------------------------------------------------------------------------
+
+
+def _tree(rec):
+    """{id: span dict} of a published record."""
+    return {s["id"]: s for s in rec.to_dict()["spans"]}
+
+
+def _names(rec):
+    return [s["name"] for s in rec.to_dict()["spans"]]
+
+
+@pytest.fixture
+def cex(ex):
+    """The executor with a coalescer attached (the conftest platform is
+    an 8-device mesh, so the coalesced launch is the mesh engine)."""
+    from pilosa_tpu.parallel.coalescer import Coalescer
+
+    ex.coalescer = Coalescer(window_s=0.01, max_batch=8, enabled=True,
+                             stats=_stats.MemStatsClient())
+    return ex
+
+
+class TestSpans:
+    def test_nothing_recorded_without_a_record(self, monkeypatch):
+        """No record, no Request, no timer, no recording tracer: the
+        shared no-op, no clock read, no annotation built."""
+        reads = []
+        monkeypatch.setattr(observe, "clock_ns",
+                            lambda: reads.append(1) or 0)
+        built = []
+        monkeypatch.setattr(observe, "_annotation",
+                            lambda *a, **k: built.append(a))
+        assert observe.span("stage", leaves=3) is observe.NOSPAN
+        with observe.span("launch") as sp:
+            sp.note(engine="dense")
+            sp.note_engine()
+        assert sp.id == 0 and not reads and not built
+        assert observe.open_span() == 0
+
+    def test_ids_parents_and_one_trace(self, ex):
+        ex.execute("i", "Count(Row(f=7))")
+        rec = ex.recorder.recent_records()[-1]
+        tree = _tree(rec)
+        assert len(tree) == len(rec.spans)  # ids are unique
+        [root] = [s for s in tree.values() if not s["parent"]]
+        assert root["name"] == "exec" and root["startNs"] == 0
+        # elapsedMs keeps its meaning: the exec span
+        assert rec.elapsed_ns == root["endNs"] - root["startNs"]
+        for s in tree.values():
+            if s is root:
+                continue
+            p = tree[s["parent"]]  # every parent is a span of the record
+            assert p["startNs"] <= s["startNs"] <= s["endNs"] <= p["endNs"]
+        by_name = {s["name"]: s for s in tree.values()}
+        assert by_name["call.Count"]["parent"] == root["id"]
+        for child in ("cache.probe", "map.fused"):
+            assert by_name[child]["parent"] == by_name["call.Count"]["id"]
+        # the choice of engine: eligibility under the call, the
+        # container plan under the fused group
+        assert [tree[s["parent"]]["name"] for s in tree.values()
+                if s["name"] == "plan"] == ["call.Count", "map.fused"]
+        for child in ("stage", "launch", "reduce"):
+            assert by_name[child]["parent"] == by_name["map.fused"]["id"]
+        for child in ("launch.dispatch", "launch.ready"):
+            assert by_name[child]["parent"] == by_name["launch"]["id"]
+        assert by_name["stage"]["leaves"] == 1
+        assert by_name["launch"]["engine"] == rec.engine
+
+    def test_self_times_of_a_level_sum_to_the_parent(self, ex):
+        ex.execute("i", "Count(Row(f=7))")
+        tree = _tree(ex.recorder.recent_records()[-1])
+        for p in tree.values():
+            kids = [s for s in tree.values() if s["parent"] == p["id"]]
+            if not kids:
+                continue
+            covered = sum(s["endNs"] - s["startNs"] for s in kids)
+            self_ns = (p["endNs"] - p["startNs"]) - covered
+            # one thread, children in sequence: self time is what is
+            # left, and never negative
+            assert self_ns >= 0, (p["name"], self_ns)
+            assert self_ns + covered == p["endNs"] - p["startNs"]
+
+    def test_stages_render_from_the_spans(self, ex):
+        ex.execute("i", "Count(Row(f=7))")
+        rec = ex.recorder.recent_records()[-1]
+        d = rec.to_dict()
+        by_name = {s["name"]: s for s in d["spans"]}
+        stages = {s["name"]: s["ms"] for s in d["stages"]}
+        assert list(stages) == ["translate", "map.fused",
+                                "execute.Count", "translateResults"]
+        call = by_name["call.Count"]
+        assert stages["execute.Count"] == round(
+            (call["endNs"] - call["startNs"]) / 1e6, 3)
+        assert not hasattr(rec, "note_stage")
+
+    def test_pool_workers_attach_under_the_map_span(self, ex):
+        ex.fuse_shards = False
+        ex.execute("i", "TopN(f, n=2)")
+        rec = ex.recorder.recent_records()[-1]
+        tree = _tree(rec)
+        [mp] = [s for s in tree.values() if s["name"] == "map"]
+        launches = [s for s in tree.values() if s["name"] == "launch"]
+        assert len(launches) == 3  # one matrix scan a shard
+        assert {s["parent"] for s in launches} == {mp["id"]}
+        # the workers are other threads than the one that waits
+        assert len({s["thread"] for s in launches} | {mp["thread"]}) > 1
+        for s in launches:
+            assert mp["startNs"] <= s["startNs"] <= s["endNs"] <= mp["endNs"]
+
+    def test_request_scope_is_adopted_not_doubled(self, ex):
+        """What the handler records before Executor.execute and after
+        it lands on the ONE record, under the root http.request."""
+        n0 = len(ex.recorder.recent_records())
+        with observe.Request() as rq:
+            rq.admission = {"class": "query", "queue_wait_ns": 5}
+            with observe.span("admission.wait"):
+                pass
+            ex.execute("i", "Count(Row(f=7))")
+            rec = ex.recorder.recent_records()[-1]
+            # inline rendering: the root is still open
+            [root] = [s for s in rec.to_dict()["spans"]
+                      if not s["parent"]]
+            assert root["name"] == "http.request" and root["open"] is True
+            with observe.span("serialize"):
+                pass
+        assert len(ex.recorder.recent_records()) == n0 + 1
+        d = rec.to_dict()
+        tree = {s["id"]: s for s in d["spans"]}
+        [root] = [s for s in tree.values() if not s["parent"]]
+        assert root["id"] == 1 and "open" not in root
+        assert d["rootStartNs"] == rq.start_ns
+        assert d["admission"]["class"] == "query"
+        names = [s["name"] for s in d["spans"]]
+        assert names[:2] == ["http.request", "admission.wait"]
+        assert "pql.parse" in names and names[-1] == "serialize"
+        by_name = {s["name"]: s for s in tree.values()}
+        for child in ("admission.wait", "pql.parse", "exec", "serialize"):
+            assert by_name[child]["parent"] == 1
+        assert root["endNs"] >= by_name["serialize"]["endNs"]
+
+    def test_the_cap(self, ex, monkeypatch):
+        monkeypatch.setattr(observe, "MAX_SPANS", 6)
+        ex.execute("i", "Count(Row(f=7))")
+        rec = ex.recorder.recent_records()[-1]
+        assert len(rec.spans) == 7  # six, and the exec span itself
+        assert "exec" in _names(rec)
+
+    def test_follower_links_to_its_leader(self, cex):
+        qs = ["Count(Row(f=7))", "Count(Union(Row(f=7), Row(f=8)))",
+              "Count(Intersect(Row(f=7), Row(f=8)))"]
+        ts = [threading.Thread(target=cex.execute,
+                               args=("i", q),
+                               kwargs={"opt": None}) for q in qs]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        recs = cex.recorder.recent_records()[-3:]
+        leaders = [r for r in recs if r.coalesce and r.coalesce["leader"]
+                   and r.coalesce["batch"] > 1]
+        followers = [r for r in recs
+                     if r.coalesce and not r.coalesce["leader"]]
+        assert leaders and followers
+        for fo in followers:
+            by = {s["name"]: s for s in fo.to_dict()["spans"]}
+            trace, sid = by["launch"]["link"]
+            [ld] = [r for r in leaders if r.trace_id == trace]
+            lspan = _tree(ld)[sid]
+            assert lspan["name"] == "launch"
+            assert lspan["batch"] == by["launch"]["batch"] > 1
+            # the leader's times, on the shared clock
+            root_f = fo.to_dict()["rootStartNs"]
+            root_l = ld.to_dict()["rootStartNs"]
+            assert root_f + by["launch"]["endNs"] == \
+                root_l + lspan["endNs"]
+            # a follower dispatched nothing itself
+            assert "launch.dispatch" not in by
+            assert by["coalesce.wait"]["endNs"] == by["launch"]["startNs"]
+            assert by["reduce"]["startNs"] == by["launch"]["endNs"]
+
+    def test_clock_reads_per_coalesced_count_are_pinned(self, cex,
+                                                        monkeypatch):
+        """The budget of ISSUE 24: what one coalesced Count (a leader
+        that flushes alone, cache probe and fill included) reads of the
+        span clock from Executor.execute in, under a handler Request.
+        A new span on this path moves the number: say why in the PR."""
+        q = "Count(Union(Row(f=7), Row(f=8)))"
+        cex.execute("i", q)  # compile, import, stage
+        from pilosa_tpu.runtime import resultcache
+
+        resultcache.reset()
+        real = observe.clock_ns
+        reads = []
+
+        def counting():
+            reads.append(1)
+            return real()
+
+        monkeypatch.setattr(observe, "clock_ns", counting)
+        with observe.Request():
+            cex.execute("i", q)
+        monkeypatch.setattr(observe, "clock_ns", real)
+        rec = cex.recorder.recent_records()[-1]
+        assert rec.path == "coalesced" and not rec.cached
+        assert len(reads) == CLOCK_READS_PER_COALESCED_COUNT, _names(rec)
+
+
+#: Request 2, pql.parse 2, the record's begin 1 and publish 1 (the exec
+#: span), translate 2, call.Count 2, plan 2, cache.probe 2, stage 2, the
+#: submit 1 (coalesce.wait starts there) and its end 1, the flush 1
+#: (launch starts there) and its end 1, launch.dispatch 2, launch.ready
+#: 1 (it starts where dispatch ended), the end of reduce 1 (it starts
+#: where launch ended), cache.fill 2, translateResults 2.  Behind the
+#: handler: admission.wait, http.read and serialize, 2 each, so 34; the
+#: parent commit read the clock 19 times on this path.
+CLOCK_READS_PER_COALESCED_COUNT = 28
+
+
+@pytest.mark.parametrize("pql,kind", [
+    ("TopN(f, n=2)", "topn"),
+    ("GroupBy(Rows(f))", "groupby"),
+    ("Sum(field=v)", "sum"),
+    ("Max(field=v)", "max"),
+    ("Row(v > 1)", "range"),
+])
+def test_engine_and_path_on_every_call_kind(tmp_path, pql, kind):
+    """TopN, GroupBy, Sum/Min/Max and range records read engine=None
+    path=None before ISSUE 24; their dispatch sites open the same
+    launch span as a Count and stamp the engine."""
+    from pilosa_tpu.models.field import FieldOptions, FieldType
+
+    holder = Holder(str(tmp_path / kind))
+    idx = holder.create_index("i")
+    idx.create_field("f")
+    idx.create_field("v", FieldOptions(type=FieldType.INT, min=0, max=100))
+    e = Executor(holder)
+    try:
+        for s in range(3):
+            for k in range(4):
+                e.execute("i", f"Set({s * SHARD_WIDTH + k}, f={k % 2})")
+                e.execute("i", f"Set({s * SHARD_WIDTH + k}, v={k + 1})")
+        e.execute("i", pql)
+        rec = e.recorder.recent_records()[-1]
+        d = rec.to_dict()
+        assert d["path"] in ("fused", "per-shard"), d.get("path")
+        from pilosa_tpu import perfobs
+
+        assert d["engine"] in perfobs.ENGINES, d.get("engine")
+        launches = [s for s in d["spans"] if s["name"] == "launch"]
+        assert launches and all(s.get("engine") for s in launches)
+        ids = {s["id"] for s in launches}
+        assert {"launch.dispatch", "launch.ready"} <= {
+            s["name"] for s in d["spans"] if s["parent"] in ids}
+    finally:
+        holder.close()

@@ -431,3 +431,104 @@ class TestHTTP:
         assert cfg2.observe.device_peak_gbps == 1228.0
         assert cfg2.observe.profiler_max_seconds == 5.0
         assert cfg2.cost.shadow is False
+
+
+# ---------------------------------------------------------------------------
+# One clock with the device trace (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+
+class TestCaptureClocks:
+    @pytest.fixture
+    def fake_trace(self, monkeypatch):
+        import jax
+
+        calls = {}
+
+        def start_trace(log_dir, **kw):
+            calls["start"] = (log_dir, kw)
+
+        monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+        monkeypatch.setattr(jax.profiler, "stop_trace",
+                            lambda: calls.setdefault("stop", True))
+        return calls
+
+    def test_start_and_stop_return_both_clocks(self, tmp_path, fake_trace):
+        import time
+
+        lo_perf, lo_unix = time.perf_counter_ns(), time.time_ns()
+        info = perfobs.profiler_start(str(tmp_path), max_seconds=0)
+        out = perfobs.profiler_stop()
+        hi_perf, hi_unix = time.perf_counter_ns(), time.time_ns()
+        assert lo_perf <= info["perfCounterNs"] <= out["perfCounterNs"] \
+            <= hi_perf
+        assert lo_unix <= info["unixNs"] <= out["unixNs"] <= hi_unix
+        assert fake_trace["stop"] is True
+
+    def test_python_tracer_off_host_tracer_on(self, tmp_path, fake_trace):
+        perfobs.profiler_start(str(tmp_path), max_seconds=0)
+        perfobs.profiler_stop()
+        log_dir, kw = fake_trace["start"]
+        assert log_dir.startswith(str(tmp_path))
+        opts = kw["profiler_options"]
+        assert opts.python_tracer_level == 0
+        assert opts.host_tracer_level >= 1
+
+    def test_spans_annotate_only_while_a_capture_runs(self, tmp_path,
+                                                      fake_trace,
+                                                      monkeypatch):
+        from pilosa_tpu import observe
+
+        seen = []
+
+        class Ann:
+            def __init__(self, name, **kw):
+                seen.append((name, kw))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(observe, "_annotation", Ann)
+        rec = observe.FlightRecorder().begin("i", "Count(Row(f=1))")
+        with observe.attach(rec):
+            with observe.span("stage"):
+                pass
+            assert not seen and observe.capturing is False
+            perfobs.profiler_start(str(tmp_path), max_seconds=0)
+            assert observe.capturing is True
+            with observe.span("stage"):
+                s0 = perfobs.t0()
+                perfobs.sample("dense", np.zeros(1, dtype=np.uint32),
+                               s0, nbytes=4)
+            perfobs.profiler_stop()
+            with observe.span("stage"):
+                pass
+        assert observe.capturing is False
+        assert [n for n, _ in seen] == ["stage", "launch.dispatch",
+                                        "launch.ready"]
+        assert all(kw == {"rid": rec.trace_id} for _, kw in seen)
+
+    def test_sample_takes_its_clock_from_the_launch_span(self, monkeypatch):
+        """With a record, t0()/sample() read no clock of their own: the
+        cost-table wall is launch.dispatch's start to launch.ready's
+        end."""
+        from pilosa_tpu import observe
+
+        def boom():
+            raise AssertionError("perfobs read its own clock")
+
+        monkeypatch.setattr(perfobs, "_clock", boom)
+        rec = observe.FlightRecorder().begin("i", "Count(Row(f=1))")
+        with observe.attach(rec):
+            s0 = perfobs.t0()
+            perfobs.sample("dense", np.zeros(1, dtype=np.uint32), s0,
+                           nbytes=4096, work=1024)
+        by = {s[2]: s for s in rec.spans}
+        d, r = by["launch.dispatch"], by["launch.ready"]
+        assert d[4] == r[3]  # ready starts where dispatch ended
+        [row] = perfobs.cost_debug()["table"]
+        assert row["lastUs"] == pytest.approx((r[4] - d[3]) / 1e3)
+        assert rec.engine == "dense"

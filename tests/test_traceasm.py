@@ -153,16 +153,27 @@ class TestEventJournal:
 # ============================================== pure trace assembly
 
 
+def _sp(sid, parent, name, start_ms, end_ms, thread=7, **counts):
+    """One rendered span (QueryRecord.to_dict's shape), times in ms."""
+    return {"id": sid, "parent": parent, "name": name,
+            "startNs": int(start_ms * 1e6), "endNs": int(end_ms * 1e6),
+            "thread": thread, **counts}
+
+
 def _origin_rec(**over) -> dict:
     rec = {
         "traceID": "a" * 32, "index": "i", "pql": "Count(Row(f=1))",
-        "elapsedMs": 10.0,
+        "elapsedMs": 8.9,
         "admission": {"class": "query", "queueWaitMs": 1.0},
-        "stages": [
-            {"name": "translate", "ms": 0.5},
-            {"name": "map", "ms": 6.0},
-            {"name": "execute.Count", "ms": 8.0},
-            {"name": "translateResults", "ms": 0.2},
+        "spans": [
+            _sp(1, 0, "http.request", 0.0, 10.0),
+            _sp(2, 1, "admission.wait", 0.0, 1.0),
+            _sp(3, 1, "exec", 1.0, 9.9),
+            _sp(4, 3, "translate", 1.0, 1.5),
+            _sp(5, 3, "call.Count", 1.5, 9.5),
+            _sp(6, 5, "map", 1.5, 7.5),
+            _sp(7, 5, "reduce", 7.5, 9.5),
+            _sp(8, 3, "translateResults", 9.5, 9.7),
         ],
         "engine": "fused", "deviceLaunches": 3,
         "nodeTimings": [{"node": "node1", "ms": 4.0, "shards": 2},
@@ -176,8 +187,9 @@ def _remote_rec(**over) -> dict:
     rec = {
         "traceID": "a" * 32, "index": "i", "pql": "Count(Row(f=1))",
         "elapsedMs": 3.0, "remote": True, "engine": "fused",
-        "stages": [{"name": "map", "ms": 2.5},
-                   {"name": "execute.Count", "ms": 2.8}],
+        "spans": [_sp(1, 0, "exec", 0.0, 3.0),
+                  _sp(2, 1, "call.Count", 0.1, 2.9),
+                  _sp(3, 2, "map", 0.2, 2.7)],
     }
     rec.update(over)
     return rec
@@ -210,11 +222,10 @@ class TestTraceAssembly:
         assert out["origin"] == "node0"
         root = out["root"]
         assert root["name"] == "query/i" and root["ms"] == 10.0
-        # the map stage nests UNDER its execute stage (the recorder
-        # appends stages as they finish, so rendering both at the top
-        # level would double-count the map wall)
-        [ex] = [c for c in root["children"]
-                if c["name"] == "stage:execute.Count"]
+        # the map span nests UNDER its call span, by its parent id
+        # (rendering both at one level would double-count the map wall)
+        [ex] = [c for c in _find(root, "call.Count")
+                if c["node"] == "node0"]
         assert ex["engine"] == "fused" and ex["launches"] == 3
         [mp] = [c for c in ex["children"] if c["name"] == "map"]
         assert mp["ms"] == 6.0
@@ -222,7 +233,8 @@ class TestTraceAssembly:
             "(unattributed)"} == {"node/node1", "node/local"}
         [rd] = [c for c in ex["children"] if c["name"] == "reduce"]
         assert rd["ms"] == 2.0
-        assert not _find(root, "stage:map")  # never a top-level sibling
+        # never a sibling of its call
+        assert "map" not in {c["name"] for c in root["children"]}
         # node1's own flight record hangs under the per-node map child
         [rsub] = _find(root, "remote/i")
         assert rsub["node"] == "node1" and rsub["ms"] == 3.0
@@ -246,8 +258,8 @@ class TestTraceAssembly:
             "node2": {"records": [_remote_rec(elapsedMs=2.0)]},
         }
         out = traceasm.assemble_trace(sections, {}, "a" * 32)
-        [ex] = [c for c in out["root"]["children"]
-                if c["name"] == "stage:execute.Count"]
+        [ex] = [c for c in _find(out["root"], "call.Count")
+                if "abandoned" in c]
         [lost] = ex["abandoned"]
         assert lost["name"] == "node/node2 (hedge loser)"
         assert lost["offCriticalPath"] is True and lost["ms"] == 5.0
@@ -276,15 +288,52 @@ class TestTraceAssembly:
         assert out["errors"] == errors
         assert out["root"] is not None  # partial assembly still lands
 
-    def test_trailing_map_without_execute_kept(self):
-        origin = _origin_rec(stages=[{"name": "translate", "ms": 0.5},
-                                     {"name": "map", "ms": 6.0}],
+    def test_map_without_a_call_parent_kept(self):
+        """A map span whose call never closed (the parent id names no
+        span of the record) hangs on the root, not silently dropped."""
+        origin = _origin_rec(spans=[_sp(1, 0, "exec", 0.0, 8.0),
+                                    _sp(2, 1, "translate", 0.0, 0.5),
+                                    _sp(4, 3, "map", 0.5, 6.5)],
                              nodeTimings=[])
         out = traceasm.assemble_trace(
             {"node0": {"records": [origin]}}, {}, "a" * 32)
-        assert _find(out["root"], "stage:map")  # not silently dropped
+        assert [c["name"] for c in out["root"]["children"]] == [
+            "translate", "map", "(unattributed)"]
         acc = out["accounting"]
-        assert acc["observedMs"] == acc["accountedMs"]
+        assert acc["observedMs"] == acc["accountedMs"] == 8.0
+
+    def test_worker_spans_are_concurrent_and_follower_links(self):
+        """Pool workers' spans under ``map`` run beside the thread that
+        waits for them: shown, marked, and left out of the wall sum.  A
+        follower's launch keeps its link to the leader's span."""
+        origin = _origin_rec(nodeTimings=[], spans=[
+            _sp(1, 0, "exec", 0.0, 10.0),
+            _sp(2, 1, "call.Count", 0.0, 10.0),
+            _sp(3, 2, "map", 1.0, 5.0),
+            _sp(4, 3, "launch", 1.0, 4.0, thread=8),
+            _sp(5, 3, "launch", 1.0, 4.5, thread=9),
+            _sp(6, 2, "launch", 5.0, 9.0, link=["b" * 32, 12]),
+        ])
+        out = traceasm.assemble_trace(
+            {"node0": {"records": [origin]}}, {}, "a" * 32)
+        [mp] = _find(out["root"], "map")
+        assert [c.get("concurrent") for c in mp["children"]] == [
+            True, True]
+        [linked] = [s for s in _find(out["root"], "launch")
+                    if "link" in s]
+        assert linked["link"] == ["b" * 32, 12]
+        acc = out["accounting"]
+        assert acc["observedMs"] == acc["accountedMs"] == 10.0
+
+    def test_record_without_spans_is_one_leaf(self):
+        """A peer that predates spans (or a shed record): its elapsedMs
+        is the whole tree."""
+        origin = {"traceID": "a" * 32, "index": "i", "pql": "",
+                  "elapsedMs": 4.0}
+        out = traceasm.assemble_trace(
+            {"node0": {"records": [origin]}}, {}, "a" * 32)
+        assert out["root"]["ms"] == 4.0 and not out["root"]["children"]
+        assert out["accounting"]["accountedMs"] == 4.0
 
     def test_short_trace_id_normalizes(self):
         out = traceasm.assemble_trace({}, {}, "abc123")
