@@ -165,8 +165,12 @@ class CoalescerConfig:
     analog; the serving-side batching lever for the TPU dispatch
     floor, parallel/coalescer.py).  ``enabled`` is tri-state:
     ``"auto"`` turns batching on only when an accelerator is attached
-    (on a host-mode CPU dispatch is free and the window would only add
-    latency); TOML booleans / "true"/"false" force it."""
+    (on a host-mode CPU dispatch is free and batching buys nothing);
+    TOML booleans / "true"/"false" force it.  ``window_ms`` caps the
+    wait of a batch's first query BEHIND A LAUNCH IN FLIGHT — it
+    collects companions until that launch ends, ``max_batch`` is
+    reached or the cap runs out; with nothing in flight it dispatches
+    at once, so a sequential client never pays the window."""
 
     enabled: str = "auto"  # auto | true | false
     window_ms: float = 2.0

@@ -739,6 +739,9 @@ class QueryRecord:
                 "launchMs": round(c["launch_ns"] / ms, 3),
                 "leader": c.get("leader", True),
             }
+            if c.get("why"):
+                # what ended the leader's wait: idle|busy|full|cap
+                d["coalescer"]["why"] = c["why"]
             if c.get("launch_trace"):
                 # a follower names the batch leader's trace — the
                 # span that owns the shared device launch

@@ -14,10 +14,24 @@ Mechanics
 ---------
 Fused-eligible `Count(tree)` queries stage their operands on the calling
 thread (`Executor._fused_expr`: canonical tree SHAPE + leaf stacks),
-then meet in a bucket.  The first arrival becomes the bucket's LEADER
-and waits up to ``window_s`` for followers; hitting ``max_batch`` seals
-the bucket early.  The leader runs ONE launch for the sealed bucket and
-scatters the per-query count rows back to every waiter's future.
+then meet in a bucket.  The first arrival becomes the bucket's LEADER.
+A batch is worth a wait only while the device (or the host thread that
+feeds it) is busy: queries that arrive during a launch could not start
+anyway, so collecting them is free.  The coalescer therefore counts its
+own launches in flight (``Coalescer.in_flight``), and the leader's wait
+depends on that count, not on a clock:
+
+- no launch in flight: the leader seals its bucket and flushes at once
+  (``why = idle``) — a read that batches with nobody pays no window;
+- one or more in flight: it waits until the first of: the bucket fills
+  (``max_batch``, ``why = full``); the in-flight count reaches zero
+  (``why = busy``: every waiting leader wakes and flushes, buckets of
+  different keys concurrently); ``window_s`` runs out (``why = cap``).
+
+``window_s`` is a cap on the wait behind a launch, never a floor.  The
+leader runs ONE launch for the sealed bucket and scatters the per-query
+count rows back to every waiter's future.  Launches that bypass the
+coalescer (TopN, GroupBy, BSI, range) do not count as in flight.
 
 Bucketing is two-tier:
 
@@ -43,14 +57,14 @@ bit-exact against the unbatched path; a batch of one takes the
 identical single-query program (passthrough).
 
 Enablement: OFF in host mode (single CPU device — dispatch is a Python
-call there, batching buys nothing and the window would only add
-latency); ON by default when an accelerator is attached.  The server
-knobs live under ``[coalescer]`` and ``[ragged]``
-(docs/configuration.md).
+call there and batching buys nothing); ON by default when an
+accelerator is attached.  The server knobs live under ``[coalescer]``
+and ``[ragged]`` (docs/configuration.md).
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from concurrent.futures import Future
 
@@ -85,8 +99,14 @@ def resolve_enabled(mode) -> bool:
     return not bm.host_mode()
 
 
+#: what ended a leader's wait: ``idle`` (no launch in flight, flushed
+#: at once), ``busy`` (waited out the launches in flight), ``full``
+#: (``max_batch``), ``cap`` (``window_s`` ran out behind a launch)
+FLUSH_WHY = ("idle", "busy", "full", "cap")
+
+
 class _Bucket:
-    __slots__ = ("items", "full", "sealed",
+    __slots__ = ("items", "sealed", "why",
                  "n_final", "shapes_final", "tape_final", "vm_final",
                  "flush_t0", "launch_ns", "engine", "would_choose",
                  "flush_trace", "launch_span")
@@ -94,8 +114,10 @@ class _Bucket:
     def __init__(self):
         # _Entry per enqueued query
         self.items: list[_Entry] = []
-        self.full = threading.Event()
         self.sealed = False
+        # what ended the leader's wait: one of FLUSH_WHY, written under
+        # the coalescer's lock where the bucket is sealed
+        self.why = ""
         # flight-recorder breakdown, written by the leader BEFORE the
         # futures resolve (so every waiter may read them after
         # fut.result() without a lock): final batch occupancy, distinct
@@ -149,7 +171,8 @@ class _Entry:
 
 class Coalescer:
     """One per executor.  Thread-safe; queries block at most
-    ``window_s`` beyond their own execution time."""
+    ``window_s`` beyond their own execution time, and only behind a
+    launch in flight: ``window_s`` is a cap, never a floor."""
 
     def __init__(self, window_s: float = 0.002, max_batch: int = 32,
                  enabled="auto", stats=None, ragged: bool = True,
@@ -176,6 +199,14 @@ class Coalescer:
 
         self._lock = lockcheck.lock("coalescer")
         self._pending: dict[tuple, _Bucket] = {}
+        # launches in flight (in_flight scopes open) and the condition
+        # a waiting leader sleeps on: notified when the count reaches
+        # zero and when a follower fills a bucket.  Both, and the
+        # per-``why`` flush totals (/debug/ragged), live under _lock.
+        self.inflight = 0
+        self._drains = 0  # times the in-flight count fell to zero
+        self._wake = threading.Condition(self._lock)
+        self.flushes = dict.fromkeys(FLUSH_WHY, 0)
         # (shape, n_leaves) -> (Tape|None, fallback-counter-name|None):
         # shapes are canonical/hashable and few, so compile each once
         # instead of re-walking the tree (and re-raising TapeError for
@@ -317,18 +348,33 @@ class Coalescer:
                 self._pending[key] = bucket
             bucket.items.append(entry)
             if len(bucket.items) >= self.max_batch:
-                bucket.sealed = True
-                del self._pending[key]
-                bucket.full.set()
+                self._seal_locked(key, bucket, "full")
+                self._wake.notify_all()
         if leader:
-            # the window, as the leader sits through it; a follower's
-            # coalesce.wait is written below from the bucket's times
-            with _observe.span("coalesce.wait", start_ns=t0):
-                bucket.full.wait(self.window_s)
+            # the wait, as the leader sits through it: none unless a
+            # launch is in flight, then until the first of a full
+            # bucket, no launch left in flight, the window's cap.  The
+            # span is written even when it is zero long; a follower's
+            # coalesce.wait is written below from the bucket's times.
+            with _observe.span("coalesce.wait", start_ns=t0) as wait:
                 with self._lock:
                     if not bucket.sealed:
-                        bucket.sealed = True
-                        del self._pending[key]
+                        why = "idle"
+                        if self.inflight:
+                            # a launch that starts between the drain
+                            # and this thread's wake-up must not send
+                            # it back to sleep: wait for the drain
+                            # itself, not for a count of zero
+                            drains = self._drains
+                            self._wake.wait_for(
+                                lambda: (bucket.sealed
+                                         or self._drains != drains),
+                                self.window_s)
+                            why = ("busy" if self._drains != drains
+                                   else "cap")
+                        if not bucket.sealed:
+                            self._seal_locked(key, bucket, why)
+                wait.note(why=bucket.why)
             self._flush(bucket)
         counts = entry.fut.result()
         launch_end = bucket.flush_t0 + bucket.launch_ns
@@ -336,7 +382,8 @@ class Coalescer:
         if rec is not None and not leader:
             # a follower never dispatched: its launch span is the
             # LEADER's, by its times, linked to the span that owns it
-            rec.add_span("coalesce.wait", t0, bucket.flush_t0)
+            rec.add_span("coalesce.wait", t0, bucket.flush_t0,
+                         why=bucket.why)
             rec.add_span("launch", bucket.flush_t0, launch_end,
                          batch=bucket.n_final,
                          shapes=bucket.shapes_final,
@@ -363,6 +410,7 @@ class Coalescer:
                 "queue_wait_ns": max(0, bucket.flush_t0 - t0),
                 "launch_ns": bucket.launch_ns,
                 "leader": leader,
+                "why": bucket.why,
             }
             if bucket.flush_trace and not leader:
                 # a follower's record names the batch leader's trace —
@@ -394,6 +442,31 @@ class Coalescer:
         return total
 
     # ------------------------------------------------------------- flush
+
+    def _seal_locked(self, key, bucket: _Bucket, why: str) -> None:
+        """Close the bucket to appends (caller holds ``_lock``)."""
+        bucket.sealed = True
+        bucket.why = why
+        del self._pending[key]
+        self.flushes[why] += 1
+
+    @contextlib.contextmanager
+    def in_flight(self):
+        """One launch in flight for as long as the scope is open
+        (``_flush`` holds it around the batch's ``launch`` span).  A
+        leader that arrives meanwhile collects followers instead of
+        launching; when the last scope closes every waiting leader
+        wakes and flushes."""
+        with self._lock:
+            self.inflight += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self.inflight -= 1
+                if not self.inflight:
+                    self._drains += 1
+                    self._wake.notify_all()
 
     def _flush(self, bucket: _Bucket) -> None:
         """Leader-side: ONE launch for the sealed bucket, results
@@ -450,16 +523,20 @@ class Coalescer:
             if bucket.shapes_final > 1:
                 _tape.bump("coalescer.shape_flushes")
             self.stats.count("coalescer.dispatches", 1)
+            self.stats.count("coalescer.flush_" + bucket.why, 1)
             self.stats.histogram("coalescer.batch_occupancy", n)
             self.stats.histogram("coalescer.shape_distinct",
                                  bucket.shapes_final)
             # the batch's ONE launch span, on the leader's record (and,
             # under a recording tracer, the exported coalescer.flush
-            # span); it starts where the window ended
-            with _observe.span("launch", start_ns=bucket.flush_t0,
-                               timer=(self.stats, "coalescer.launch_ns"),
-                               export="coalescer.flush", batch=n,
-                               shapes=bucket.shapes_final) as span:
+            # span); it starts where the wait ended.  The launch is in
+            # flight until its results are on the host, and no longer
+            # by the time the futures resolve.
+            with self.in_flight(), _observe.span(
+                    "launch", start_ns=bucket.flush_t0,
+                    timer=(self.stats, "coalescer.launch_ns"),
+                    export="coalescer.flush", batch=n,
+                    shapes=bucket.shapes_final) as span:
                 bucket.flush_trace = tracing.active_trace_id()
                 lead = _observe.current()
                 if lead is not None:

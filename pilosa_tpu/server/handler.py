@@ -1441,6 +1441,8 @@ class Handler:
                 "maxTape": co.max_tape,
                 "maxLeaves": co.max_leaves,
                 "windowMs": co.window_s * 1e3,
+                "inFlight": co.inflight,
+                "flushes": dict(co.flushes),
                 "maxBatch": co.max_batch,
                 "vm": co.vm,
                 "vmMinDomain": co.vm_min_domain,

@@ -35,6 +35,8 @@ from pilosa_tpu.serve.deadline import Deadline, DeadlineExceededError
 from pilosa_tpu.server.client import InternalClient
 from pilosa_tpu.server.server import Server
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import (map_behind_launch, queued,
+                                  run_behind_launch, wait_until)
 
 N_SHARDS = 3
 
@@ -358,23 +360,20 @@ class TestCoalescerDeadline:
                 errs["a"] = e
 
         def follower():
-            time.sleep(0.08)  # join the leader's open bucket
+            wait_until(lambda: queued(co))  # join the leader's bucket
             try:
                 results["b"] = co.count(ex, idx, child, shards,
                                         deadline=Deadline(-1.0))
             except BaseException as e:  # noqa: BLE001
                 errs["b"] = e
 
-        ts = [threading.Thread(target=leader),
-              threading.Thread(target=follower)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(10)
+        run_behind_launch(co, [threading.Thread(target=leader),
+                               threading.Thread(target=follower)])
         assert "a" not in errs, errs
         assert results["a"] == expected
         assert isinstance(errs.get("b"), DeadlineExceededError)
         assert stats.snapshot().get("coalescer.deadline_dropped") == 1
+        assert stats.snapshot().get("coalescer.dispatches") == 1
 
     def test_tight_deadline_bypasses_window(self, ex):
         """remaining < 2*window: the query must not be held for
@@ -394,18 +393,8 @@ class TestCoalescerDeadline:
         stats = _stats.MemStatsClient()
         ex.coalescer = Coalescer(window_s=0.25, max_batch=4,
                                  enabled=True, stats=stats)
-        bar = threading.Barrier(4)
-        out = [None] * 4
-
-        def run(i):
-            bar.wait()
-            out[i] = ex.execute("i", QUERY)[0]
-
-        ts = [threading.Thread(target=run, args=(i,)) for i in range(4)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(10)
+        out = map_behind_launch(
+            ex.coalescer, lambda i: ex.execute("i", QUERY)[0], 4)
         assert len(set(out)) == 1
         occ = stats.snapshot().get("coalescer.batch_occupancy", {})
         assert occ.get("count", 0) >= 1

@@ -12,6 +12,7 @@ from __future__ import annotations
 import json
 import random
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -23,6 +24,8 @@ from pilosa_tpu.ops import expr
 from pilosa_tpu.parallel.coalescer import Coalescer, resolve_enabled
 from pilosa_tpu.parallel.executor import Executor
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import (map_behind_launch, queued,
+                                  run_behind_launch, wait_until)
 
 N_SHARDS = 6
 
@@ -134,7 +137,7 @@ class TestFusedDispatchCount:
 
 
 # ---------------------------------------------------------------------------
-# Coalescer: window semantics + bit-exactness
+# Coalescer: wait semantics + bit-exactness
 # ---------------------------------------------------------------------------
 
 
@@ -146,25 +149,10 @@ def _attach(ex, window_s=0.5, max_batch=8):
 
 
 def _run_concurrent(ex, queries):
-    bar = threading.Barrier(len(queries))
-    out = [None] * len(queries)
-    err = []
-
-    def run(i):
-        try:
-            bar.wait()
-            out[i] = ex.execute("i", queries[i])[0]
-        except BaseException as e:  # noqa: BLE001
-            err.append(e)
-
-    ts = [threading.Thread(target=run, args=(i,))
-          for i in range(len(queries))]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join()
-    assert not err, err
-    return out
+    """The queries, one thread each, batched behind a held launch."""
+    return map_behind_launch(
+        ex.coalescer, lambda i: ex.execute("i", queries[i])[0],
+        len(queries))
 
 
 class TestCoalescer:
@@ -193,13 +181,12 @@ class TestCoalescer:
         snap = stats.snapshot()
         assert snap["coalescer.dispatches"] == 1
         assert snap["coalescer.batch_occupancy"]["max"] == 8
+        assert snap["coalescer.flush_full"] == 1  # 8 is max_batch
 
     def test_flush_on_max_batch_before_window(self, ex):
-        """A full bucket seals immediately — the window is an upper
-        bound, not a floor."""
-        import time
-
-        _attach(ex, window_s=30.0, max_batch=4)
+        """A full bucket seals immediately, launch in flight or not —
+        the window is an upper bound, not a floor."""
+        stats = _attach(ex, window_s=30.0, max_batch=4)
         qs = [f"Count(Intersect(Row(f0={a}), Row(f1=0)))"
               for a in range(4)]
         expected = [_unbatched(ex, q) for q in qs]
@@ -207,22 +194,37 @@ class TestCoalescer:
         got = _run_concurrent(ex, qs)
         assert got == expected
         assert time.monotonic() - t0 < 15.0  # nowhere near the window
+        snap = stats.snapshot()
+        assert snap["coalescer.flush_full"] == 1
+        assert snap["coalescer.batch_occupancy"]["max"] == 4
 
     def test_flush_on_deadline_with_partial_batch(self, ex):
         """Fewer queries than max_batch still flush when the window
-        expires."""
+        runs out behind a launch that outlasts it: the cap."""
         stats = _attach(ex, window_s=0.05, max_batch=32)
         qs = ["Count(Intersect(Row(f0=1), Row(f1=1)))",
               "Count(Intersect(Row(f0=2), Row(f1=2)))"]
         expected = [_unbatched(ex, q) for q in qs]
-        got = _run_concurrent(ex, qs)
-        assert got == expected
+        got = [None, None]
+
+        def run(i):
+            got[i] = ex.execute("i", qs[i])[0]
+
+        ts = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+        with ex.coalescer.in_flight():
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=30)  # answered while the launch holds
+            assert got == expected
         snap = stats.snapshot()
         assert snap["coalescer.dispatches"] >= 1
+        assert snap["coalescer.flush_cap"] == snap["coalescer.dispatches"]
+        assert ex.coalescer.flushes["cap"] == snap["coalescer.flush_cap"]
 
     def test_single_query_passthrough(self, ex):
-        """A lone query runs the identical single-query program after
-        the window — same result, occupancy 1."""
+        """A lone query runs the identical single-query program —
+        same result, occupancy 1."""
         stats = _attach(ex, window_s=0.01, max_batch=32)
         q = "Count(Intersect(Row(f0=3), Row(f2=4)))"
         assert ex.execute("i", q)[0] == _unbatched(ex, q)
@@ -276,8 +278,6 @@ class TestCoalescer:
 
         stats = _attach(ex, window_s=5.0, max_batch=32)
         q = "Count(Intersect(Row(f0=1), Row(f1=1)))"
-        import time
-
         t0 = time.monotonic()
         got = ex.execute("i", q, opt=ExecOptions(coalesce=False))[0]
         assert time.monotonic() - t0 < 4.0
@@ -314,23 +314,18 @@ class TestCoalescer:
 
         expr.evaluate = boom
         try:
-            bar = threading.Barrier(2)
             errs = []
 
             def run(i):
-                bar.wait()
                 try:
                     ex.execute(
                         "i", f"Count(Intersect(Row(f0={i}), Row(f1=0)))")
                 except RuntimeError as e:
                     errs.append(str(e))
 
-            ts = [threading.Thread(target=run, args=(i,))
-                  for i in range(2)]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join(timeout=30)
+            run_behind_launch(
+                ex.coalescer, [threading.Thread(target=run, args=(i,))
+                               for i in range(2)], timeout=30)
         finally:
             expr.evaluate = orig
         assert errs == ["flush exploded", "flush exploded"]
@@ -345,6 +340,124 @@ class TestCoalescer:
         # "auto" on the 8-virtual-CPU-device test platform: not host
         # mode (multiple devices), so batching is on
         assert resolve_enabled("auto") == (not bm.host_mode())
+
+
+class TestInFlightWait:
+    """The leader's wait depends on whether a launch is in flight, not
+    on a clock."""
+
+    def test_lone_query_with_nothing_in_flight_does_not_wait(self, ex):
+        stats = _attach(ex, window_s=30.0, max_batch=32)
+        q = "Count(Intersect(Row(f0=3), Row(f2=4)))"
+        want = _unbatched(ex, q)
+        t0 = time.monotonic()
+        assert ex.execute("i", q)[0] == want
+        assert time.monotonic() - t0 < 15.0  # nowhere near the window
+        snap = stats.snapshot()
+        assert snap["coalescer.flush_idle"] == 1
+        assert snap["coalescer.batch_occupancy"]["max"] == 1
+        rec = ex.recorder.recent_records()[-1]
+        assert rec.coalesce["why"] == "idle" and rec.coalesce["leader"]
+        # the span is written though it is (next to) zero long
+        [wait] = [sp for sp in rec.spans if sp[2] == "coalesce.wait"]
+        assert wait[6] == {"why": "idle"}
+        assert wait[4] - wait[3] < 1e9
+        assert ex.coalescer.flushes == {"idle": 1, "busy": 0,
+                                        "full": 0, "cap": 0}
+
+    def test_arrivals_during_a_launch_share_the_next_one(self, ex):
+        stats = _attach(ex, window_s=30.0, max_batch=32)
+        qs = [f"Count(Intersect(Row(f0={a}), Row(f1=1)))"
+              for a in range(5)]
+        expected = [_unbatched(ex, q) for q in qs]
+        n0 = len(ex.recorder.recent_records())
+        assert _run_concurrent(ex, qs) == expected
+        snap = stats.snapshot()
+        assert snap["coalescer.dispatches"] == 1
+        assert snap["coalescer.flush_busy"] == 1
+        assert snap["coalescer.batch_occupancy"]["max"] == 5
+        recs = ex.recorder.recent_records()[n0:]
+        assert [r.coalesce["why"] for r in recs] == ["busy"] * 5
+        assert sum(r.coalesce["leader"] for r in recs) == 1
+        for r in recs:  # leader's and followers' spans both say why
+            [wait] = [sp for sp in r.spans if sp[2] == "coalesce.wait"]
+            assert wait[6] == {"why": "busy"}
+        assert ex.coalescer.inflight == 0 and queued(ex.coalescer) == 0
+
+    def test_window_zero_flushes_at_once_behind_a_launch(self, ex):
+        stats = _attach(ex, window_s=0.0, max_batch=32)
+        q = "Count(Intersect(Row(f0=1), Row(f1=1)))"
+        want = _unbatched(ex, q)
+        with ex.coalescer.in_flight():
+            assert ex.execute("i", q)[0] == want
+        assert stats.snapshot()["coalescer.flush_cap"] == 1
+
+    def test_every_waiting_leader_flushes_when_the_count_drains(self, ex):
+        """Buckets of different keys wake together and launch
+        concurrently: the first one's launch must not send the second
+        leader back to sleep until the cap."""
+        _attach(ex, window_s=30.0, max_batch=32)
+        ex.coalescer.ragged = False  # per-shape keys: two buckets
+        qs = ["Count(Intersect(Row(f0=1), Row(f1=2)))",
+              "Count(Union(Row(f0=1), Row(f1=2), Row(f2=3)))"]
+        expected = [_unbatched(ex, q) for q in qs]
+        both = threading.Barrier(2)
+        orig = expr.evaluate
+
+        def meet(shape, leaves, **kw):
+            both.wait(timeout=20)  # broken if the other never launches
+            return orig(shape, leaves, **kw)
+
+        expr.evaluate = meet
+        try:
+            assert _run_concurrent(ex, qs) == expected
+        finally:
+            expr.evaluate = orig
+        assert ex.coalescer.flushes["busy"] == 2
+
+    def test_failed_flush_leaves_no_launch_in_flight(self, ex):
+        """A flush that raises still ends its launch: the count returns
+        to zero and the leader waiting behind it wakes and answers."""
+        stats = _attach(ex, window_s=30.0, max_batch=32)
+        co = ex.coalescer
+        q1 = "Count(Intersect(Row(f0=1), Row(f1=1)))"
+        q2 = "Count(Intersect(Row(f0=2), Row(f1=2)))"
+        want2 = _unbatched(ex, q2)
+        orig = expr.evaluate
+        calls = []
+
+        def first_explodes(shape, leaves, **kw):
+            calls.append(shape)
+            if len(calls) > 1:
+                return orig(shape, leaves, **kw)
+            # hold this launch until the second query waits behind it
+            wait_until(lambda: queued(co))
+            raise RuntimeError("flush exploded")
+
+        out = {}
+
+        def run(name, q):
+            try:
+                out[name] = ex.execute("i", q)[0]
+            except RuntimeError as e:
+                out[name] = str(e)
+
+        expr.evaluate = first_explodes
+        try:
+            t1 = threading.Thread(target=run, args=("a", q1))
+            t1.start()
+            wait_until(lambda: co.inflight)
+            t2 = threading.Thread(target=run, args=("b", q2))
+            t2.start()
+            t1.join(timeout=30)
+            t2.join(timeout=30)
+        finally:
+            expr.evaluate = orig
+        assert out == {"a": "flush exploded", "b": want2}
+        assert co.inflight == 0 and queued(co) == 0
+        snap = stats.snapshot()
+        assert snap["coalescer.flush_idle"] == 1
+        assert snap["coalescer.flush_busy"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -390,28 +503,21 @@ class TestHTTPConcurrency:
                 with urllib.request.urlopen(req, timeout=30) as resp:
                     return json.loads(resp.read())["results"][0]
 
-            out = [None] * len(qs)
-            errs = []
-            bar = threading.Barrier(len(qs))
-
-            def run(i):
-                try:
-                    bar.wait()
-                    out[i] = post(qs[i])
-                except BaseException as e:  # noqa: BLE001
-                    errs.append(e)
-
-            ts = [threading.Thread(target=run, args=(i,))
-                  for i in range(len(qs))]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join(timeout=60)
-            assert not errs, errs
+            out = map_behind_launch(
+                srv.node.executor.coalescer, lambda i: post(qs[i]),
+                len(qs))
             assert out == expected
             snap = srv.stats.snapshot()
             # batching engaged: strictly fewer launches than queries
             assert snap["coalescer.dispatches"] < len(qs)
+            with urllib.request.urlopen(f"{srv.uri}/debug/ragged",
+                                        timeout=30) as resp:
+                co = json.loads(resp.read())["coalescer"]
+            assert co["inFlight"] == 0 and co["windowMs"] == 50.0
+            assert set(co["flushes"]) == {"idle", "busy", "full", "cap"}
+            assert (sum(co["flushes"].values())
+                    == snap["coalescer.dispatches"])
+            assert co["flushes"]["busy"] + co["flushes"]["full"] >= 1
         finally:
             srv.close()
 

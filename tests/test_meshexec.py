@@ -335,9 +335,8 @@ class TestTapeMesh:
         """16 structurally distinct concurrent Counts through the
         ragged coalescer on the mesh: <= 2 launches, bit-exact, and a
         concurrent ?nomesh query NEVER shares their launch."""
-        import threading
-
         from pilosa_tpu.parallel.coalescer import Coalescer
+        from tests.coalesce_batch import map_behind_launch
 
         holder, ex, f, oracle = _mk(seed=6)
         try:
@@ -349,26 +348,14 @@ class TestTapeMesh:
                   for i in range(8)]
             expected = [ex.execute("i", q, opt=ExecOptions(
                 coalesce=False))[0] for q in qs]
-            out = [None] * len(qs)
-            errs = []
-            launch_counts = [0] * len(qs)
-
             def run(i):
-                try:
-                    with bm.dispatch_counter() as dc:
-                        out[i] = ex.execute("i", qs[i])[0]
-                    launch_counts[i] = dc.n
-                except Exception as e:  # noqa: BLE001
-                    errs.append(e)
+                with bm.dispatch_counter() as dc:
+                    got = ex.execute("i", qs[i])[0]
+                return got, dc.n
 
-            ts = [threading.Thread(target=run, args=(i,))
-                  for i in range(len(qs))]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join()
-            assert not errs, errs
-            assert out == expected
+            res = map_behind_launch(ex.coalescer, run, len(qs))
+            assert [r[0] for r in res] == expected
+            launch_counts = [r[1] for r in res]
             assert sum(launch_counts) <= 2, launch_counts
         finally:
             holder.close()

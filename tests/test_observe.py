@@ -19,6 +19,7 @@ from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.parallel.executor import Executor
 from pilosa_tpu.server.server import Server
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import map_behind_launch, run_behind_launch
 
 
 def _post(uri, path, obj=None):
@@ -461,31 +462,19 @@ class TestCoalescerObservability:
                 e.execute("i", f"Set({s * SHARD_WIDTH + k}, f=1)")
                 e.execute("i", f"Set({s * SHARD_WIDTH + k + 8}, f=2)")
         n_threads = 4
-        errs: list = []
-        barrier = threading.Barrier(n_threads)
 
         # DISTINCT same-shape queries: identical concurrent queries
         # now single-flight at the result cache (only the leader
         # reaches the coalescer; followers record as cache hits), so
         # observing per-member batch context needs distinct keys —
         # same canonical tree shape, different row ids, one batch.
-        def worker(a, b):
-            try:
-                barrier.wait()
-                got = e.execute(
-                    "i", f"Count(Intersect(Row(f={a}), Row(f={b})))")[0]
-                assert got == 0
-            except BaseException as exc:  # noqa: BLE001
-                errs.append(exc)
+        def worker(i):
+            a, b = 1 + 2 * i, 2 + 2 * i
+            return e.execute(
+                "i", f"Count(Intersect(Row(f={a}), Row(f={b})))")[0]
 
-        threads = [threading.Thread(target=worker,
-                                    args=(1 + 2 * i, 2 + 2 * i))
-                   for i in range(n_threads)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errs
+        assert map_behind_launch(e.coalescer, worker,
+                                 n_threads) == [0] * n_threads
         recs = [r for r in e.recorder.recent_records()
                 if r.path == "coalesced"]
         assert len(recs) == n_threads
@@ -497,13 +486,14 @@ class TestCoalescerObservability:
         # race — so check each record against its own role rather than
         # assuming recs[-1] is a follower
         base = {"batch", "shapes", "tape", "queueWaitMs", "launchMs",
-                "leader"}
+                "leader", "why"}
         for r in recs:
             d = r.to_dict()
             want = (base if d["coalescer"]["leader"]
                     else base | {"launchTrace"})
             assert set(d["coalescer"]) == want, d["coalescer"]
             assert d["coalescer"]["queueWaitMs"] >= 0
+            assert d["coalescer"]["why"] in ("busy", "full")
         # exactly one record per flush owns the shared launch
         assert sum(1 for r in recs if r.coalesce["leader"]) >= 1
         holder.close()
@@ -723,13 +713,11 @@ class TestSpans:
     def test_follower_links_to_its_leader(self, cex):
         qs = ["Count(Row(f=7))", "Count(Union(Row(f=7), Row(f=8)))",
               "Count(Intersect(Row(f=7), Row(f=8)))"]
-        ts = [threading.Thread(target=cex.execute,
-                               args=("i", q),
-                               kwargs={"opt": None}) for q in qs]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join()
+        run_behind_launch(
+            cex.coalescer, [threading.Thread(target=cex.execute,
+                                             args=("i", q),
+                                             kwargs={"opt": None})
+                            for q in qs])
         recs = cex.recorder.recent_records()[-3:]
         leaders = [r for r in recs if r.coalesce and r.coalesce["leader"]
                    and r.coalesce["batch"] > 1]
@@ -751,6 +739,7 @@ class TestSpans:
             # a follower dispatched nothing itself
             assert "launch.dispatch" not in by
             assert by["coalesce.wait"]["endNs"] == by["launch"]["startNs"]
+            assert by["coalesce.wait"]["why"] == "busy"
             assert by["reduce"]["startNs"] == by["launch"]["endNs"]
 
     def test_clock_reads_per_coalesced_count_are_pinned(self, cex,

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import json
 import random
-import threading
 import urllib.request
 
 import numpy as np
@@ -29,6 +28,7 @@ from pilosa_tpu.parallel.coalescer import Coalescer
 from pilosa_tpu.parallel.executor import Executor
 from pilosa_tpu.runtime import resultcache
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import map_behind_launch
 
 N_SHARDS = 4
 
@@ -97,32 +97,19 @@ SHAPES_16 = (
 
 
 def _run_concurrent_counting(ex, queries):
-    """Fire the queries concurrently, each worker under its own
-    thread-local dispatch counter; returns (results, total_launches).
+    """Fire the queries concurrently behind a held launch (so they
+    meet in their buckets), each worker under its own thread-local
+    dispatch counter; returns (results, total_launches).
     The batch's shared launch ticks the leader's counter only, so the
     SUM across workers is the true device-launch count of the wave."""
-    bar = threading.Barrier(len(queries))
-    out = [None] * len(queries)
-    launches = [0] * len(queries)
-    err = []
-
     def run(i):
-        try:
-            bar.wait()
-            with bm.dispatch_counter() as dc:
-                out[i] = ex.execute("i", queries[i])[0]
-            launches[i] = dc.n
-        except BaseException as e:  # noqa: BLE001
-            err.append(e)
+        with bm.dispatch_counter() as dc:
+            got = ex.execute("i", queries[i])[0]
+        return got, dc.n
 
-    ts = [threading.Thread(target=run, args=(i,))
-          for i in range(len(queries))]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join(timeout=120)
-    assert not err, err
-    return out, sum(launches)
+    res = map_behind_launch(ex.coalescer, run, len(queries),
+                            timeout=120)
+    return [r[0] for r in res], sum(r[1] for r in res)
 
 
 # ---------------------------------------------------------------------------
@@ -406,26 +393,13 @@ class TestRaggedCoalescer:
                 for _ in range(300)]
         f.import_bits(rows, cols)
         _attach(ex, window_s=2.0, max_batch=4)
-        bar = threading.Barrier(2)
-        out = {}
-        err = []
-
-        def run(name, q):
-            try:
-                bar.wait()
-                out[name] = ex.execute(name, q)[0]
-            except BaseException as e:  # noqa: BLE001
-                err.append(e)
-
         q_i = "Count(Intersect(Row(f0=1), Row(f1=2)))"
         q_j = "Count(Union(Row(g=0), Row(g=1)))"
-        ts = [threading.Thread(target=run, args=("i", q_i)),
-              threading.Thread(target=run, args=("j", q_j))]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=120)
-        assert not err, err
+        wave = [("i", q_i), ("j", q_j)]
+        got = map_behind_launch(
+            ex.coalescer, lambda k: ex.execute(*wave[k])[0], 2,
+            timeout=120)
+        out = {"i": got[0], "j": got[1]}
         assert out["i"] == _unbatched(ex, q_i)
         ex.fuse_shards = False
         try:
@@ -494,24 +468,9 @@ class TestHTTP:
                 with urllib.request.urlopen(req, timeout=60) as resp:
                     return json.loads(resp.read())["results"][0]
 
-            out = [None] * len(qs)
-            errs = []
-            bar = threading.Barrier(len(qs))
-
-            def run(i):
-                try:
-                    bar.wait()
-                    out[i] = post(qs[i])
-                except BaseException as e:  # noqa: BLE001
-                    errs.append(e)
-
-            ts = [threading.Thread(target=run, args=(i,))
-                  for i in range(len(qs))]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join(timeout=120)
-            assert not errs, errs
+            out = map_behind_launch(
+                srv.node.executor.coalescer, lambda i: post(qs[i]),
+                len(qs), timeout=120)
             assert out == expected
             snap = srv.stats.snapshot()
             assert snap["coalescer.dispatches"] < len(qs)

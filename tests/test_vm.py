@@ -22,7 +22,6 @@ from __future__ import annotations
 import json
 import random
 import tempfile
-import threading
 import urllib.request
 
 import numpy as np
@@ -40,6 +39,7 @@ from pilosa_tpu.parallel.coalescer import Coalescer
 from pilosa_tpu.parallel.executor import ExecOptions, Executor
 from pilosa_tpu.runtime import resultcache
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import map_behind_launch
 from tests.naive import NaiveBitmap
 
 W = SHARD_WIDTH
@@ -189,31 +189,18 @@ def _unbatched(ex, q):
 
 
 def _run_concurrent(ex, queries, opt=VMOPT):
-    """Barrier-fire the queries; returns (results, flattened launch
+    """Fire the queries behind a held launch (so they meet in one
+    bucket); returns (results, flattened launch
     kinds across all workers — the batch's shared launch ticks the
     leader's thread-local counter only)."""
-    bar = threading.Barrier(len(queries))
-    out = [None] * len(queries)
-    kinds: list[list] = [[] for _ in queries]
-    err = []
-
     def run(i):
-        try:
-            bar.wait()
-            with bm.dispatch_counter() as dc:
-                out[i] = ex.execute("i", queries[i], opt=opt)[0]
-            kinds[i] = dc.launches
-        except BaseException as e:  # noqa: BLE001
-            err.append(e)
+        with bm.dispatch_counter() as dc:
+            got = ex.execute("i", queries[i], opt=opt)[0]
+        return got, dc.launches
 
-    ts = [threading.Thread(target=run, args=(i,))
-          for i in range(len(queries))]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join(timeout=120)
-    assert not err, err
-    return out, [k for ks in kinds for k in ks]
+    res = map_behind_launch(ex.coalescer, run, len(queries),
+                            timeout=120)
+    return [r[0] for r in res], [k for r in res for k in r[1]]
 
 
 #: 16 structurally DISTINCT fused-eligible trees over <= 3 leaves, all

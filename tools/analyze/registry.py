@@ -93,7 +93,7 @@ CLASS_LOCKS: dict[tuple, ClassLockRule] = {
     ),
     ("parallel/coalescer.py", "Coalescer"): ClassLockRule(
         lock="_lock",
-        attrs=frozenset({"_pending"}),
+        attrs=frozenset({"_pending", "inflight", "_drains", "flushes"}),
         # _tape_memo is deliberately UNREGISTERED: racy-by-design
         # (a duplicate compile is wasted work, never a wrong entry —
         # see the inline comment at its definition)

@@ -1,0 +1,80 @@
+#!/usr/bin/env python3
+"""A traced benchmark run that also prints PERF.md section 5's route
+table: the window's reads by route (result cache; dense or tape; batch
+leader, follower or alone), each with the medians of its host phases
+from the flight records' spans and of the client's service time, and
+the share of coalescer flushes by what ended the leader's wait (`why`:
+idle, busy, full, cap; absent on a program that predates it).
+
+    python3 tools/route_table.py --workload seg-dense --seed <n> \\
+        --seconds 51 --trace 1
+
+Arguments, result line and exit code are `perfbench/run.py`'s: this is
+that run with a longer `say_routes`.  The table goes to stderr with the
+run's other lines."""
+
+from __future__ import annotations
+
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from perfbench import run as harness  # noqa: E402
+from perfbench import spans as sp  # noqa: E402
+
+PHASES = ("stage", "coalesce.wait", "launch", "launch.stack",
+          "launch.dispatch", "launch.ready", "reduce")
+
+
+def route_of(profile: dict) -> str:
+    if profile.get("cached"):
+        return "cached"
+    co = profile.get("coalescer")
+    if not co or profile.get("path") != "coalesced":
+        return f"{profile.get('engine')}, not coalesced"
+    role = ("follower" if not co["leader"]
+            else "alone" if co["batch"] == 1 else "leader")
+    return f"{profile.get('engine')}, {role}"
+
+
+def say_table(records) -> None:
+    rows: dict[str, list] = {}
+    why: dict[str, int] = {}
+    for r in records:
+        spans = sp.of(r) if r.status == 200 and r.profile else None
+        if spans is None:
+            continue
+        rows.setdefault(route_of(r.profile), []).append(
+            [sp.self_total(spans, "stage")]
+            + [sp.total(spans, name) for name in PHASES[1:]]
+            + [sp.ms(sp.root(spans)), (r.done - r.sent) * 1e3])
+        co = r.profile.get("coalescer")
+        if co and co["leader"] and not r.profile.get("cached"):
+            key = str(co.get("why"))
+            why[key] = why.get(key, 0) + 1
+    harness.say("route table, medians in ms: n | " + " | ".join(PHASES)
+                + " | root | client service")
+    for route, vals in sorted(rows.items(), key=lambda kv: -len(kv[1])):
+        med = [statistics.median(col) for col in zip(*vals)]
+        harness.say(f"  {route}: {len(vals)} | "
+                    + " | ".join(f"{m:.3f}" for m in med))
+    flushes = sum(why.values())
+    harness.say("flushes by why: " + ", ".join(
+        f"{k} {n} ({100 * n / flushes:.1f}%)"
+        for k, n in sorted(why.items(), key=lambda kv: -kv[1])))
+
+
+def say_routes(records) -> None:
+    _say_routes(records)
+    say_table(records)
+
+
+_say_routes = harness.say_routes
+harness.say_routes = say_routes
+
+if __name__ == "__main__":
+    sys.exit(harness.main())
