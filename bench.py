@@ -451,7 +451,7 @@ def bench_coalescer(a_np: np.ndarray,
             frag._rows[2] = b_np[s].copy()
             for v in range(N_VAR):
                 frag._rows[100 + v] = a_np[s] ^ salts[v]
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
     expects = [int(np.bitwise_count((a_np ^ salts[v]) & b_np)
                    .sum(dtype=np.uint64)) for v in range(N_VAR)]
@@ -732,7 +732,7 @@ def bench_ragged(a_np: np.ndarray, b_np: np.ndarray) -> dict | None:
                     if r != 2 else b_np[s].copy())
             for v in range(N_VAR):
                 frag._rows[100 + v] = a_np[s] ^ salts[v]
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
 
     ex = Executor(holder)
@@ -881,7 +881,7 @@ def bench_resultcache(a_np: np.ndarray,
             frag._rows[2] = b_np[s].copy()
             for v in range(N_VAR):
                 frag._rows[100 + v] = a_np[s] ^ salts[v]
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
     expects = [int(np.bitwise_count((a_np ^ salts[v]) & b_np)
                    .sum(dtype=np.uint64)) for v in range(N_VAR)]
@@ -995,7 +995,7 @@ def bench_ingest(a_np: np.ndarray, b_np: np.ndarray) -> dict | None:
         with frag._lock:
             frag._rows[1] = a_np[s].copy()
             frag._rows[2] = b_np[s].copy()
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
     expect = int(np.bitwise_count(a_np[:SH] & b_np[:SH])
                  .sum(dtype=np.uint64))
@@ -1714,7 +1714,7 @@ def verify_product_path(a_np: np.ndarray, b_np: np.ndarray,
         with frag._lock:
             frag._rows[1] = a_np[s].copy()
             frag._rows[2] = b_np[s].copy()
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
     ex = Executor(holder)
     got = int(ex.execute("i", "Count(Intersect(Row(f=1), Row(f=2)))")[0])

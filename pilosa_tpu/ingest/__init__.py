@@ -31,6 +31,11 @@ Cache discipline (the point of the whole subsystem):
   *its* fragment's delta actually changes, and a compaction refill is
   one recompute against the already-resident base — not an eviction
   storm across every read path.
+- Both move only in ``Fragment._bump_gen`` / ``_bump_delta_seq``,
+  which also change the owning view's write token (``stagecheck.py``):
+  the cached stacks compare the per-fragment tokens above only when
+  that token has moved, so a delta write costs every resident stack of
+  the view one walk over the shards on its next read, and no rebuild.
 
 Durability is unchanged: delta-landing writes append the SAME WAL
 records as the base path at write time; compaction merely moves bits

@@ -107,6 +107,12 @@ FAMILIES: tuple[Family, ...] = (
            "container_*_gathered breaks gathers out per kind",
            live_prefixes=("container_",), group="container",
            doc="architecture.md"),
+    Family("stage", "stage_",
+           "staged leaves validated against the view's write token "
+           "alone (stage_fast) or by a walk over the shards "
+           "(stage_walk) (stagecheck.py, models/field.py)",
+           live_prefixes=("stage_",), group="container",
+           doc="architecture.md"),
     Family("mesh", "mesh_",
            "mesh-native SPMD execution of the fused serving path "
            "(parallel/meshexec.py)",

@@ -20,8 +20,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from pilosa_tpu import observe as _observe
+from pilosa_tpu import stagecheck as _stagecheck
 from pilosa_tpu.models.timequantum import TimeQuantum, views_by_time, views_by_time_range
 from pilosa_tpu.models.view import VIEW_BSI_PREFIX, VIEW_STANDARD, View
+from pilosa_tpu.runtime import residency
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 
@@ -89,8 +92,6 @@ def _padded_rows(n: int) -> int:
     return ((n + a - 1) // a) * a
 
 def _live(dev) -> bool:
-    from pilosa_tpu.runtime import residency
-
     return residency.live(dev)
 
 
@@ -101,6 +102,16 @@ def _leaf_live(leaf) -> bool:
         return False
     return all(_live(p) for p in (leaf.apool, leaf.acard, leaf.rpool)
                if p is not None)
+
+
+def _pair_live(pair) -> bool:
+    """Both stacks of a (set, clear) delta pair."""
+    return _live(pair[0]) and _live(pair[1])
+
+
+def _leaf_pair_live(pair) -> bool:
+    """Both pools of a (set, clear) pair of delta container leaves."""
+    return _live(pair[0].pool) and _live(pair[1].pool)
 
 
 def _placement_token():
@@ -238,7 +249,14 @@ class Field:
         self.options = options
         self.views: dict[str, View] = {}
         self._shards: set[int] = set()
-        self._row_stack_cache: dict = {}  # (row, shards) -> (gens, dev)
+        # (row, shards) -> (gens, dev, [stamp]): the per-fragment
+        # tokens the entry was built from, the device value, and in a
+        # one-slot list the view write token it was last proved good
+        # under (_stamped_hit / _walked_hit)
+        self._row_stack_cache: dict = {}
+        # ("delta" | "dcont", row, shards) -> stamp: "no fragment has
+        # an overlay for this row", as of that view write token
+        self._no_delta: dict = {}
         # shards-tuple -> (gens, row_ids, shard_pos, pos_dev, mat_dev):
         # concatenated cross-shard row matrices for the fused TopN scan
         self._matrix_stack_cache: dict = {}
@@ -425,25 +443,27 @@ class Field:
         ranges use device_time_row_stack).  Missing fragments
         contribute zero rows (semantically identical to the per-shard
         None propagation).  Cached per (row, shards) and invalidated by
-        the per-fragment mutation generations."""
+        the per-fragment mutation generations, which are compared only
+        when the view's write token has moved since the entry was last
+        proved good (_stamped_hit, then _walked_hit)."""
         from pilosa_tpu.ops import bitmap as bm
 
         view = self.view(VIEW_STANDARD)
         key = (row_id, shards)
+        stamp = (_stagecheck.view_token(view), _placement_token())
+        self._note_access(self._row_stack_cache, key)
+        dev = self._stamped_hit(key, stamp)
+        if dev is not None:
+            return dev
         # bind each fragment once: a concurrent delete_fragment between
         # two lookups must read as "empty", not crash.  BASE token: a
         # pending delta must NOT invalidate this stack — the executor
         # fuses it on top (device_delta_stacks + expr "dfuse")
         frags = [None if view is None else view.fragment(s) for s in shards]
-        gens = (_placement_token(),) + tuple(
-            _frag_base_gen(fr) for fr in frags)
-        self._note_access(self._row_stack_cache, key)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if hit is not None and hit[0] == gens and _live(hit[1]):
-                self._touch(self._row_stack_cache, key)
-                self._note_tier("hbm")
-                return hit[1]
+        gens = (stamp[1],) + tuple(_frag_base_gen(fr) for fr in frags)
+        dev = self._walked_hit(key, gens, stamp)
+        if dev is not None:
+            return dev
         # demoted-but-warm: the host tier holds the assembled stack —
         # promote asynchronously (bounded wait) or serve host bytes
         tiered = self._tier_consult(
@@ -469,13 +489,53 @@ class Field:
             if not copied:
                 stack[i] = 0
         stack[len(shards):] = 0  # device-count padding rows
-        return self._place_and_cache_stack(key, gens, stack,
+        return self._place_and_cache_stack(key, gens, stack, stamp,
                                            t0_ns=t_build)
+
+    def _stamped_hit(self, key, stamp, live=_live, tier: bool = True):
+        """Step one of validating a cached stack (stagecheck.py):
+        ``stamp`` holds the write token of every view the entry was
+        built from, READ BEFORE THIS LOOKUP, with the placement token
+        and whatever settings the entry froze.  An entry last proved
+        good under the same stamp, buffers live, is good: nothing in
+        those views was written since, and its value is returned.  No
+        fragment is touched.  None sends the caller on to the
+        per-fragment tokens (``_walked_hit``); this check only ever
+        spares that walk, it never rebuilds, copies or evicts.
+        ``tier`` stamps the access on the flight record as an HBM hit
+        (the delta overlays are not tiered)."""
+        with self._lock:
+            hit = self._row_stack_cache.get(key)
+            if (hit is None or hit[2][0] != stamp
+                    or not live(hit[1])):
+                return None
+            self._touch(self._row_stack_cache, key)
+        if tier:
+            self._note_tier("hbm")
+        return hit[1]
+
+    def _walked_hit(self, key, gens, stamp, live=_live,
+                    tier: bool = True):
+        """Step two: the caller has walked the shards and rebuilt the
+        per-fragment tokens ``gens``; the comparison is the one that
+        was there before write tokens.  A match re-stamps the entry
+        with the token read in step one (a write to ANOTHER row moved
+        the view's token and left this entry's own tokens alone: one
+        walk, then O(1) again) and returns its value; None means
+        rebuild, as ever."""
+        _stagecheck.note_walk()
+        with self._lock:
+            hit = self._row_stack_cache.get(key)
+            if hit is None or hit[0] != gens or not live(hit[1]):
+                return None
+            hit[2][0] = stamp
+            self._touch(self._row_stack_cache, key)
+        if tier:
+            self._note_tier("hbm")
+        return hit[1]
 
     @staticmethod
     def _touch(cache: dict, key) -> None:
-        from pilosa_tpu.runtime import residency
-
         residency.manager().touch(cache, key)
 
     @staticmethod
@@ -484,9 +544,6 @@ class Field:
         cold) onto the active flight record — the stall-vs-hit split
         ?profile=1 and /debug/queries carry.  Silent under ?notiers
         (the escape's profile must look pre-tier too)."""
-        from pilosa_tpu import observe as _observe
-        from pilosa_tpu.runtime import residency
-
         if not residency.tiers_enabled():
             return
         rec = _observe.current()
@@ -497,8 +554,6 @@ class Field:
     def _note_access(cache: dict, key) -> None:
         """Tick the prefetcher's access-statistics table
         (observe.access_stats) for one stack entry."""
-        from pilosa_tpu import observe as _observe
-
         _observe.note_access((id(cache), key))
 
     def _tier_consult(self, cache: dict, key, gens, valid):
@@ -510,7 +565,6 @@ class Field:
         fallback (bit-exact; the promotion keeps running for the next
         query).  None on a true cold miss: the caller assembles from
         fragment state, exactly the pre-tier path."""
-        from pilosa_tpu.runtime import residency
         from pilosa_tpu.serve import deadline as _deadline
 
         mgr = residency.manager()
@@ -581,25 +635,28 @@ class Field:
         host-side (numpy OR over the fragments' host rows), so a wide
         cover costs ONE cache entry and one device transfer, not one
         per view.  Cached per (row, shards, views); every contributing
-        fragment's generation invalidates."""
+        fragment's generation invalidates, compared only when one of
+        the covering views' write tokens has moved (_stamped_hit)."""
         from pilosa_tpu.ops import bitmap as bm
 
         key = ("time", row_id, shards, view_names)
-        frag_grid = []
-        gens = [_placement_token()]
         views = [self.view(vn) for vn in view_names]
+        stamp = (tuple(_stagecheck.view_token(v) for v in views),
+                 _placement_token())
+        self._note_access(self._row_stack_cache, key)
+        dev = self._stamped_hit(key, stamp)
+        if dev is not None:
+            return dev
+        frag_grid = []
+        gens = [stamp[1]]
         for s in shards:
             frags = [None if v is None else v.fragment(s) for v in views]
             frag_grid.append(frags)
             gens.append(tuple(_frag_gen(fr) for fr in frags))
         gens = tuple(gens)
-        self._note_access(self._row_stack_cache, key)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if hit is not None and hit[0] == gens and _live(hit[1]):
-                self._touch(self._row_stack_cache, key)
-                self._note_tier("hbm")
-                return hit[1]
+        dev = self._walked_hit(key, gens, stamp)
+        if dev is not None:
+            return dev
         tiered = self._tier_consult(
             self._row_stack_cache, key, gens,
             lambda h: h[0] == gens and _live(h[1]))
@@ -632,7 +689,7 @@ class Field:
             if not wrote:
                 stack[i] = 0
         stack[len(shards):] = 0
-        return self._place_and_cache_stack(key, gens, stack,
+        return self._place_and_cache_stack(key, gens, stack, stamp,
                                            t0_ns=t_build)
 
     @staticmethod
@@ -644,15 +701,13 @@ class Field:
         default budget never relaxes the cap: on a big device a giant
         one-off stack must stay uncacheable rather than evict the
         whole warm cache."""
-        from pilosa_tpu.runtime import residency
-
         mgr = residency.manager()
         if not mgr.operator_sized:
             return fixed_cap
         return max(fixed_cap, mgr.budget // 4)
 
     def _place_and_cache_stack(self, key, gens, stack: np.ndarray,
-                               t0_ns: int | None = None):
+                               stamp, t0_ns: int | None = None):
         dev = self._place_on_devices(stack)
         if t0_ns is not None:
             # cold-build attribution: this query paid the fragment
@@ -667,10 +722,11 @@ class Field:
             # async re-promotion: re-place the demoted host stack under
             # whatever [mesh] layout is then in force; a placement-
             # token drift simply misses at the consumer and rebuilds
-            return (_g, place(arr))
+            # (unstamped: its first read walks the shards once)
+            return (_g, place(arr), [None])
 
         self._evict_and_insert(
-            self._row_stack_cache, key, (gens, dev), entry_bytes,
+            self._row_stack_cache, key, (gens, dev, [stamp]), entry_bytes,
             max_entries=64, devices=_placement_devices(),
             token=gens, host=stack, promote=_promote)
         return dev
@@ -687,6 +743,9 @@ class Field:
         Cached per (row, shards) keyed on the per-fragment ``(uid,
         row_seq)`` tokens — a delta write to a DIFFERENT row leaves a
         cached pair valid, so only the written row's stacks rebuild.
+        Those tokens are rebuilt only when the view's write token has
+        moved since the answer was last proved good (_stamped_hit),
+        and the answer so remembered includes None (_no_delta).
         Safe under a concurrent compaction because delta application
         is idempotent: the executor stages these BEFORE the base stack,
         and re-applying an already-merged overlay reproduces the same
@@ -694,21 +753,20 @@ class Field:
         from pilosa_tpu.ops import bitmap as bm
 
         view = self.view(VIEW_STANDARD)
-        frags = [None if view is None else view.fragment(s)
-                 for s in shards]
-        toks = (_placement_token(),) + tuple(
-            0 if fr is None
-            else (fr._uid, fr._delta_row_seq(row_id))
-            for fr in frags)
-        if not any(t and t[1] for t in toks[1:]):
-            return None
         key = ("delta", row_id, shards)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if (hit is not None and hit[0] == toks
-                    and _live(hit[1][0]) and _live(hit[1][1])):
-                self._touch(self._row_stack_cache, key)
-                return hit[1]
+        stamp = (_stagecheck.view_token(view), _placement_token())
+        if self._no_delta.get(key) == stamp:
+            return None
+        pair = self._stamped_hit(key, stamp, _pair_live, tier=False)
+        if pair is not None:
+            return pair
+        frags, toks = self._delta_tokens(view, key, stamp)
+        if toks is None:
+            return None
+        pair = self._walked_hit(key, toks, stamp, _pair_live,
+                                tier=False)
+        if pair is not None:
+            return pair
         n_words = bm.n_words(SHARD_WIDTH)
         rows = _padded_rows(len(shards))
         set_stack = np.zeros((rows, n_words), dtype=np.uint32)
@@ -731,10 +789,49 @@ class Field:
         entry_bytes = set_stack.nbytes + clear_stack.nbytes
         if entry_bytes <= self._entry_cap(self.ROW_STACK_CACHE_BYTES):
             self._evict_and_insert(self._row_stack_cache, key,
-                                   (toks, pair), entry_bytes,
+                                   (toks, pair, [stamp]), entry_bytes,
                                    max_entries=64,
                                    devices=_placement_devices())
         return pair
+
+    def delta_pending(self, row_id: int, shards: tuple[int, ...]) -> bool:
+        """Whether any fragment of the shard set has a pending overlay
+        for this standard-view row: what ``device_delta_stacks``
+        answers None to, for a caller that wants no stacks built
+        (containers.plan_fused).  "No" is remembered like theirs."""
+        view = self.view(VIEW_STANDARD)
+        key = ("delta", row_id, shards)
+        stamp = (_stagecheck.view_token(view), _placement_token())
+        if self._no_delta.get(key) == stamp:
+            return False
+        return self._delta_tokens(view, key, stamp)[1] is not None
+
+    #: entries of _no_delta kept before the table starts over (a stamp
+    #: holds no buffer; forgetting one costs the next read one walk)
+    _NO_DELTA_CAP = 4096
+
+    def _delta_tokens(self, view, key, stamp):
+        """The walk behind both delta builders: ``(frags, toks)`` with
+        one ``(uid, row_seq)`` token per shard for the row of ``key``,
+        or ``(frags, None)`` when no fragment has a pending overlay
+        for it.  That answer is remembered under ``stamp``, the view
+        write token read before the walk, so the next read of an
+        unwritten view does not walk again."""
+        row_id, shards = key[1], key[2]
+        frags = [None if view is None else view.fragment(s)
+                 for s in shards]
+        toks = (stamp[1],) + tuple(
+            0 if fr is None
+            else (fr._uid, fr._delta_row_seq(row_id))
+            for fr in frags)
+        if any(t and t[1] for t in toks[1:]):
+            self._no_delta.pop(key, None)
+            return frags, toks
+        _stagecheck.note_walk()
+        if len(self._no_delta) >= self._NO_DELTA_CAP:
+            self._no_delta.clear()
+        self._no_delta[key] = stamp
+        return frags, None
 
     def device_delta_container_leaves(self, row_id: int,
                                       shards: tuple[int, ...]):
@@ -748,28 +845,30 @@ class Field:
         SHARD_WIDTH/2^16 containers, and only the non-empty ones pool.
 
         Cached per (row, shards) keyed on the per-fragment ``(uid,
-        row_seq)`` tokens, like device_delta_stacks — and safe under a
-        concurrent compaction for the same reason: the VM stages these
-        BEFORE the base leaf, and re-applying an already-merged
-        overlay is idempotent ((b&~c|s)&~c|s == b&~c|s)."""
+        row_seq)`` tokens, like device_delta_stacks (None included,
+        and walked only when the view's write token has moved) — and
+        safe under a concurrent compaction for the same reason: the VM
+        stages these BEFORE the base leaf, and re-applying an
+        already-merged overlay is idempotent ((b&~c|s)&~c|s ==
+        b&~c|s)."""
         from pilosa_tpu.ops import containers as ct
 
         view = self.view(VIEW_STANDARD)
-        frags = [None if view is None else view.fragment(s)
-                 for s in shards]
-        toks = (_placement_token(),) + tuple(
-            0 if fr is None
-            else (fr._uid, fr._delta_row_seq(row_id))
-            for fr in frags)
-        if not any(t and t[1] for t in toks[1:]):
-            return None
         key = ("dcont", row_id, shards)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if (hit is not None and hit[0] == toks
-                    and _live(hit[1][0].pool) and _live(hit[1][1].pool)):
-                self._touch(self._row_stack_cache, key)
-                return hit[1]
+        stamp = (_stagecheck.view_token(view), _placement_token())
+        if self._no_delta.get(key) == stamp:
+            return None
+        pair = self._stamped_hit(key, stamp, _leaf_pair_live,
+                                 tier=False)
+        if pair is not None:
+            return pair
+        frags, toks = self._delta_tokens(view, key, stamp)
+        if toks is None:
+            return None
+        pair = self._walked_hit(key, toks, stamp, _leaf_pair_live,
+                                tier=False)
+        if pair is not None:
+            return pair
         from pilosa_tpu.ops import bitmap as bm
 
         cpr = SHARD_WIDTH // ct.CONTAINER_BITS
@@ -819,7 +918,7 @@ class Field:
         entry_bytes = pair[0].nbytes + pair[1].nbytes
         if entry_bytes <= self._entry_cap(self.ROW_STACK_CACHE_BYTES):
             self._evict_and_insert(self._row_stack_cache, key,
-                                   (toks, pair), entry_bytes,
+                                   (toks, pair, [stamp]), entry_bytes,
                                    max_entries=64, kind="compressed",
                                    devices=_placement_devices())
         return pair
@@ -836,13 +935,13 @@ class Field:
         accounts the REAL compressed bytes under kind="compressed", so
         a sparse row costs HBM proportional to its containers, not to
         shards x shard-width — the capacity multiplier of the roaring
-        layout."""
+        layout.  The per-fragment tokens are compared only when the
+        view's write token, the placement or a setting below has
+        moved since the leaf was last proved good (_stamped_hit)."""
         from pilosa_tpu.ops import containers as ct
+        from pilosa_tpu.parallel import meshexec
 
         view = self.view(VIEW_STANDARD)
-        frags = [None if view is None else view.fragment(s)
-                 for s in shards]
-        from pilosa_tpu.parallel import meshexec
 
         # the fill-ratio threshold joins the token: a cached leaf
         # froze each fragment's sparse-vs-hot verdict, so a runtime
@@ -854,18 +953,20 @@ class Field:
         # mesh-routed queries keep the exact legacy all-bitmap leaves
         cfg = ct.config()
         eff_kinds = bool(cfg.kinds) and not meshexec.active()
-        gens = (cfg.threshold, eff_kinds, cfg.array_max, cfg.run_cap,
-                _placement_token(),
-                *(_frag_base_gen(fr) for fr in frags))
+        settings = (cfg.threshold, eff_kinds, cfg.array_max, cfg.run_cap,
+                    _placement_token())
+        stamp = (_stagecheck.view_token(view),) + settings
         key = ("cont", row_id, shards)
         self._note_access(self._row_stack_cache, key)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if (hit is not None and hit[0] == gens
-                    and _leaf_live(hit[1])):
-                self._touch(self._row_stack_cache, key)
-                self._note_tier("hbm")
-                return hit[1]
+        leaf = self._stamped_hit(key, stamp, _leaf_live)
+        if leaf is not None:
+            return leaf
+        frags = [None if view is None else view.fragment(s)
+                 for s in shards]
+        gens = (*settings, *(_frag_base_gen(fr) for fr in frags))
+        leaf = self._walked_hit(key, gens, stamp, _leaf_live)
+        if leaf is not None:
+            return leaf
         tiered = self._tier_consult(
             self._row_stack_cache, key, gens,
             lambda h: h[0] == gens and _leaf_live(h[1]))
@@ -954,10 +1055,10 @@ class Field:
                         apool=place_pool(apool_h),
                         acard=place_pool(acard_h),
                         rpool=place_pool(rpool_h),
-                        an=_leaf.an, rn=_leaf.rn))
+                        an=_leaf.an, rn=_leaf.rn), [None])
                 return (_g, ct.ContainerLeaf(
                     _sh, _leaf.entries, _leaf.starts, _leaf.kinds,
-                    place_pool(p), _leaf.n, p.nbytes))
+                    place_pool(p), _leaf.n, p.nbytes), [None])
 
             def _leaf_host(p, _leaf=leaf, _sh=shards):
                 if isinstance(p, tuple):
@@ -975,7 +1076,7 @@ class Field:
                     np.ascontiguousarray(p), _leaf.n, p.nbytes)
 
             self._evict_and_insert(self._row_stack_cache, key,
-                                   (gens, leaf), leaf.nbytes,
+                                   (gens, leaf, [stamp]), leaf.nbytes,
                                    max_entries=64, kind="compressed",
                                    token=gens, host=host_payload,
                                    promote=_promote_leaf,
@@ -1091,8 +1192,6 @@ class Field:
         host tier (eviction demotes instead of dropping); cap
         evictions DEMOTE too — the FIFO-displaced entry is still valid,
         merely cold."""
-        from pilosa_tpu.runtime import residency
-
         mgr = residency.manager()
         with self._lock:
             if cache.pop(key, None) is not None:
@@ -1121,7 +1220,6 @@ class Field:
         key embeds the shard tuple (``(row, shards)``, ``("time", row,
         shards, views)``, the matrix cache's bare ``shards``...), so
         membership in any int-tuple component identifies coverage."""
-        from pilosa_tpu.runtime import residency
 
         shard = int(shard)
 
@@ -1280,8 +1378,9 @@ class Field:
         """BSI plane stacks across shards as one device-resident uint32
         [n_shards, planes, words] tensor (planes = exists, sign, then
         bit_depth value planes) — the fused Sum path's operand.  Cached
-        and generation-invalidated like device_row_stack; shard axis is
-        padded and mesh-sharded the same way."""
+        and generation-invalidated like device_row_stack (the BSI
+        view's write token first, the per-fragment tokens when it has
+        moved); shard axis is padded and mesh-sharded the same way."""
         from pilosa_tpu.ops import bitmap as bm
         from pilosa_tpu.ops import bsi as bsi_ops
 
@@ -1289,16 +1388,16 @@ class Field:
         depth = self.options.bit_depth
         view = self.view(self.bsi_view_name)
         key = ("planes", shards, depth)
-        frags = [None if view is None else view.fragment(s) for s in shards]
-        gens = (_placement_token(),) + tuple(
-            _frag_gen(fr) for fr in frags)
+        stamp = (_stagecheck.view_token(view), _placement_token())
         self._note_access(self._row_stack_cache, key)
-        with self._lock:
-            hit = self._row_stack_cache.get(key)
-            if hit is not None and hit[0] == gens and _live(hit[1]):
-                self._touch(self._row_stack_cache, key)
-                self._note_tier("hbm")
-                return hit[1]
+        dev = self._stamped_hit(key, stamp)
+        if dev is not None:
+            return dev
+        frags = [None if view is None else view.fragment(s) for s in shards]
+        gens = (stamp[1],) + tuple(_frag_gen(fr) for fr in frags)
+        dev = self._walked_hit(key, gens, stamp)
+        if dev is not None:
+            return dev
         tiered = self._tier_consult(
             self._row_stack_cache, key, gens,
             lambda h: h[0] == gens and _live(h[1]))
@@ -1323,7 +1422,7 @@ class Field:
                     else:
                         stack[i, p] = 0
         stack[len(shards):] = 0
-        return self._place_and_cache_stack(key, gens, stack,
+        return self._place_and_cache_stack(key, gens, stack, stamp,
                                            t0_ns=t_build)
 
     # ------------------------------------------------------------ BSI ops
@@ -1794,7 +1893,6 @@ class Field:
     # ---------------------------------------------------------- lifecycle
 
     def close(self) -> None:
-        from pilosa_tpu.runtime import residency
 
         for view in self.views.values():
             view.close()

@@ -141,6 +141,10 @@ class Fragment:
         # bumps _gen instead.
         self._delta = None  # ingest.deltaplane.DeltaPlane | None
         self._delta_seq = 0
+        # the View whose map holds this fragment (bound by the map,
+        # models/view.py): every change to _gen, _delta_seq or _delta
+        # also changes that view's write token (_written)
+        self._owner = None
         # process-unique identity for cache keys: a fragment deleted
         # (resize cleanup) and later re-fetched is a NEW object whose
         # _gen can collide with a stale cached tuple — uid makes a
@@ -252,7 +256,7 @@ class Fragment:
         for path in (self._wal_path, self._wal_new_path):
             if os.path.exists(path):
                 self._replay_wal_file(path)
-        self._gen += 1
+        self._bump_gen()
 
     def _replay_wal_file(self, path: str) -> None:
         with open(path, "rb") as f:
@@ -439,6 +443,7 @@ class Fragment:
 
                 compactor.compactor().forget(self)
                 self._delta = None
+                self._written()
             # release device residency accounting (drops the cache refs;
             # the jax buffers free once no computation holds them)
             mgr = residency.manager()
@@ -504,6 +509,34 @@ class Fragment:
 
             snapqueue.enqueue(self)
 
+    # -------------------------------------------------------- write tokens
+
+    def _written(self) -> None:
+        """Change the owning view's write token (stagecheck.py).
+        Every event that changes what a per-fragment cache token reads
+        (``_gen``, ``_delta_seq``, a row's ``row_seq`` in the delta
+        plane, ``_delta`` itself) ends here, AFTER that state is final
+        and before the write returns to its caller: a cached stack
+        stamped with the old token is then never taken for current
+        without the per-fragment comparison."""
+        owner = self._owner
+        if owner is not None:
+            owner.note_write()
+
+    def _bump_gen(self) -> None:
+        """Base content changed (or a compaction merged the delta in):
+        the one place ``_gen`` moves."""
+        self._gen += 1
+        self._written()
+
+    def _bump_delta_seq(self) -> None:
+        """A write is landing in the delta plane: the one place
+        ``_delta_seq`` moves.  The plane's own ``row_seq`` moves after
+        this, so ``_delta_after_write_locked`` changes the view's
+        token once more when the plane holds the write."""
+        self._delta_seq += 1
+        self._written()
+
     # ------------------------------------------------ streaming delta plane
 
     #: BSI views (bsig_<field>) never take the delta path: their reads
@@ -538,6 +571,7 @@ class Fragment:
         memory — backpressure lands on the writer, never on readers)."""
         from pilosa_tpu.ingest import compactor
 
+        self._written()  # row_seq moved after _bump_delta_seq
         if compactor.compactor().note_delta(self):
             self._flush_delta_locked(inline=True)
 
@@ -570,7 +604,9 @@ class Fragment:
     def _flush_delta_locked(self, inline: bool = False) -> int:
         d = self._delta
         if d is None or d.empty():
-            self._delta = None
+            if d is not None:
+                self._delta = None
+                self._written()
             return 0
         # sets first, clears second — matching _apply_bulk's order so a
         # position present in both planes (impossible by the disjoint
@@ -584,7 +620,7 @@ class Fragment:
                 np.bitwise_and(arr, ~words, out=arr)
         bits = d.bits
         self._delta = None
-        self._gen += 1
+        self._bump_gen()
         from pilosa_tpu.ingest import compactor
 
         compactor.compactor().note_flushed(self, bits, inline=inline)
@@ -622,7 +658,7 @@ class Fragment:
         self._wal_append(_WAL_REC.pack(
             _WAL_CLEAR if clear else _WAL_SET, row, off))
         self._op_n += 1
-        self._delta_seq += 1
+        self._bump_delta_seq()
         self._delta_or_new().add_bit(row, off, clear, self._delta_seq)
         self._delta_after_write_locked()
         self._maybe_snapshot()
@@ -699,7 +735,7 @@ class Fragment:
                 self._wal_append(_WAL_REC.pack(_WAL_SET, row, off))
                 self._op_n += 1
             if changed:
-                self._gen += 1
+                self._bump_gen()
             self._maybe_snapshot()
             self._paranoia_check()
             return changed
@@ -713,7 +749,7 @@ class Fragment:
             if self._apply_clear(row, off):
                 self._wal_append(_WAL_REC.pack(_WAL_CLEAR, row, off))
                 self._op_n += 1
-                self._gen += 1
+                self._bump_gen()
                 self._maybe_snapshot()
                 self._paranoia_check()
                 return True
@@ -735,7 +771,7 @@ class Fragment:
                 _WAL_BULK_HDR.pack(_WAL_BULK, 0, len(pos)) + pos.tobytes()
             )
             self._op_n += len(pos)
-            self._gen += 1
+            self._bump_gen()
             self._maybe_snapshot()
             self._paranoia_check()
             return True
@@ -761,7 +797,7 @@ class Fragment:
                 + sets.tobytes() + clears.tobytes()
             )
             self._op_n += len(sets) + len(clears)
-            self._gen += 1
+            self._bump_gen()
             self._maybe_snapshot()
             self._paranoia_check()
             return True
@@ -791,7 +827,7 @@ class Fragment:
                     + sets.tobytes() + clears.tobytes()
                 )
                 self._op_n += len(sets) + len(clears)
-                self._delta_seq += 1
+                self._bump_delta_seq()
                 d = self._delta_or_new()
                 d.add_positions(sets, False, self._delta_seq)
                 d.add_positions(clears, True, self._delta_seq)
@@ -806,7 +842,7 @@ class Fragment:
                 + sets.tobytes() + clears.tobytes()
             )
             self._op_n += len(sets) + len(clears)
-            self._gen += 1
+            self._bump_gen()
             self._maybe_snapshot()
             self._paranoia_check()
 
@@ -836,7 +872,7 @@ class Fragment:
                         _WAL_ROARING_HDR.pack(_WAL_ROARING, len(data),
                                               1 if clear else 0) + data)
                     self._op_n += len(pos)
-                    self._delta_seq += 1
+                    self._bump_delta_seq()
                     self._delta_or_new().add_positions(
                         pos, clear, self._delta_seq)
                     self._delta_after_write_locked()
@@ -850,7 +886,7 @@ class Fragment:
                     _WAL_ROARING_HDR.pack(_WAL_ROARING, len(data),
                                           1 if clear else 0) + data)
                 self._op_n += changed
-                self._gen += 1
+                self._bump_gen()
                 self._maybe_snapshot()
             self._paranoia_check()
 
@@ -1479,7 +1515,7 @@ class Fragment:
                 changed |= self._apply_clear(bsi_ops.SIGN_PLANE, off)
                 self._wal_append(_WAL_REC.pack(_WAL_CLEAR, bsi_ops.SIGN_PLANE, off))
             self._op_n += 2
-            self._gen += 1
+            self._bump_gen()
             self._maybe_snapshot()
             self._paranoia_check()
         return changed
@@ -1491,7 +1527,7 @@ class Fragment:
             if changed:
                 self._wal_append(_WAL_REC.pack(_WAL_CLEAR, bsi_ops.EXISTS_PLANE, off))
                 self._op_n += 1
-                self._gen += 1
+                self._bump_gen()
         return changed
 
     def value(self, col: int, depth: int) -> tuple[int, bool]:

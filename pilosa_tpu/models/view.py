@@ -13,11 +13,45 @@ import os
 
 import numpy as np
 
+from pilosa_tpu import stagecheck
 from pilosa_tpu.models.fragment import Fragment
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_PREFIX = "bsig_"
+
+
+class _FragmentMap(dict):
+    """shard -> Fragment.  The map cannot change without the view's
+    write token changing (stagecheck.py): create, replace and
+    delete all pass here, and a fragment put in is bound to the view,
+    so that its own writes change the token from then on."""
+
+    __slots__ = ("_view",)
+
+    def __init__(self, view: "View"):
+        super().__init__()
+        self._view = view
+
+    def __setitem__(self, shard: int, frag: Fragment) -> None:
+        frag._owner = self._view
+        super().__setitem__(shard, frag)
+        self._view.note_write()
+
+    def __delitem__(self, shard: int) -> None:
+        super().__delitem__(shard)
+        self._view.note_write()
+
+    def pop(self, shard: int, *default):
+        try:
+            return super().pop(shard, *default)
+        finally:
+            self._view.note_write()
+
+    def _unsupported(self, *args, **kwargs):
+        raise TypeError("View.fragments changes one shard at a time")
+
+    clear = update = setdefault = popitem = __ior__ = _unsupported
 
 
 class View:
@@ -40,7 +74,11 @@ class View:
         self.mutex = mutex
         self.cache_type = cache_type
         self.cache_size = cache_size
-        self.fragments: dict[int, Fragment] = {}
+        #: changes after every write to any fragment of this view and
+        #: every change of the map below; cached stacks are stamped
+        #: with it (stagecheck.py)
+        self.write_token = stagecheck.next_token()
+        self.fragments: dict[int, Fragment] = _FragmentMap(self)
         # guards fragment CREATION/DELETION only; reads stay lock-free
         # (GIL-atomic dict gets, the double-checked pattern)
         self._lock = _lockcheck.lock("view")
@@ -67,6 +105,12 @@ class View:
                 shard, mutex=self.mutex,
                 cache_type=self.cache_type, cache_size=self.cache_size,
             )
+
+    def note_write(self) -> None:
+        """Something a per-fragment cache token could read has changed
+        in this view.  Called by the writer, after the fragment's own
+        counters and before the write returns to its caller."""
+        self.write_token = stagecheck.next_token()
 
     def fragment(self, shard: int) -> Fragment | None:
         return self.fragments.get(shard)

@@ -56,6 +56,7 @@ import numpy as np
 
 from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
+from pilosa_tpu import stagecheck as _stagecheck
 
 #: Container geometry: 2^16 bits = 1024 uint64 = 2048 uint32 words —
 #: the reference's container size and storage/roaring.py's block shape.
@@ -263,7 +264,7 @@ class ContainerLeaf:
 
     __slots__ = ("shards", "entries", "starts", "kinds", "pool", "n",
                  "nbytes", "uid", "slots", "apool", "acard", "rpool",
-                 "an", "rn")
+                 "an", "rn", "_dense")
 
     def __init__(self, shards: tuple, entries: list, starts: list,
                  kinds: list, pool: Any, n: int, nbytes: int,
@@ -287,10 +288,13 @@ class ContainerLeaf:
         # mutation) is a NEW object with a fresh uid, so stale staged
         # gathers can never be addressed
         self.uid = next(_LEAF_UID)
+        # found once: every read of a dense row asks (stage_vm's and
+        # plan_fused's decline), and a leaf's directory never changes
+        self._dense = [i for i, e in enumerate(entries) if e is None]
 
     def dense_slots(self) -> list[int]:
         """Shard positions whose fragment row is too hot to compress."""
-        return [i for i, e in enumerate(self.entries) if e is None]
+        return self._dense
 
     @property
     def has_kinds(self) -> bool:
@@ -741,6 +745,7 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
     leaves: list[ContainerLeaf] = []
     for i, (f, row_id) in enumerate(leaf_descs):
         pair = None
+        mark = _stagecheck.mark()
         if not use_delta:
             # the ?nodelta=1 contract: compact up front, then a real
             # pure-base read — which the VM is
@@ -748,6 +753,7 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
         else:
             pair = f.device_delta_container_leaves(row_id, shards)
         base = f.device_container_leaf(row_id, shards)
+        _stagecheck.leaf_done(mark)
         if base.dense_slots():
             bump("container.fallbacks")
             _tp.bump("vm.fallbacks.ineligible_leaf")
@@ -1106,7 +1112,6 @@ def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
     gathering would both tick a launch the dense route doesn't (the
     route-invariant accounting would break) and redo work the stack
     cache already holds."""
-    from pilosa_tpu.models.view import VIEW_STANDARD
     from pilosa_tpu.ops import bitmap as bm
     from pilosa_tpu.shardwidth import SHARD_WIDTH
 
@@ -1122,22 +1127,16 @@ def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
         return None
     use_delta = opt is None or opt.delta
     for f, row_id in leaf_descs:
-        view = f.view(VIEW_STANDARD)
-        if view is None:
-            continue
         if not use_delta:
             # the ?nodelta=1 contract: compact up front, then a real
             # pure-base read — which the compressed path is
             f.flush_deltas(shards)
-            continue
-        for s in shards:
-            fr = view.fragment(s)
-            if fr is not None and fr._delta_row_seq(row_id):
-                # pending overlay on a queried row: the dense path
-                # fuses it (expr "dfuse"); compressed pools hold base
-                # content only
-                bump("container.fallbacks")
-                return None
+        elif f.delta_pending(row_id, shards):
+            # pending overlay on a queried row: the dense path
+            # fuses it (expr "dfuse"); compressed pools hold base
+            # content only
+            bump("container.fallbacks")
+            return None
     leaves = []
     for f, row_id in leaf_descs:
         leaf = f.device_container_leaf(row_id, shards)

@@ -72,6 +72,7 @@ import numpy as np
 
 from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
+from pilosa_tpu import stagecheck as _stagecheck
 from pilosa_tpu import stats as _stats
 from pilosa_tpu import tracing
 from pilosa_tpu.ops import containers as _containers
@@ -313,12 +314,18 @@ class Coalescer:
             # keep the shard_map interpreter.  Any decline (dense/hot
             # leaf, ineligible tree, oversize) falls through to the
             # existing ragged/fused staging below, all-or-nothing.
-            with _observe.span("stage", vm=True):
+            with _observe.span("stage", vm=True) as sp:
+                fast0 = _stagecheck.fast_leaves()
+                leaves0 = _stagecheck.leaves()
                 vmstage = _containers.stage_vm(
                     idx, child, shards, use_delta=use_delta,
                     max_tape=self.max_tape, max_leaves=self.max_leaves,
                     min_domain=self.vm_min_domain,
                     max_prefetch=self.vm_max_prefetch)
+                # the row leaves it got through (a decline stops at
+                # the first dense one) and how many needed no walk
+                sp.note(leaves=_stagecheck.leaves() - leaves0,
+                        fast=_stagecheck.fast_leaves() - fast0)
             if vmstage is None:
                 _tape.bump("vm.fallbacks")
         elif self.vm and self.ragged and use_vm:

@@ -62,6 +62,7 @@ from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu import faultinject as _fi
 from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
+from pilosa_tpu import stagecheck as _stagecheck
 from pilosa_tpu import stats as _stats
 from pilosa_tpu import tracing
 
@@ -1104,9 +1105,14 @@ class Executor:
         every leaf stays a plain base leaf."""
         leaves: list = []
         with _observe.span("stage") as sp:
+            fast0 = _stagecheck.fast_leaves()
             shape = self._fused_shape(idx, call, shards, leaves,
                                       use_delta)
-            sp.note(leaves=len(leaves))
+            # fast: leaves whose cached stacks were validated against
+            # the view's write token alone, with no walk over the
+            # shards (stagecheck.py)
+            sp.note(leaves=len(leaves),
+                    fast=_stagecheck.fast_leaves() - fast0)
         return shape, tuple(leaves)
 
     def _fused_row_leaf(self, f, row_id, shards: tuple[int, ...],
@@ -1117,12 +1123,14 @@ class Executor:
         overlay stacks join as ``dfuse`` operands — staged BEFORE the
         base stack, so a compaction racing the two reads can only
         double-apply the (idempotent) overlay, never drop it."""
+        mark = _stagecheck.mark()
         if not use_delta:
             f.flush_deltas(shards)
             ds = None
         else:
             ds = f.device_delta_stacks(row_id, shards)
         leaves.append(f.device_row_stack(row_id, shards))
+        _stagecheck.leaf_done(mark)
         shape = ("leaf", len(leaves) - 1)
         if ds is not None:
             leaves.append(ds[0])
@@ -1145,10 +1153,12 @@ class Executor:
                          if condition.op == "><" else condition.value)
                 # the range compare dispatches while it stages: its
                 # launch hangs under the stage span
+                mark = _stagecheck.mark()
                 leaves.append(_perfobs.launch(
                     self._raw_engine(self._query_mesh(None)),
                     lambda: idx.field(fname).device_range_stack(
                         condition.op, value, shards)))
+                _stagecheck.leaf_done(mark)
                 return ("leaf", len(leaves) - 1)
             fname = call.field_arg()
             f = idx.field(fname)
@@ -1159,8 +1169,10 @@ class Executor:
                 # inside the builder (effective reads; token carries
                 # the delta seq) — no dfuse leaves needed.
                 views = self._time_range_views(f, call) or []
+                mark = _stagecheck.mark()
                 leaves.append(f.device_time_row_stack(
                     call.args[fname], shards, tuple(views)))
+                _stagecheck.leaf_done(mark)
                 return ("leaf", len(leaves) - 1)
             # arg is a plain int row id (bool literals were excluded by
             # _fused_supported)

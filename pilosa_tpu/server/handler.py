@@ -1862,6 +1862,7 @@ class Handler:
         from pilosa_tpu import faultinject as _faultinject
         from pilosa_tpu import observe as _observe_mod
         from pilosa_tpu import perfobs as _perfobs
+        from pilosa_tpu import stagecheck as _stagecheck
         from pilosa_tpu.ingest import compactor
         from pilosa_tpu.models import fragment as _fragment
         from pilosa_tpu.ops import containers as _containers
@@ -1878,6 +1879,7 @@ class Handler:
             compactor.compactor().publish_gauges(self.stats)
             tape.publish_gauges(self.stats)
             _containers.publish_gauges(self.stats)
+            _stagecheck.publish_gauges(self.stats)
             _meshexec.publish_gauges(self.stats)
             # engine observatory: launch/bytes totals, cost-table
             # size, shadow consult counters, per-engine tagged

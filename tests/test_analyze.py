@@ -212,12 +212,12 @@ class TestGenerationAudit:
         fixtures."""
         with open(os.path.join(PKG, "models", "fragment.py")) as fh:
             src = fh.read()
-        assert src.count("self._gen += 1") >= 5
+        assert src.count("self._bump_gen()") >= 5
         # clear_value: the one-bump method with no transitive bump
         broken = src.replace(
             "self._wal_append(_WAL_REC.pack(_WAL_CLEAR, "
             "bsi_ops.EXISTS_PLANE, off))\n                "
-            "self._op_n += 1\n                self._gen += 1",
+            "self._op_n += 1\n                self._bump_gen()",
             "self._wal_append(_WAL_REC.pack(_WAL_CLEAR, "
             "bsi_ops.EXISTS_PLANE, off))\n                "
             "self._op_n += 1")

@@ -32,7 +32,7 @@ def test_268m_column_fused_count_exact(tmp_path):
         with frag._lock:
             frag._rows[1] = a
             frag._rows[2] = b
-            frag._gen += 1
+            frag._bump_gen()
         f._note_shard(s)
     ex = Executor(holder)
     got = ex.execute("i", "Count(Intersect(Row(f=1), Row(f=2)))")[0]

@@ -64,6 +64,10 @@ CLASS_LOCKS: dict[tuple, ClassLockRule] = {
             "_delta_or_new": "delta write path; callers hold _lock",
             "_delta_set_bit": "delta write path; callers hold _lock",
             "_delta_row_seq": "token read under the caller's _lock",
+            "_bump_gen": "the one place _gen moves; every caller is a "
+                         "mutator holding _lock (or _load replay)",
+            "_bump_delta_seq": "the one place _delta_seq moves; delta "
+                               "write path, callers hold _lock",
         },
     ),
     ("ingest/compactor.py", "Compactor"): ClassLockRule(

@@ -2,9 +2,12 @@
 """A traced benchmark run that also prints PERF.md section 5's route
 table: the window's reads by route (result cache; dense or tape; batch
 leader, follower or alone), each with the medians of its host phases
-from the flight records' spans and of the client's service time, and
-the share of coalescer flushes by what ended the leader's wait (`why`:
-idle, busy, full, cap; absent on a program that predates it).
+from the flight records' spans and of the client's service time, the
+share of coalescer flushes by what ended the leader's wait (`why`:
+idle, busy, full, cap; absent on a program that predates it), and the
+share of staged leaves whose cached stacks were validated against the
+view's write token alone (`fast` of `leaves` on the `stage` spans;
+absent likewise).
 
     python3 tools/route_table.py --workload seg-dense --seed <n> \\
         --seconds 51 --trace 1
@@ -44,10 +47,15 @@ def route_of(profile: dict) -> str:
 def say_table(records) -> None:
     rows: dict[str, list] = {}
     why: dict[str, int] = {}
+    fast = leaves = 0
     for r in records:
         spans = sp.of(r) if r.status == 200 and r.profile else None
         if spans is None:
             continue
+        for s in spans:
+            if s["name"] == "stage" and "fast" in s:
+                fast += s["fast"]
+                leaves += s["leaves"]
         rows.setdefault(route_of(r.profile), []).append(
             [sp.self_total(spans, "stage")]
             + [sp.total(spans, name) for name in PHASES[1:]]
@@ -66,6 +74,9 @@ def say_table(records) -> None:
     harness.say("flushes by why: " + ", ".join(
         f"{k} {n} ({100 * n / flushes:.1f}%)"
         for k, n in sorted(why.items(), key=lambda kv: -kv[1])))
+    if leaves:
+        harness.say(f"leaves staged: {leaves}, without a walk over the "
+                    f"shards: {fast} ({100 * fast / leaves:.2f}%)")
 
 
 def say_routes(records) -> None:
