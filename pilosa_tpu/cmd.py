@@ -447,7 +447,6 @@ def run_server(cfg: Config, ready_event: threading.Event | None = None,
         observe_journal=cfg.observe.journal,
         observe_journal_size=cfg.observe.journal_size,
         observe_journal_kinds=cfg.observe.journal_kinds,
-        cost_shadow=cfg.cost.shadow,
         admission_enabled=cfg.admission.enabled,
         admission_query_cap=cfg.admission.query_cap,
         admission_query_queue=cfg.admission.query_queue,

@@ -225,8 +225,11 @@ class VMConfig:
 class ObserveConfig:
     """[observe] — the query flight recorder (pilosa_tpu.observe; no
     reference analog beyond ``cluster.long-query-time``).  ``enabled``
-    keeps the per-query record assembly on (sub-1% of the coalesced
-    Count path, benchmarked in bench.py extras.observe); ``recent`` is
+    keeps the per-query record assembly on (off, a Count begins no
+    record, takes no recorder lock and opens no span but the per-call
+    stats timing: ``tests/test_observer_cost.py``; on, the span spine
+    read ``read_p50_ms`` 6.74 -> 6.82 on ``seg-dense``, the driver's
+    PR 24 line, PERF.md section 6); ``recent`` is
     the ring-buffer depth behind ``GET /debug/queries``;
     ``long_query_time`` (seconds, 0 = off) logs PQL + trace id + the
     stage breakdown for queries over the threshold — the reference's
@@ -242,8 +245,8 @@ class ObserveConfig:
 
     Engine observatory (pilosa_tpu.perfobs):
     ``device_peak_gbps`` is the memory-bandwidth roof the per-engine
-    achieved GB/s is reported against (``bw_util`` on /debug/cost and
-    in chip captures); 0 (the default) picks a datasheet ballpark per
+    achieved GB/s is reported against (``bw_util`` on /debug/cost);
+    0 (the default) picks a datasheet ballpark per
     jax device kind — set it when the exact part's roof is known.
     ``profiler_max_seconds`` auto-stops an on-demand device profiler
     capture (``POST /debug/profiler/start``) that was never stopped
@@ -252,8 +255,8 @@ class ObserveConfig:
 
     Cluster event journal (pilosa_tpu.observe.EventJournal):
     ``journal`` keeps the structured state-transition ring behind
-    ``GET /debug/events`` on (disarmed cost is one module-bool read,
-    benchmarked in bench.py extras.traceasm); ``journal_size`` is the
+    ``GET /debug/events`` on (disarmed cost is one module-bool read:
+    ``tests/test_observer_cost.py``); ``journal_size`` is the
     ring depth; ``journal_kinds`` is a comma-separated kind-prefix
     allowlist (empty = keep every kind) — filtered emissions tick the
     drop counter so a too-narrow filter is visible."""
@@ -268,21 +271,6 @@ class ObserveConfig:
     journal: bool = True  # the cluster event journal ring
     journal_size: int = 2048  # event ring depth
     journal_kinds: str = ""  # comma-separated kind prefixes; "" = all
-
-
-@dataclass
-class CostConfig:
-    """[cost] — the shadow cost model (pilosa_tpu.perfobs; no
-    reference analog — the stepping stone to a cost-based planner,
-    ROADMAP item 4).  With ``shadow`` on (the default), the
-    executor/coalescer consult the observed-cost table AFTER choosing
-    an engine: the table's verdict is stamped onto the flight record
-    (``wouldChoose``/``costDisagree``) and ``cost.disagreements``
-    ticks, while routing itself stays byte-identical to a consult-free
-    build — there is no active mode yet.  ``shadow = false`` turns the
-    consult off entirely (per-launch samples still collect)."""
-
-    shadow: bool = True
 
 
 @dataclass
@@ -495,7 +483,6 @@ class Config:
     ragged: RaggedConfig = field(default_factory=RaggedConfig)
     vm: VMConfig = field(default_factory=VMConfig)
     observe: ObserveConfig = field(default_factory=ObserveConfig)
-    cost: CostConfig = field(default_factory=CostConfig)
     admission: AdmissionConfig = field(default_factory=AdmissionConfig)
     cache: CacheConfig = field(default_factory=CacheConfig)
     ingest: IngestConfig = field(default_factory=IngestConfig)
@@ -543,7 +530,7 @@ class Config:
             if key in ("cluster", "anti_entropy", "replication",
                        "rebalance", "metric", "tracing",
                        "profile", "tls", "coalescer", "ragged", "vm",
-                       "observe", "cost", "admission", "cache",
+                       "observe", "admission", "cache",
                        "ingest", "containers", "mesh", "residency",
                        "faultinject", "tenants") and isinstance(v, dict):
                 section = getattr(self, key)
@@ -564,7 +551,6 @@ class Config:
                                                         RaggedConfig,
                                                         VMConfig,
                                                         ObserveConfig,
-                                                        CostConfig,
                                                         AdmissionConfig,
                                                         CacheConfig,
                                                         IngestConfig,
@@ -582,7 +568,7 @@ class Config:
             if f.name in ("cluster", "anti_entropy", "replication",
                           "rebalance", "metric", "tracing",
                           "profile", "tls", "coalescer", "ragged",
-                          "vm", "observe", "cost", "admission",
+                          "vm", "observe", "admission",
                           "cache", "ingest", "containers", "mesh",
                           "residency", "faultinject", "tenants"):
                 section = getattr(self, f.name)
@@ -688,9 +674,6 @@ class Config:
             f"journal = {str(self.observe.journal).lower()}",
             f"journal-size = {self.observe.journal_size}",
             f'journal-kinds = "{self.observe.journal_kinds}"',
-            "",
-            "[cost]",
-            f"shadow = {str(self.cost.shadow).lower()}",
             "",
             "[admission]",
             f"enabled = {str(self.admission.enabled).lower()}",

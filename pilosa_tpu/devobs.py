@@ -36,8 +36,8 @@ query answers "slow because it compiled" in one request.
 Lock discipline mirrors observe.py: the per-dispatch fast path is one
 attribute read + two C calls (``_cache_size``), no locks; the
 observer's lock is touched only on the rare compile/transfer events
-and on snapshot.  Budget: < 1% of the coalesced Count path
-(bench.py extras.devobs).
+and on snapshot: a warm fused Count takes it zero times and records
+nothing (``tests/test_observer_cost.py``).
 """
 
 from __future__ import annotations
@@ -358,9 +358,7 @@ class _InstrumentedJit:
     """Wraps one jitted callable with compile-event detection.
 
     Fast path (cache hit, observer disabled): one attribute read and at
-    most two ``_cache_size`` C calls on top of the dispatch — ~0.3 us,
-    vs the ~20 us device-dispatch floor the serving path is built
-    around (VERDICT round 5), so the <1% budget holds by construction.
+    most two ``_cache_size`` C calls on top of the dispatch, no lock.
 
     Detection is the jit cache-size delta around the call: jit only
     grows its cache on a genuine trace+lower+compile, so canonical-form

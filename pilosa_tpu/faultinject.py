@@ -11,8 +11,8 @@ exercised against the code that actually ships.  The design follows
 the freebsd/etcd/pingcap failpoint idiom: sites are compiled in
 permanently, and are **zero-cost when disarmed** — every site is
 gated on the module-level ``armed`` bool, so the disarmed hot path
-pays one attribute load and a falsy test (benchmarked in bench.py
-extras.faultinject, same <1% budget as the observe/admission gates).
+pays one attribute load and a falsy test and never enters :func:`hit`
+(``tests/test_observer_cost.py`` counts it over a fused Count).
 
 Arming surfaces (all feeding :func:`arm`):
 

@@ -178,9 +178,8 @@ FAMILIES: tuple[Family, ...] = (
            live_prefixes=("engine_",), group="engine",
            doc="administration.md"),
     Family("cost", "cost_",
-           "shadow cost model: cost-table samples/cells, shadow "
-           "consults and disagreements, completed profiler captures "
-           "(pilosa_tpu.perfobs)",
+           "cost table: samples and cells, completed profiler "
+           "captures (pilosa_tpu.perfobs)",
            live_prefixes=("cost_",), group="engine",
            doc="administration.md"),
     Family("tenant", "tenant_",

@@ -8,7 +8,7 @@
 // This is the moral analog of the reference's hand-tuned container
 // fast paths (roaring/roaring.go:570 intersectionCount*) — the exact
 // counting loop a CPU should run, where XLA:CPU's generic codegen loses
-// to vectorized popcount by ~8x at bench shapes.
+// to vectorized popcount.
 //
 // Large inputs fan out over std::thread (the analog of the reference's
 // per-shard worker pool, executor.go:2561, collapsed to one kernel):

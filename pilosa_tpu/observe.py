@@ -32,9 +32,12 @@ GIL-atomic) — no lock on the per-span / per-launch hot path.  The
 recorder's own lock is touched once at begin and once at publish
 (keeping the active table and ring buffer safely iterable from
 /debug/queries), plus the stats registry's on the latency-histogram
-observation.  The recorder must stay under 1%
-of the coalesced Count path — benchmarked by ``bench.py``
-(extras.observe).
+observation.  What that costs: the whole span spine read
+``read_p50_ms`` 6.74 -> 6.82 on ``seg-dense`` (the driver's PR 24
+line) and ``?profile=1`` adds 0.25 to 0.45 ms (both PERF.md section
+6); the clock reads, spans and lock takes of one coalesced Count, and
+a disabled recorder beginning no record, are counts
+``tests/test_observer_cost.py`` holds.
 """
 
 from __future__ import annotations
@@ -401,7 +404,7 @@ class QueryRecord:
         "admission", "outcome", "compiles", "cached", "cache_key",
         "delta_notes", "compacted", "hedged", "hedge_wins",
         "hedge_losers", "missing_shards", "tier_notes", "tenant",
-        "engine", "would_choose", "remote",
+        "engine", "remote",
     )
 
     def __init__(self, qid: int, index: str, pql: str,
@@ -436,11 +439,6 @@ class QueryRecord:
         # (last launch wins — the engine that produced the result);
         # plain attribute store, race-free under the GIL
         self.engine: str | None = None
-        # SHADOW cost-model verdict ([cost] shadow=true): the engine
-        # the observed-cost table would have picked when it disagrees
-        # with routing (rendered wouldChoose + costDisagree) — routing
-        # itself is never changed by it
-        self.would_choose: str | None = None
         self.coalesce: dict | None = None
         self.result_sizes: list[int] = []
         self.error: str | None = None
@@ -719,11 +717,6 @@ class QueryRecord:
             d["path"] = self.path
         if self.engine is not None:
             d["engine"] = self.engine
-        # shadow cost-model verdict: present only on a disagreement
-        # (the common agreeing record stays small)
-        if self.would_choose is not None:
-            d["wouldChoose"] = self.would_choose
-            d["costDisagree"] = True
         if self.coalesce is not None:
             c = self.coalesce
             d["coalescer"] = {

@@ -62,7 +62,7 @@ def note_dispatch(name: str) -> None:
         # error(oom) exercises the executor's RESOURCE_EXHAUSTED
         # evict-and-retry without a real allocation failure.  Gated on
         # the module bool so the disarmed hot path pays one attribute
-        # read (bench.py extras.faultinject).
+        # read (tests/test_observer_cost.py).
         _fi.hit("device.dispatch")
     log = getattr(_dispatch, "log", None)
     if log is not None:

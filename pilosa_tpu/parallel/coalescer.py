@@ -1,14 +1,13 @@
 """Cross-query micro-batched dispatch: concurrent count-style queries
 share one device launch.
 
-The Count/Intersect hot path is dispatch-bound on a real chip behind an
-RPC boundary (VERDICT round 5: 0.555 ms/query against a 20 us
-trivial-dispatch floor, bw_util 0.148), and `bench.py`'s batched engine
-proves one fused B=32 launch recovers the headroom.  This module is that
-engine made product code — the serving-side batching lever TPU inference
-stacks pull (Ragged Paged Attention, arxiv 2604.15464) applied to our
-map-reduce-over-shards execution model (DrJAX, arxiv 2403.07128;
-reference executor.go:2455 scatter-gather).
+The Count/Intersect hot path is bound by the host's fixed cost a launch,
+not by the kernel (PERF.md section 5, PR 29: a lone dense read's kernel
+is 0.09 ms of a 3.6 ms read on `seg-dense`), so queries that arrive
+while a launch is in flight share the next one: the serving-side
+batching lever TPU inference stacks pull (Ragged Paged Attention, arxiv
+2604.15464) applied to our map-reduce-over-shards execution model
+(DrJAX, arxiv 2403.07128; reference executor.go:2455 scatter-gather).
 
 Mechanics
 ---------
@@ -109,7 +108,7 @@ FLUSH_WHY = ("idle", "busy", "full", "cap")
 class _Bucket:
     __slots__ = ("items", "sealed", "why",
                  "n_final", "shapes_final", "tape_final", "vm_final",
-                 "flush_t0", "launch_ns", "engine", "would_choose",
+                 "flush_t0", "launch_ns", "engine",
                  "flush_trace", "launch_span")
 
     def __init__(self):
@@ -131,12 +130,10 @@ class _Bucket:
         self.vm_final = False
         self.flush_t0 = 0
         self.launch_ns = 0
-        # the canonical perfobs engine the flush ran, and the shadow
-        # cost model's verdict when it disagreed — followers stamp both
-        # onto their own flight records (the ops-layer sample only sees
-        # the leader's thread)
+        # the canonical perfobs engine the flush ran — followers stamp
+        # it onto their own flight records (the ops-layer sample only
+        # sees the leader's thread)
         self.engine: str | None = None
-        self.would_choose: str | None = None
         # the LEADER's trace id at flush: batchmates inherit the
         # batch's launch span — a follower's /debug/trace tree can
         # point at the trace that actually owns the shared launch
@@ -407,8 +404,6 @@ class Coalescer:
             rec.note_path("coalesced")
             if bucket.engine is not None:
                 rec.note_engine(bucket.engine)
-            if bucket.would_choose is not None:
-                rec.would_choose = bucket.would_choose
             rec.coalesce = {
                 "batch": bucket.n_final,
                 "shapes": bucket.shapes_final,
@@ -555,8 +550,7 @@ class Coalescer:
                 # size-class key every engine's cost-table cell shares)
                 # and bytes-touched / dense-equivalent sparsity — the
                 # perfobs.context scope threads both to the ops-layer
-                # launch sample, and the shadow consult below looks up
-                # candidate engines at the same coordinates
+                # launch sample
                 sig_work = sum(
                     int(lv.size) for it in live for lv in it.leaves)
                 sig_sparsity = 1.0
@@ -696,15 +690,6 @@ class Coalescer:
                                 counts=True, tape_len=tb, slots=lb,
                                 mesh=live[0].mesh))
                 span.note(engine=bucket.engine)
-                # SHADOW cost consult ([cost] shadow): would the table
-                # have routed this batch to a different engine at the
-                # same workload coordinates?  Verdict lands on the
-                # flight records only — the launch above already ran
-                # and is byte-identical either way
-                bucket.would_choose = _perfobs.would_choose(
-                    bucket.engine,
-                    {e: (sig_work, sig_sparsity)
-                     for e in ("dense", "tape", "vm", bucket.engine)})
             bucket.launch_ns = span.end_ns - bucket.flush_t0
         except BaseException as e:  # noqa: BLE001 — every waiter fails
             for it in live:

@@ -1280,12 +1280,10 @@ class Executor:
 
         Memoized per (field, view): ``Intersect(Row(f=a), Row(f=b))``
         touches the same view twice but needs one stamp.  The single
-        pass is what keeps the 0%-hit-rate probe within its <1% budget
-        at wide shard counts (bench.py extras.resultcache): the common
-        fully-populated case batches all dict lookups into one C-level
-        ``itemgetter`` call (~35% cheaper than per-shard ``.get`` at
-        256 shards on the bench box), falling back to the filtering
-        loop only when some shard has no fragment."""
+        pass keeps a probe that misses to one walk over the shards: the
+        common fully-populated case batches all dict lookups into one
+        C-level ``itemgetter`` call, falling back to the filtering loop
+        only when some shard has no fragment."""
         mkey = (id(f), view_name)
         if mkey in out:
             return

@@ -1,6 +1,6 @@
 """Engine observatory: per-launch wall time + bytes-touched accounting,
-achieved bandwidth per engine, the shadow cost model, and on-demand
-device profiler capture.
+achieved bandwidth per engine, the cost table, and on-demand device
+profiler capture.
 
 The flight recorder (pilosa_tpu.observe) explains where a QUERY spent
 its time and devobs explains compile/transfer/memory events — but
@@ -24,20 +24,12 @@ module is that measurement substrate:
   words gathered plus directory scalars for the compressed ones,
   register files for the interpreters.  bytes/wall yields achieved
   GB/s; against the configured roof (``[observe] device-peak-gbps``,
-  defaulted per device kind) that is the ``bw_util`` the chip captures
-  report.
+  defaulted per device kind) that is the ``bw_util`` ``/debug/cost``
+  reports.
 - **Cost table** — samples feed a process-wide EWMA + deviation table
   keyed (engine, work size-class, sparsity bucket), rendered at
-  ``GET /debug/cost`` and summarized per engine for
-  ``tools/chipcapture.py``.
-- **Shadow cost model** — with ``[cost] shadow=true`` (the default)
-  the executor/coalescer consult :func:`would_choose` AFTER routing:
-  the table's verdict lands on the flight record (``wouldChoose`` /
-  ``costDisagree``) and ticks ``cost.disagreements``, while the launch
-  itself is byte-identical to a consult-free build — the stepping
-  stone to ROADMAP item 4's cost-based planner, never the planner
-  itself.  ``shadow=false`` disables the consult entirely (samples
-  still collect).
+  ``GET /debug/cost`` and summarized per engine in the tagged
+  ``engine.*`` gauges.  Nothing routes on it (ROADMAP D4).
 - **Profiler capture** — ``POST /debug/profiler/start|stop`` wraps
   ``jax.profiler.start_trace``/``stop_trace`` into a dated artifact
   dir, try-lock 409 on concurrent capture (the /debug/pprof/profile
@@ -46,8 +38,9 @@ module is that measurement substrate:
 Lock discipline: the disarmed fast path is ONE module-bool read
 (:func:`t0` returns 0 and every sample call gates on it); blocking
 (``block_until_ready``) always happens OUTSIDE the module lock, which
-only covers the table/counter writes.  Budget: < 1% of the coalesced
-Count path (bench.py extras.perfobs).
+only covers the table/counter writes.  With the observatory off a
+fused Count takes neither lock and records no sample
+(``tests/test_observer_cost.py``).
 """
 
 from __future__ import annotations
@@ -61,15 +54,10 @@ from typing import Any
 from pilosa_tpu import observe as _observe
 
 #: The canonical engine taxonomy — the one ``engine`` enum the flight
-#: record, /debug/cost, and the chip captures all share.
+#: record and /debug/cost share.
 ENGINES = ("dense", "gather", "tape", "vm", "mesh", "host",
            "collective", "gather_aa", "gather_ab", "gather_kinds",
            "vm_kinds")
-
-#: Shadow consult requires this many samples in BOTH cells before it
-#: is willing to disagree — a single noisy wall must not tick a
-#: disagreement.
-MIN_SAMPLES = 3
 
 #: EWMA smoothing for wall/bytes/bandwidth per cell.
 ALPHA = 0.2
@@ -94,17 +82,14 @@ KIND_PEAKS: tuple[tuple[str, float], ...] = (
 
 
 class PerfobsRuntimeConfig:
-    """Process-wide observatory knobs (``[observe]`` + ``[cost]``)."""
+    """Process-wide observatory knobs (``[observe]``)."""
 
-    __slots__ = ("enabled", "peak_gbps", "shadow",
-                 "profiler_max_seconds")
+    __slots__ = ("enabled", "peak_gbps", "profiler_max_seconds")
 
     def __init__(self, enabled: bool = True, peak_gbps: float = 0.0,
-                 shadow: bool = True,
                  profiler_max_seconds: float = 30.0):
         self.enabled = enabled
         self.peak_gbps = peak_gbps  # 0 = default per device kind
-        self.shadow = shadow
         self.profiler_max_seconds = profiler_max_seconds
 
 
@@ -125,7 +110,6 @@ def config() -> PerfobsRuntimeConfig:
 
 def configure(enabled_: bool | None = None,
               peak_gbps: float | None = None,
-              shadow: bool | None = None,
               profiler_max_seconds: float | None = None) -> None:
     """Apply explicit values only (the containers.configure rule: an
     absent kwarg leaves the knob untouched)."""
@@ -135,8 +119,6 @@ def configure(enabled_: bool | None = None,
             _cfg.enabled = enabled_
         if peak_gbps is not None:
             _cfg.peak_gbps = peak_gbps
-        if shadow is not None:
-            _cfg.shadow = shadow
         if profiler_max_seconds is not None:
             _cfg.profiler_max_seconds = profiler_max_seconds
         enabled = _cfg.enabled
@@ -149,8 +131,7 @@ def retain() -> None:
     with _cfg_lock:
         if _refs == 0:
             _baseline = PerfobsRuntimeConfig(
-                _cfg.enabled, _cfg.peak_gbps, _cfg.shadow,
-                _cfg.profiler_max_seconds)
+                _cfg.enabled, _cfg.peak_gbps, _cfg.profiler_max_seconds)
         _refs += 1
 
 
@@ -165,7 +146,6 @@ def release() -> None:
         if _refs == 0 and _baseline is not None:
             _cfg.enabled = _baseline.enabled
             _cfg.peak_gbps = _baseline.peak_gbps
-            _cfg.shadow = _baseline.shadow
             _cfg.profiler_max_seconds = _baseline.profiler_max_seconds
             _baseline = None
             enabled = _cfg.enabled
@@ -228,9 +208,6 @@ _counters = {
     "engine.launches": 0,       # sampled steady-state launches
     "engine.bytes": 0,          # analytic bytes across sampled launches
     "cost.samples": 0,          # cost-table sample insertions
-    "cost.consults": 0,         # shadow-mode comparisons performed
-    "cost.disagreements": 0,    # consults where the table preferred
-                                # a different engine than routing chose
     "cost.profiles": 0,         # completed profiler captures
 }
 
@@ -245,14 +222,6 @@ def counters() -> dict[str, int]:
         return dict(_counters)
 
 
-def reset_counters() -> None:
-    """Zero counters and the cost table (tests)."""
-    with _lock:
-        for k in _counters:
-            _counters[k] = 0
-        _table.clear()
-
-
 def publish_gauges(stats: Any) -> None:
     """Push the engine.*/cost.* families into a stats registry at
     scrape time — cumulative totals as GAUGES (the tape/devobs rule:
@@ -264,7 +233,6 @@ def publish_gauges(stats: Any) -> None:
     for name, value in snap.items():
         stats.gauge(name, value)
     stats.gauge("cost.cells", cells)
-    stats.gauge("cost.shadow", 1 if config().shadow else 0)
     # 0 = no roof known for this device kind (then no bw_util either)
     stats.gauge("engine.peak_gbps", device_peak_gbps() or 0.0)
     for eng, s in engine_summary().items():
@@ -435,8 +403,8 @@ def launch(engine: str, fn: Any) -> Any:
     site (the TopN matrix scan, a GroupBy level, the BSI plane ops, a
     range compare): the flight record's ``launch`` span with its
     ``launch.dispatch`` / ``launch.ready`` children, and the engine
-    stamp.  No cost-table sample — these shapes are not what the
-    shadow model compares.  With no record it is just ``fn()``."""
+    stamp.  No cost-table sample: the table holds the Count engines
+    only.  With no record it is just ``fn()``."""
     rec = _observe.current()
     if rec is None:
         return fn()
@@ -477,51 +445,12 @@ def record_sample(engine: str, wall_ns: int, nbytes: int,
         _counters["cost.samples"] += 1
 
 
-# --------------------------------------------------------------- shadow model
-
-
-def would_choose(chosen: str,
-                 candidates: dict[str, tuple[int, float]]) -> str | None:
-    """SHADOW-mode cost consult: given the engine routing chose and
-    each candidate engine's (work, sparsity) coordinates for THIS
-    batch, return the engine the cost table would have picked instead,
-    or None when it agrees / lacks confident data.  Ticks
-    ``cost.consults`` always and ``cost.disagreements`` on a disagree.
-    Never changes routing — callers only stamp the verdict onto the
-    flight record (``[cost] shadow=false`` turns the consult off
-    entirely)."""
-    if not enabled or not config().shadow:
-        return None
-    with _lock:
-        _counters["cost.consults"] += 1
-        chosen_cell = None
-        best = None
-        best_us = float("inf")
-        for eng, (work, sparsity) in candidates.items():
-            cell = _table.get((eng, size_class(work),
-                               sparsity_bucket(sparsity)))
-            if cell is None or cell.count < MIN_SAMPLES:
-                if eng == chosen:
-                    return None  # no confident baseline to disagree with
-                continue
-            if eng == chosen:
-                chosen_cell = cell
-            if cell.ewma_us < best_us:
-                best, best_us = eng, cell.ewma_us
-        if (best is None or best == chosen or chosen_cell is None
-                or best_us >= chosen_cell.ewma_us):
-            return None
-        _counters["cost.disagreements"] += 1
-        return best
-
-
 # ------------------------------------------------------------------- exports
 
 
 def engine_summary() -> dict[str, dict]:
     """Per-engine rollup of the cost table (sample-count-weighted):
-    the measured bw_util slice chip captures stamp
-    (tools/chipcapture.py) and the tagged engine.* gauges."""
+    ``/debug/cost`` ``engines`` and the tagged engine.* gauges."""
     peak = device_peak_gbps()
     out: dict[str, dict] = {}
     with _lock:
@@ -560,7 +489,6 @@ def cost_debug() -> dict:
         snap = dict(_counters)
     return {
         "enabled": cfg.enabled,
-        "shadow": cfg.shadow,
         "peakGbps": peak,
         "counters": snap,
         "engines": engine_summary(),

@@ -11,8 +11,8 @@ submits the hottest ones to the promotion pool as PREFETCH work
 before a query stalls on them.  On a zipfian row mix this converts
 most would-be promotion waits into plain HBM hits — the
 ``prefetch.useful`` counter (a query touching a prefetcher-installed
-entry) is the direct evidence, and bench.py extras.residency pins the
-prefetch-on stall rate strictly below prefetch-off.
+entry) is the direct evidence.  Its effect on the stall rate is not
+measured on the chip: no cell's working set exceeds HBM (ROADMAP S7).
 
 Prefetch work is the FIRST thing shed under pressure: the promoter
 refuses prefetch jobs on a full queue (and evicts queued prefetch

@@ -4,11 +4,12 @@ The reference eagerly opens + mmaps every fragment at startup
 (holder.go:137 -> view.go:117-177), so a restarted server answers its
 first query immediately.  Here fragments also load eagerly at open, but
 the fused executor path adds one more tier the reference doesn't have:
-device/host row stacks assembled on first touch.  At the 10B-column
-north-star shape that first touch is ~2 x 1.25 GB of stack assembly —
-measured at 18.6 s after a bulk import (the background compaction of
-9,537 fresh fragments competes for the same core) — a tail the warm
-179 ms steady state never shows (VERDICT round-2 missing #3).
+device/host row stacks assembled on first touch.  At a 10B-column
+shape that first touch is ~2 x 1.25 GB of stack assembly, competing
+with the background compaction of the freshly imported fragments — a
+tail the warm steady state never shows.  On the chip the benchmark's
+warm-up pays it inside ``setup_s``; the first query alone is not
+measured there.
 
 This module shifts that cost off the first query.  Bulk imports and
 holder open enqueue the touched field+rows; one background worker

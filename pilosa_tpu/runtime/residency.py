@@ -916,8 +916,8 @@ class ResidencyManager:
         prefetcher's victim selection.  A prefetch promotion that let
         the ordinary LRU eviction pick its victim displaces whatever
         was least-recently TOUCHED, which under a skewed mix is often
-        a hot-but-not-just-now row — measured on the zipfian bench as
-        prefetching making stalls WORSE.  Choosing the victim by the
+        a hot-but-not-just-now row, so prefetching can make stalls
+        worse.  Choosing the victim by the
         same access-frequency signal that chose the candidate turns
         the pair into a strict improvement and converges (once
         residents are the top-scored set, every candidate fails the

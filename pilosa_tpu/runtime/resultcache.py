@@ -1,10 +1,11 @@
 """Generation-stamped query result cache: repeated reads skip the
 device entirely.
 
-VERDICT round 5 established the Count/Intersect hot path is
-dispatch-bound, not HBM-bound (a ~20 us trivial-dispatch floor under a
-0.555 ms/query chip capture, bw_util 0.148) — so for read-heavy traffic
-the biggest remaining win is to not launch at all.  The reference ships
+The Count/Intersect hot path is bound by the host's fixed cost a
+launch, not by HBM (PERF.md section 5, PR 29: on `seg-dense` a cached
+read's root is 0.70 ms against 2.49 ms for a lone dense launch whose
+kernel is 0.09 ms) — so for read-heavy traffic the biggest remaining
+win is to not launch at all.  The reference ships
 only the per-fragment rank cache (cache.go:136, ported as
 models/cache.py with exact generation-stamped counts); this module
 generalizes the same idiom to whole PQL subtrees, the classic
@@ -477,24 +478,6 @@ class ResultCache:
                 self._resolve_flight_locked(key)
             self.invalidations += len(victims)
             return len(victims)
-
-    def invalidate_all(self) -> int:
-        """Drop everything (operator escape hatch / tests).  Counted
-        as invalidations.  Open flights resolve (waiters wake, miss,
-        and compute) rather than linger against cleared entries."""
-        with self._lock:
-            n = len(self._entries)
-            self._entries.clear()
-            self.bytes = 0
-            self._tenant_bytes.clear()
-            self._tenant_lru.clear()
-            self.invalidations += n
-            for fl in self._flights.values():
-                fl.event.set()
-            self._flights.clear()
-            return n
-
-    # ------------------------------------------------------------- exports
 
     def stats_dict(self) -> dict[str, Any]:
         with self._lock:

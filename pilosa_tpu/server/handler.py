@@ -1294,8 +1294,8 @@ class Handler:
         """Engine observatory state (pilosa_tpu.perfobs): per-launch
         cost table keyed (engine, work size-class, sparsity bucket)
         with EWMA wall/bytes/achieved-GB/s per cell, the per-engine
-        bw_util rollup against the configured bandwidth roof, shadow
-        consult counters, and the device-profiler capture status."""
+        bw_util rollup against the configured bandwidth roof, and the
+        device-profiler capture status."""
         from pilosa_tpu import perfobs
 
         self._json(req, perfobs.cost_debug())
@@ -1882,8 +1882,8 @@ class Handler:
             _stagecheck.publish_gauges(self.stats)
             _meshexec.publish_gauges(self.stats)
             # engine observatory: launch/bytes totals, cost-table
-            # size, shadow consult counters, per-engine tagged
-            # bandwidth — zeros on a clean server
+            # size, per-engine tagged bandwidth — zeros on a clean
+            # server
             _perfobs.publish_gauges(self.stats)
             # chaos-round families: breakers, hedged reads, failpoints,
             # partial degradation — zeros on a clean server so the

@@ -72,7 +72,6 @@ class Server:
         observe_journal: bool = True,
         observe_journal_size: int = 2048,
         observe_journal_kinds: str = "",
-        cost_shadow: bool = True,
         admission_enabled: bool = True,
         admission_query_cap: int = 32,
         admission_query_queue: int = 128,
@@ -331,7 +330,7 @@ class Server:
         _meshexec.configure(enabled=mesh_enabled,
                             axis_size=mesh_axis_size)
         # engine observatory ([observe] device-peak-gbps /
-        # profiler-max-seconds + [cost] shadow): process-wide like
+        # profiler-max-seconds): process-wide like
         # [mesh] — the first server's retain() captures the pre-server
         # baseline, the LAST release() (in close) restores it
         from pilosa_tpu import perfobs as _perfobs
@@ -341,7 +340,6 @@ class Server:
         self._perfobs_cfg = dict(
             enabled_=observe_enabled,
             peak_gbps=observe_device_peak_gbps,
-            shadow=cost_shadow,
             profiler_max_seconds=observe_profiler_max_seconds)
         _perfobs.configure(**self._perfobs_cfg)
         # per-tenant isolation ([tenants] config): process-wide like

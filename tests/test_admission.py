@@ -252,9 +252,11 @@ class TestController:
         assert ctrl.total_capacity() == 8
 
     def test_uncontended_overhead_small(self):
-        """The gate must be invisible on the uncontended path; the real
-        <1% pin is bench.py extras.admission — this is the coarse CI
-        regression net against a lock disaster."""
+        """The gate must be invisible on the uncontended path; what
+        it takes is counted in tests/test_observer_cost.py (two lock
+        takes and one counter an admitted request, none with the gate
+        off) — this is the coarse CI regression net against a lock
+        disaster."""
         ctrl = _controller()
         ctrl.acquire("query").release()
         n = 2000

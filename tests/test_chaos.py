@@ -102,7 +102,8 @@ class TestFailpoints:
     def test_disarmed_gate_is_module_bool(self):
         """The zero-overhead contract: sites gate on ``fi.armed``
         before calling hit(), so the disarmed hot path pays one
-        attribute read (bench.py extras.faultinject pins the cost)."""
+        attribute read (tests/test_observer_cost.py counts hit() and
+        the registry lock over a fused Count: zero)."""
         assert fi.armed is False
         fi.arm("device.dispatch=error")
         assert fi.armed is True

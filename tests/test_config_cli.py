@@ -3,6 +3,7 @@ merge cmd/root.go:94; ctl/ subcommands)."""
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import urllib.request
@@ -69,13 +70,9 @@ class TestConfig:
         assert cfg2.bind == cfg.bind
 
 
-@pytest.fixture
-def running_server(tmp_path):
-    """A node run through the real CLI server path on a random port."""
-    cfg = Config()
-    cfg.data_dir = str(tmp_path / "data")
-    cfg.bind = "127.0.0.1:0"
-    cfg.anti_entropy.interval = 0
+@contextlib.contextmanager
+def cli_server(cfg):
+    """``cfg`` run through the real CLI server path -> the Server."""
     ready, stop = threading.Event(), threading.Event()
     holder = {}
 
@@ -97,10 +94,24 @@ def running_server(tmp_path):
 
     t = threading.Thread(target=run, daemon=True)
     t.start()
-    assert ready.wait(30)
-    yield holder["srv"]
-    stop.set()
-    t.join(timeout=10)
+    try:
+        assert ready.wait(60)
+        yield holder["srv"]
+    finally:
+        stop.set()
+        t.join(timeout=30)
+    assert not t.is_alive()
+
+
+@pytest.fixture
+def running_server(tmp_path):
+    """A node run through the real CLI server path on a random port."""
+    cfg = Config()
+    cfg.data_dir = str(tmp_path / "data")
+    cfg.bind = "127.0.0.1:0"
+    cfg.anti_entropy.interval = 0
+    with cli_server(cfg) as srv:
+        yield srv
 
 
 class TestCLI:

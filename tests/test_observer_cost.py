@@ -1,0 +1,377 @@
+"""What the observers cost a fused Count, as counts.
+
+Seven observers sit on the Count path: failpoints (faultinject), the
+event journal behind the trace assembler (traceasm), the admission
+gate, per-tenant accounting, the query flight recorder (observe),
+device-runtime telemetry (devobs) and the engine observatory
+(perfobs).  Each promises that its disabled (or, for the journal and
+devobs, idle) path is an attribute read: no call into its recording
+functions, no lock of its own, no clock read.  A wall-clock A/B on a
+shared CPU cannot hold such a promise; a counting fake can.  Every
+case also turns its observer ON and sees the same fakes count, so a
+zero is never the zero of a fake that was not wired in.
+
+What the observers cost in time is the chip's to say: PERF.md
+section 6 (the span spine, ``?profile=1``).
+"""
+
+from __future__ import annotations
+
+import json
+import time
+import urllib.request
+
+import numpy as np
+import pytest
+
+from pilosa_tpu import devobs
+from pilosa_tpu import faultinject as fi
+from pilosa_tpu import observe, perfobs
+from pilosa_tpu import stats as _stats
+from pilosa_tpu.models.holder import Holder
+from pilosa_tpu.ops import bitmap as bm
+from pilosa_tpu.parallel.coalescer import Coalescer
+from pilosa_tpu.parallel.executor import ExecOptions, Executor
+from pilosa_tpu.runtime import resultcache
+from pilosa_tpu.serve import tenant
+from pilosa_tpu.serve.admission import AdmissionController
+from pilosa_tpu.shardwidth import SHARD_WIDTH
+from tests.coalesce_batch import wait_until
+
+N_SHARDS = 4
+QUERY = "Count(Intersect(Row(f=1), Row(f=2)))"
+#: one device, as on a one-chip server (conftest's 8 virtual CPU
+#: devices would otherwise route every read through the mesh)
+ONE_DEVICE = ExecOptions(mesh=False)
+
+
+class Calls:
+    """A callable that counts its calls and passes them through."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.n = 0
+
+    def __call__(self, *args, **kwargs):
+        self.n += 1
+        return self.fn(*args, **kwargs)
+
+
+class CountingLock:
+    """Stands in for a ``threading.Lock``: counts every acquisition."""
+
+    def __init__(self, lock):
+        self.lock = lock
+        self.n = 0
+
+    def acquire(self, *args, **kwargs):
+        self.n += 1
+        return self.lock.acquire(*args, **kwargs)
+
+    def release(self):
+        self.lock.release()
+
+    def __enter__(self):
+        self.n += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+def _calls(monkeypatch, owner, name) -> Calls:
+    c = Calls(getattr(owner, name))
+    monkeypatch.setattr(owner, name, c)
+    return c
+
+
+def _lock(monkeypatch, owner, name) -> CountingLock:
+    c = CountingLock(getattr(owner, name))
+    monkeypatch.setattr(owner, name, c)
+    return c
+
+
+def _dense_rows():
+    """Rows 1 and 2 at 40% fill (over the 25% containers threshold:
+    dense leaves) across four shards -> (rows, columns) to import."""
+    rng = np.random.default_rng(30)
+    n = N_SHARDS * SHARD_WIDTH
+    for row in (1, 2):
+        cols = np.flatnonzero(rng.random(n) < 0.4)
+        yield [row] * len(cols), cols.tolist()
+
+
+@pytest.fixture
+def ex(tmp_path):
+    """A bare executor over the dense rows, staged and compiled once
+    (conftest gives every test a fresh result cache)."""
+    holder = Holder(str(tmp_path / "h"))
+    f = holder.create_index("i").create_field("f")
+    for rows, cols in _dense_rows():
+        f.import_bits(rows, cols)
+    resultcache.cache().enabled = False  # every Count reaches the engine
+    perfobs.reset()
+    ex = Executor(holder)
+    assert _count(ex) > 0
+    yield ex
+    perfobs.reset()
+    holder.close()
+
+
+def _count(ex):
+    return ex.execute("i", QUERY, opt=ONE_DEVICE)[0]
+
+
+def _faultinject(ex, monkeypatch):
+    hit = _calls(monkeypatch, fi, "hit")
+    lock = _lock(monkeypatch, fi, "_lock")
+    assert fi.armed is False
+    want = _count(ex)
+    off = (hit.n, lock.n)
+    fi.arm("device.dispatch=delay(0)")
+    try:
+        assert _count(ex) == want
+    finally:
+        fi.disarm()
+    return off, (hit.n, 0), "disarmed"
+
+
+def _traceasm(ex, monkeypatch):
+    """The journal records state transitions, never queries: on or
+    off, a warm Count emits nothing; off, an emission site stops at
+    the module bool."""
+    journal = observe.journal()
+    emit = _calls(monkeypatch, journal, "emit")
+    lock = _lock(monkeypatch, journal, "_lock")
+    monkeypatch.setattr(observe, "journal_on", True)
+    _count(ex)
+    assert (emit.n, lock.n) == (0, 0)
+    monkeypatch.setattr(observe, "journal_on", False)
+    _count(ex)
+    observe.emit("test.event")
+    off = (emit.n, lock.n)
+    monkeypatch.setattr(observe, "journal_on", True)
+    observe.emit("test.event")
+    return off, (emit.n, lock.n), "journal = false"
+
+
+def _admission(ex, monkeypatch):
+    """The gate stands in the handler, around the executor's call."""
+    stats = _stats.MemStatsClient()
+    emitted = _calls(monkeypatch, stats, "count_with_tags")
+    ctrl = AdmissionController(enabled=False, stats=stats)
+    lock = _lock(monkeypatch, ctrl, "_lock")
+    policy = _calls(monkeypatch, tenant, "policy")
+
+    def admitted_count():
+        ticket = ctrl.acquire("query")
+        try:
+            return _count(ex)
+        finally:
+            ticket.release()
+
+    admitted_count()
+    off = (emitted.n, lock.n, policy.n)
+    ctrl.enabled = True
+    admitted_count()
+    # on and uncontended: one lock to admit, one to release, one
+    # counter; a third lock would be a lock on every served read
+    assert lock.n - off[1] <= 2 and emitted.n - off[0] <= 1
+    return off, (emitted.n, lock.n, 0), "[admission] enabled = false"
+
+
+def _tenants(ex, monkeypatch):
+    resolve = _calls(monkeypatch, tenant, "resolve")
+    quota_for = _calls(monkeypatch, tenant.TenantsRuntimeConfig,
+                       "quota_for")
+    lock = _lock(monkeypatch, tenant, "_cfg_lock")
+    rc = resultcache.cache()
+    monkeypatch.setattr(rc, "enabled", True)  # its put/get charge tenants
+    assert tenant.policy() is None
+    _count(ex)
+    _count(ex)  # a hit
+    off = (resolve.n, quota_for.n, lock.n, len(rc._tenant_bytes))
+    monkeypatch.setattr(tenant.config(), "enabled", True)
+    try:
+        ex.execute("i", "Count(Row(f=1))", opt=ONE_DEVICE)
+    finally:
+        monkeypatch.setattr(tenant.config(), "enabled", False)
+    return off, (resolve.n, 0, 0, 0), "[tenants] enabled = false"
+
+
+class _CountingSpan(observe._Span):
+    made = 0
+
+    def __init__(self, *args):
+        type(self).made += 1
+        super().__init__(*args)
+
+
+def _observe(ex, monkeypatch):
+    clock = _calls(monkeypatch, observe, "clock_ns")
+    monkeypatch.setattr(_CountingSpan, "made", 0)
+    monkeypatch.setattr(observe, "_Span", _CountingSpan)
+    lock = _lock(monkeypatch, ex.recorder, "_lock")
+    begin = _calls(monkeypatch, ex.recorder, "begin")
+    monkeypatch.setattr(ex.recorder, "enabled", False)
+    _count(ex)
+    # what is left is not the recorder's: the per-call stats timing
+    # (``execute.Count``) is one span with no record, two clock reads
+    assert (_CountingSpan.made, clock.n) == (1, 2)
+    off = (begin.n, lock.n)
+    monkeypatch.setattr(ex.recorder, "enabled", True)
+    _count(ex)
+    return off, (begin.n, lock.n), "[observe] enabled = false"
+
+
+class _Time:
+    """``devobs``'s view of the ``time`` module, counting."""
+
+    def __init__(self):
+        self.time = time.time
+        self.perf_counter_ns = Calls(time.perf_counter_ns)
+
+
+def _devobs(ex, monkeypatch):
+    obs = devobs.observer()
+    clock = _Time()
+    monkeypatch.setattr(devobs, "time", clock)
+    lock = _lock(monkeypatch, obs, "_lock")
+    compiles = _calls(monkeypatch, obs, "note_compile")
+    monkeypatch.setattr(obs, "enabled", False)
+    _count(ex)
+    off = (clock.perf_counter_ns.n, lock.n, compiles.n)
+    monkeypatch.setattr(obs, "enabled", True)
+    with bm.dispatch_counter() as dc:
+        _count(ex)
+    # on and warm: one clock read a jitted dispatch, and still no
+    # lock and no event (the observer's lock is for compiles,
+    # transfers and snapshots)
+    assert clock.perf_counter_ns.n <= dc.n
+    assert (lock.n, compiles.n) == (0, 0)
+    return off, (clock.perf_counter_ns.n, 0, 0), "observer().enabled = False"
+
+
+def _perfobs(ex, monkeypatch):
+    sample = _calls(monkeypatch, perfobs, "record_sample")
+    block = _calls(monkeypatch, perfobs, "_block")
+    clock = _calls(monkeypatch, perfobs, "_clock")
+    lock = _lock(monkeypatch, perfobs, "_lock")
+    cfg_lock = _lock(monkeypatch, perfobs, "_cfg_lock")
+    perfobs.configure(enabled_=False)
+    cfg_lock.n = 0
+    _count(ex)
+    off = (sample.n, block.n, clock.n, lock.n, cfg_lock.n)
+    perfobs.configure(enabled_=True)
+    cfg_lock.n = 0
+    with bm.dispatch_counter() as dc:
+        _count(ex)
+    # on: one sample a launch, under one take of the table's lock;
+    # the config lock is for configure() alone
+    assert sample.n == dc.n == 1
+    assert (lock.n, cfg_lock.n) == (1, 0)
+    return off, (sample.n, block.n, 0, 0, 0), "[observe] enabled = false"
+
+
+OBSERVERS = {
+    "faultinject": _faultinject,
+    "traceasm": _traceasm,
+    "admission": _admission,
+    "tenants": _tenants,
+    "observe": _observe,
+    "devobs": _devobs,
+    "perfobs": _perfobs,
+}
+
+
+@pytest.mark.parametrize("observer", list(OBSERVERS))
+def test_observer_off_is_no_call_no_lock_no_clock(ex, monkeypatch,
+                                                  observer):
+    """One fused Count through the executor with ``observer`` off:
+    zero calls into its recording functions, zero takes of its locks,
+    zero reads of its clock; turned on, the same fakes count."""
+    off, on, switch = OBSERVERS[observer](ex, monkeypatch)
+    assert not any(off), (observer, switch, off)
+    assert any(on), (observer, "the fakes never counted", on)
+
+
+#: what a coalesced lone dense Count costs with the flight recorder
+#: on and no ``?profile=1``, read on this tree at PR 30: (clock reads,
+#: spans) from ``Executor.execute`` down, and for the whole served
+#: request (the handler's ``http.request``, ``http.parse``,
+#: ``http.read``, ``admission.wait``, ``pql.parse`` and ``serialize``
+#: on top).  Ceilings, not targets: a PR that adds a span to the
+#: served read raises them here, in the open.
+LONE_DENSE = {"executor": (24, 13), "http": (35, 19)}
+
+
+@pytest.fixture
+def served(tmp_path):
+    from pilosa_tpu.server.server import Server
+
+    srv = Server(str(tmp_path / "srv"), port=0, coalescer_enabled=True,
+                 cache_enabled=False)
+    srv.open()
+    srv.api.create_index("i")
+    srv.api.create_field("i", "f")
+    for rows, cols in _dense_rows():
+        srv.api.import_bits("i", "f", rows, cols)
+    yield srv
+    srv.close()
+
+
+def _post_count(srv):
+    req = urllib.request.Request(
+        f"{srv.uri}/index/i/query?nomesh=1", data=QUERY.encode(),
+        method="POST")
+    with urllib.request.urlopen(req, timeout=60) as resp:
+        return json.loads(resp.read())["results"][0]
+
+
+@pytest.mark.parametrize("door", list(LONE_DENSE))
+def test_recorder_on_lone_dense_count_clock_reads_and_spans(
+        request, monkeypatch, door):
+    """The served read of ``seg-dense``: coalesced, nothing in flight
+    (``why = idle``), one dense launch, the flight recorder on, no
+    ``?profile=1``.  Pins its clock reads and spans, that the
+    recorder's lock is taken at begin and publish only, and that the
+    flush takes the cost table's lock once (the sample) and the
+    observatory's config lock never."""
+    if door == "http":
+        srv = request.getfixturevalue("served")
+        recorder = srv.node.executor.recorder
+        root = "http.request"
+    else:
+        ex = request.getfixturevalue("ex")
+        ex.coalescer = Coalescer(enabled=True,
+                                 stats=_stats.MemStatsClient())
+        recorder = ex.recorder
+        root = "exec"
+
+    def run():
+        n = _post_count(srv) if door == "http" else _count(ex)
+        # the handler closes ``http.request`` after the client has its
+        # answer: wait, or that clock read lands in the next request's
+        # count (no lock: a deque's [-1] is atomic)
+        assert wait_until(lambda: any(
+            s[2] == root for s in recorder._recent[-1].spans), timeout=10)
+        return n
+
+    assert run() > 0  # staged, and the coalesced program compiled
+    clock = _calls(monkeypatch, observe, "clock_ns")
+    rec_lock = _lock(monkeypatch, recorder, "_lock")
+    table_lock = _lock(monkeypatch, perfobs, "_lock")
+    cfg_lock = _lock(monkeypatch, perfobs, "_cfg_lock")
+    run()
+    locks = (rec_lock.n, table_lock.n, cfg_lock.n)
+    rec = recorder.recent_records()[-1]
+    assert (rec.path, rec.engine, len(rec.launches)) == (
+        "coalesced", "dense", 1)
+    assert rec.coalesce["batch"] == 1 and rec.coalesce["why"] == "idle"
+    names = [s[2] for s in rec.spans]
+    assert {"exec", "stage", "coalesce.wait", "launch",
+            "launch.dispatch", "launch.ready", "reduce"} <= set(names)
+    reads, spans = LONE_DENSE[door]
+    assert len(names) <= spans, names
+    assert clock.n <= reads, clock.n
+    assert locks == (2, 1, 0)

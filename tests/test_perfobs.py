@@ -1,6 +1,5 @@
 """Engine observatory (pilosa_tpu.perfobs): per-launch wall/bytes
-accounting, the EWMA cost table under a fake clock, the SHADOW cost
-consult (byte-identical routing + disagreement stamping), on-demand
+accounting, the EWMA cost table under a fake clock, on-demand
 profiler capture (roundtrip, busy/idle 409 discipline), the canonical
 ``engine`` enum on flight records per routing escape, and the
 /debug/cost + engine_/cost_ metric-family HTTP surface.
@@ -60,12 +59,6 @@ class _FakeClock:
     def __call__(self) -> int:
         self.now += self.step
         return self.now
-
-
-def _seed(engine, wall_ns, work, sparsity=1.0, n=perfobs.MIN_SAMPLES):
-    for _ in range(n):
-        perfobs.record_sample(engine, wall_ns, 1024, work=work,
-                              sparsity=sparsity)
 
 
 # ---------------------------------------------------------------------------
@@ -143,49 +136,6 @@ class TestCostMath:
         assert s["gbps"] == pytest.approx(1.0)
         assert s["bwUtil"] == pytest.approx(0.1)
         assert perfobs.device_peak_gbps() == 10.0
-
-
-# ---------------------------------------------------------------------------
-# Shadow cost model
-# ---------------------------------------------------------------------------
-
-
-class TestShadow:
-    def test_disagreement_ticks_and_returns_winner(self):
-        _seed("vm", 50_000_000, work=4096)
-        _seed("tape", 1_000_000, work=4096)
-        got = perfobs.would_choose(
-            "vm", {"vm": (4096, 1.0), "tape": (4096, 1.0)})
-        assert got == "tape"
-        snap = perfobs.counters()
-        assert snap["cost.consults"] == 1
-        assert snap["cost.disagreements"] == 1
-
-    def test_agreement_returns_none(self):
-        _seed("vm", 1_000_000, work=4096)
-        _seed("tape", 50_000_000, work=4096)
-        assert perfobs.would_choose(
-            "vm", {"vm": (4096, 1.0), "tape": (4096, 1.0)}) is None
-        snap = perfobs.counters()
-        assert snap["cost.consults"] == 1
-        assert snap["cost.disagreements"] == 0
-
-    def test_unconfident_chosen_cell_returns_none(self):
-        # the candidate is confidently fast, but routing's own cell
-        # has no baseline -> nothing to disagree WITH
-        _seed("tape", 1_000_000, work=4096)
-        _seed("vm", 50_000_000, work=4096, n=perfobs.MIN_SAMPLES - 1)
-        assert perfobs.would_choose(
-            "vm", {"vm": (4096, 1.0), "tape": (4096, 1.0)}) is None
-        assert perfobs.counters()["cost.disagreements"] == 0
-
-    def test_shadow_off_skips_consult_entirely(self):
-        _seed("vm", 50_000_000, work=4096)
-        _seed("tape", 1_000_000, work=4096)
-        perfobs.configure(shadow=False)
-        assert perfobs.would_choose(
-            "vm", {"vm": (4096, 1.0), "tape": (4096, 1.0)}) is None
-        assert perfobs.counters()["cost.consults"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -286,31 +236,6 @@ class TestEngineAttribution:
         assert d["counters"]["cost.samples"] == \
             d["counters"]["engine.launches"]
 
-    def test_shadow_disagreement_lands_on_records(self, ex):
-        """Seed every (size-class, sparsity) cell so the table
-        confidently prefers tape over vm, run a vm batch, and the
-        verdict appears on the flight records — while results stay
-        exactly what routing produced."""
-        for k in range(31):
-            for sp in (0.0, 0.005, 0.05, 0.3, 0.7):
-                _seed("vm", 50_000_000, work=2 ** k, sparsity=sp)
-                _seed("tape", 1_000_000, work=2 ** k, sparsity=sp)
-        qs = [f"Count({t})" for t in SHAPES_16]
-        want = [ex.execute("i", q, opt=VMOPT)[0] for q in qs]
-        _attach(ex, window_s=2.0, max_batch=16)
-        got, launches = _run_concurrent(ex, qs)
-        assert got == want          # shadow never changes routing
-        assert launches == ["vm"], launches
-        recs = ex.recorder.recent_records()[-len(qs):]
-        assert all(r.engine == "vm" for r in recs)
-        assert all(r.would_choose == "tape" for r in recs)
-        d = recs[-1].to_dict()
-        assert d["wouldChoose"] == "tape"
-        assert d["costDisagree"] is True
-        snap = perfobs.counters()
-        assert snap["cost.consults"] >= 1
-        assert snap["cost.disagreements"] >= 1
-
 
 # ---------------------------------------------------------------------------
 # HTTP surface + metric families + config knobs
@@ -362,9 +287,9 @@ class TestHTTP:
     def test_debug_cost_document_and_engine_field(self, srv):
         self._query(srv)
         d = _get(srv.uri, "/debug/cost")
-        assert set(d) == {"enabled", "shadow", "peakGbps", "counters",
+        assert set(d) == {"enabled", "peakGbps", "counters",
                           "engines", "table", "profiler"}
-        assert d["enabled"] is True and d["shadow"] is True
+        assert d["enabled"] is True
         # a CPU host has no roof in KIND_PEAKS: no assumed peak, and
         # so no utilization figure
         assert d["peakGbps"] is None
@@ -374,12 +299,6 @@ class TestHTTP:
         # the canonical enum renders on the flight record
         recs = _get(srv.uri, "/debug/queries")["recent"]
         assert recs and recs[-1]["engine"] in perfobs.ENGINES
-
-    def test_shadow_toggle_is_byte_identical(self, srv):
-        on = self._query(srv)
-        perfobs.configure(shadow=False)
-        off = self._query(srv)
-        assert on == off  # byte-identical body, consult on or off
 
     def test_profiler_routes_roundtrip_and_409(self, srv):
         code, out = _post(srv.uri, "/debug/profiler/start?seconds=0")
@@ -401,8 +320,7 @@ class TestHTTP:
             text = resp.read().decode()
         for name in ("engine_launches", "engine_bytes",
                      "engine_peak_gbps", "cost_samples",
-                     "cost_consults", "cost_disagreements",
-                     "cost_cells", "cost_shadow"):
+                     "cost_cells", "cost_profiles"):
             assert name in text, name
 
     def test_families_declared(self):
@@ -415,22 +333,39 @@ class TestHTTP:
         assert "engine_" in check_metrics.ALL_FAMILIES
         assert "cost_" in check_metrics.ALL_FAMILIES
 
-    def test_config_toml_roundtrip(self, tmp_path):
+    @pytest.mark.parametrize("old_cost_section", [False, True],
+                             ids=["rendered", "old-cost-section"])
+    def test_config_toml_roundtrip(self, tmp_path, old_cost_section):
+        """The rendered TOML loads back; so does a file written by a
+        build that still had ``[cost] shadow`` (an unknown section is
+        ignored), and the CLI's server path starts from it."""
         from pilosa_tpu.config import Config
+        from tests.test_config_cli import cli_server
 
         cfg = Config()
+        cfg.data_dir = str(tmp_path / "data")
+        cfg.bind = "127.0.0.1:0"
+        cfg.anti_entropy.interval = 0
         cfg.observe.device_peak_gbps = 1228.0
         cfg.observe.profiler_max_seconds = 5.0
-        cfg.cost.shadow = False
         text = cfg.to_toml()
         assert "device-peak-gbps = 1228.0" in text
-        assert "[cost]" in text and "shadow = false" in text
+        assert "[cost]" not in text
+        if old_cost_section:
+            text += "\n[cost]\nshadow = true\n"
         p = tmp_path / "cfg.toml"
         p.write_text(text)
         cfg2 = Config.load(str(p), env={})
         assert cfg2.observe.device_peak_gbps == 1228.0
         assert cfg2.observe.profiler_max_seconds == 5.0
-        assert cfg2.cost.shadow is False
+        assert not hasattr(cfg2, "cost")
+        if not old_cost_section:
+            return
+        with cli_server(cfg2) as srv:
+            d = _get(srv.uri, "/debug/cost")
+        assert set(d) == {"enabled", "peakGbps", "counters",
+                          "engines", "table", "profiler"}
+        assert d["peakGbps"] == 1228.0
 
 
 # ---------------------------------------------------------------------------

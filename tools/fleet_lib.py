@@ -1,14 +1,11 @@
-"""Shared multi-process fleet scaffolding for the SPMD soak and the
-plane-latency measurement (tools/soak_spmd.py, benchmarks/
-measure_spmd.py).
+"""Multi-process fleet scaffolding for the SPMD soak
+(tools/soak_spmd.py).
 
-Both entry points boot N full-server workers inside one
-jax.distributed runtime and coordinate them over the CONTROL PLANE
-(files), never over jax collectives — a pending collective parks the
-local devices, and any peer progress that needs them (serving a
-scattered sub-query) deadlocks the join.  That barrier discipline
-lives here exactly once so a fix cannot drift between the two
-harnesses.
+The soak boots N full-server workers inside one jax.distributed
+runtime and coordinates them over the CONTROL PLANE (files), never
+over jax collectives — a pending collective parks the local devices,
+and any peer progress that needs them (serving a scattered sub-query)
+deadlocks the join.
 
 Worker side: ``file_barrier``.  Parent side: ``free_ports`` and
 ``run_fleet`` (spawn, bounded wait, kill-the-whole-fleet on timeout so
@@ -41,11 +38,10 @@ def file_barrier(data_dir: str, name: str, pid: int, nproc: int,
 
 
 def norm_result(res):
-    """One plane-comparable shape for any query result object —
-    shared by the SPMD soak's cross-checks and measure_spmd so the
-    two harnesses can never drift on normalization conventions.
-    Column lists sort defensively (Row.columns() is sorted per shard;
-    sorting costs nothing and removes the ordering assumption)."""
+    """One plane-comparable shape for any query result object (the
+    SPMD soak's cross-checks).  Column lists sort defensively
+    (Row.columns() is sorted per shard; sorting costs nothing and
+    removes the ordering assumption)."""
     if isinstance(res, (int, bool)):
         return res
     if hasattr(res, "columns"):  # Row: compare the column list

@@ -348,9 +348,7 @@ while True:
     if R % 5 == 0 and pid == 0:
         for q, coll in answers:
             # counts and bare Rows cross-check against the HTTP plane
-            # (aggregate/pair shapes are oracle-checked every round);
-            # normalization is SHARED with measure_spmd (fleet_lib) so
-            # the two harnesses cannot drift
+            # (aggregate/pair shapes are oracle-checked every round)
             if not (isinstance(coll, int) or hasattr(coll, "columns")):
                 continue
             http = c.post_json(srv.uri + "/index/i/query",
