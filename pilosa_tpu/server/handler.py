@@ -15,16 +15,19 @@ control plane; the TPU data path never goes through HTTP.
 from __future__ import annotations
 
 import base64
+import email.utils
 import io
 import json
 import re
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
 import numpy as np
 
-from pilosa_tpu import observe
+from pilosa_tpu import observe, proto, tracing
+from pilosa_tpu import stats as _stats
 from pilosa_tpu.api import (
     API,
     ApiError,
@@ -165,6 +168,141 @@ def route(method: str, pattern: str, klass: str | None = None):
     return deco
 
 
+def _index_routes() -> dict[str, tuple[dict, list]]:
+    """``_ROUTES`` by method: (literal path -> its route, the method's
+    other routes in registration order)."""
+    index: dict[str, tuple[dict, list]] = {}
+    for method, rx, name, klass in _ROUTES:
+        literals, patterns = index.setdefault(method, ({}, []))
+        path = rx.pattern[1:-1]
+        # a path with no ``{name}`` segment is looked up, not matched,
+        # unless a pattern registered before it would have taken it
+        if (not rx.groups and path not in literals
+                and not any(p.match(path) for p, _, _ in patterns)):
+            literals[path] = (rx.match(path), name, klass)
+        else:
+            patterns.append((rx, name, klass))
+    return index
+
+
+def find_route(method: str, path: str):
+    """(match, handler-method name, admission class) of the first
+    registered route that takes ``method path``, or None: one dict
+    probe for a literal path, else the method's patterns in turn (the
+    query route is the first POST pattern)."""
+    literals, patterns = _ROUTE_INDEX.get(method, ({}, ()))
+    hit = literals.get(path)
+    if hit is not None:
+        return hit
+    for rx, name, klass in patterns:
+        match = rx.match(path)
+        if match is not None:
+            return match, name, klass
+    return None
+
+
+# ---------------------------------------------------------------- the wire
+
+#: a response body up to this size leaves with its head in ONE send;
+#: a larger one follows its head uncopied
+ONE_SEND_MAX = 64 << 10
+
+#: the stdlib parser's limits (http.client._MAXLINE, _MAXHEADERS)
+MAX_HEADER_LINE = 65536
+MAX_HEADERS = 100
+
+_SERVER_LINE = (f"Server: {BaseHTTPRequestHandler.server_version} "
+                f"{BaseHTTPRequestHandler.sys_version}\r\n")
+#: status -> status line + ``Server:``, as ``send_response`` wrote them
+_STATUS_HEAD = {
+    int(code): f"HTTP/1.1 {int(code)} {phrase}\r\n{_SERVER_LINE}"
+    for code, (phrase, _) in BaseHTTPRequestHandler.responses.items()}
+
+_date_line = (0, "")
+
+
+def _date_header() -> str:
+    """``Date: ...`` for this second, formatted once a second."""
+    global _date_line
+    now = int(time.time())
+    stamp, line = _date_line
+    if stamp != now:
+        line = f"Date: {email.utils.formatdate(now, usegmt=True)}\r\n"
+        _date_line = (now, line)
+    return line
+
+
+def response_head(status: int, ctype: str | None, length: int,
+                  headers: dict | None, close: bool) -> bytes:
+    """Status line through blank line."""
+    head = _STATUS_HEAD.get(status)
+    if head is None:
+        head = f"HTTP/1.1 {status} \r\n{_SERVER_LINE}"
+    head += _date_header()
+    if ctype is not None:
+        head += f"Content-Type: {ctype}\r\n"
+    head += f"Content-Length: {length}\r\n"
+    if headers:
+        for k, v in headers.items():
+            head += f"{k}: {v}\r\n"
+    head += "Connection: close\r\n\r\n" if close else "\r\n"
+    return head.encode("latin-1", "strict")
+
+
+class Headers:
+    """A request's header block: read-only, names in any case, the
+    first value of a repeated name (what ``email.message.Message.get``
+    gave), values stripped of the whitespace around them."""
+
+    __slots__ = ("_first",)
+
+    def __init__(self, first: dict[str, str]):
+        self._first = first
+
+    def get(self, name: str, default=None):
+        return self._first.get(name.lower(), default)
+
+
+class BadHead(Exception):
+    """The request head cannot be served: (status, reason phrase)."""
+
+
+def read_headers(rfile) -> Headers:
+    """The header lines up to the blank line, from the buffered
+    ``rfile``.  The stdlib parser's limits hold (431 past a 65,536-byte
+    line or 100 headers; it counted the blank line too, so took 99); a
+    line with no colon, whitespace in a name, or obsolete line folding
+    is 400 (``email.parser`` ended the block there without a word, and
+    took a folded line into the value)."""
+    first: dict[str, str] = {}
+    n = 0
+    while True:
+        raw = rfile.readline(MAX_HEADER_LINE + 1)
+        if len(raw) > MAX_HEADER_LINE:
+            raise BadHead(431, "Line too long")
+        if raw in (b"\r\n", b"\n", b""):
+            return Headers(first)
+        n += 1
+        if n > MAX_HEADERS:
+            raise BadHead(431, "Too many headers")
+        name, colon, value = raw.decode("iso-8859-1").partition(":")
+        if not colon or not name or " " in name or "\t" in name:
+            raise BadHead(400, "Bad header line")
+        first.setdefault(name.lower(), value.strip())
+
+
+def _version_number(version: str) -> tuple[int, int] | None:
+    """``HTTP/<major>.<minor>`` as integers; None when malformed (the
+    stdlib's rules: one dot, digits only, ten at most each)."""
+    if not version.startswith("HTTP/"):
+        return None
+    parts = version[5:].split(".")
+    if len(parts) != 2 or not all(
+            p.isdigit() and len(p) <= 10 for p in parts):
+        return None
+    return int(parts[0]), int(parts[1])
+
+
 class Handler:
     """Routes HTTP requests to an API instance and serves forever on a
     background thread (http/handler.go:46)."""
@@ -237,10 +375,64 @@ class Handler:
                 pass
 
             def parse_request(self):
+                """The request head, parsed here and not by
+                ``email.parser``: the stdlib's answers to a head it
+                cannot serve (400, 431, 505; ``send_error`` writes
+                them), its keep-alive rules and ``Expect:
+                100-continue``, and ``self.headers`` a ``Headers``."""
                 # the request line has just been read: the request's
                 # root span starts here (observe.Request)
                 self.arrived_ns = observe.clock_ns()
-                return super().parse_request()
+                self.command = None  # in case of an error on this line
+                self.request_version = "HTTP/0.9"
+                self.close_connection = True
+                line = str(self.raw_requestline, "iso-8859-1")
+                self.requestline = line = line.rstrip("\r\n")
+                words = line.split()
+                if not words:
+                    return False
+                if len(words) >= 3:
+                    version = words[-1]
+                    if version == "HTTP/1.1":
+                        self.close_connection = False
+                    else:
+                        number = _version_number(version)
+                        if number is None:
+                            self.send_error(
+                                400, f"Bad request version ({version!r})")
+                            return False
+                        if number >= (2, 0):
+                            self.send_error(
+                                505, f"Invalid HTTP version ({version[5:]})")
+                            return False
+                        self.close_connection = number < (1, 1)
+                    self.request_version = version
+                if not 2 <= len(words) <= 3:
+                    self.send_error(400, f"Bad request syntax ({line!r})")
+                    return False
+                command, path = words[:2]
+                if len(words) == 2 and command != "GET":
+                    self.send_error(
+                        400, f"Bad HTTP/0.9 request type ({command!r})")
+                    return False
+                if path.startswith("//"):
+                    # no scheme-less absolute URI (gh-87389)
+                    path = "/" + path.lstrip("/")
+                self.command, self.path = command, path
+                try:
+                    headers = self.headers = read_headers(self.rfile)
+                except BadHead as e:
+                    self.send_error(*e.args)
+                    return False
+                connection = headers.get("Connection", "").lower()
+                if connection == "close":
+                    self.close_connection = True
+                elif connection == "keep-alive" and len(words) == 3:
+                    self.close_connection = False
+                if (headers.get("Expect", "").lower() == "100-continue"
+                        and self.request_version >= "HTTP/1.1"):
+                    self.wfile.write(b"HTTP/1.1 100 Continue\r\n\r\n")
+                return True
 
             def _dispatch(self, method: str):
                 handler_self._handle(self, method)
@@ -315,6 +507,12 @@ class Handler:
         # set by close(): surviving keep-alive worker threads refuse
         # (503) instead of serving from a closed holder
         self._draining = False
+        # answers written by ``_respond`` and the socket sends they
+        # took (``http.responses`` / ``http.sends``): equal while every
+        # body was at most ONE_SEND_MAX bytes
+        self._wire_lock = threading.Lock()
+        self.responses = 0
+        self.sends = 0
 
     @property
     def uri(self) -> str:
@@ -412,45 +610,48 @@ class Handler:
             # thread outlives httpd.shutdown(): refuse instead of
             # answering from a closed holder (an empty fragment set
             # would serve WRONG results, not an error)
+            req.close_connection = True
             try:
-                req.send_response(503)
-                req.send_header("Retry-After", "1")
-                req.send_header("Content-Length", "0")
-                req.send_header("Connection", "close")
-                req.end_headers()
+                self._respond(req, 503, None, b"",
+                              headers={"Retry-After": "1"})
             except OSError:
                 pass
             return
-        parsed = urlparse(req.path)
-        path = parsed.path.rstrip("/") or "/"
-        params = {k: v[0] for k, v in parse_qs(parsed.query).items()}
-        for m, rx, name, klass in _ROUTES:
-            if m != method:
-                continue
-            match = rx.match(path)
-            if match is None:
-                continue
-            # the request's root span opens HERE, before admission: what
-            # the handler does around Executor.execute lands on the
-            # flight record that adopts this Request (observe.Request)
-            recorder = getattr(self.api.executor, "recorder", None)
-            if recorder is not None and recorder.enabled:
-                with observe.Request(
-                        getattr(req, "arrived_ns", 0)) as rq:
-                    self._serve(req, rq, match, name, klass, path, params)
-            else:
-                self._serve(req, None, match, name, klass, path, params)
+        target = req.path
+        if "?" in target or "#" in target or ";" in target \
+                or not target.startswith("/"):
+            parsed = urlparse(target)
+            path = parsed.path
+            params = ({k: v[0] for k, v in parse_qs(parsed.query).items()}
+                      if parsed.query else {})
+        else:
+            path, params = target, {}
+        path = path.rstrip("/") or "/"
+        hit = find_route(method, path)
+        if hit is None:
+            self._error(req, 404, "not found")
             return
-        self._error(req, 404, "not found")
+        match, name, klass = hit
+        # the request's root span opens HERE, before admission: what
+        # the handler does around Executor.execute lands on the
+        # flight record that adopts this Request (observe.Request)
+        recorder = getattr(self.api.executor, "recorder", None)
+        if recorder is not None and recorder.enabled:
+            with observe.Request(getattr(req, "arrived_ns", 0)) as rq:
+                self._serve(req, rq, match, name, klass, path, params)
+        else:
+            self._serve(req, None, match, name, klass, path, params)
 
     def _serve(self, req, rq, match, name: str, klass, path: str,
                params: dict) -> None:
         """One matched request: deadline, admission, body, the route's
         handler, and the error mapping.  ``rq`` is the request's
         ``observe.Request`` (None with the flight recorder off)."""
-        if self.stats is not None:
-            self.stats.count_with_tags("http.request", 1, 1.0,
-                                       [f"useragent:{req.headers.get('User-Agent', '')}"])
+        stats = self.stats
+        if stats is not None and stats is not _stats.NOP:
+            stats.count_with_tags(
+                "http.request", 1, 1.0,
+                [f"useragent:{req.headers.get('User-Agent', '')}"])
         # deadline + admission run BEFORE the body is read: a shed
         # request must not pay a 256MB body upload first (the
         # unread body forces the connection closed, like 413)
@@ -529,7 +730,16 @@ class Handler:
                 return
         try:
             body = b""
-            length = int(req.headers.get("Content-Length") or 0)
+            try:
+                length = int(req.headers.get("Content-Length") or 0)
+                if length < 0:
+                    raise ValueError
+            except ValueError:
+                # how long the body is cannot be known: the connection
+                # closes, as for a body left unread
+                req.close_connection = True
+                self._error(req, 400, "invalid Content-Length header")
+                return
             if length > MAX_REQUEST_BYTES:
                 # the body stays unread; the keep-alive connection
                 # must close or its bytes would parse as the next
@@ -546,8 +756,6 @@ class Handler:
             # reference's tracing middleware, http/handler.go:321);
             # entering the span makes it the parent of every span
             # the handler starts (api.*, executor.*)
-            from pilosa_tpu import tracing
-
             parent = tracing.extract_headers(req.headers)
             if rq is not None and ticket is not None:
                 # the admission stamp (class + queue wait) rides the
@@ -617,8 +825,6 @@ class Handler:
         /debug/trace/{id} away."""
         recorder = getattr(self.api.executor, "recorder", None)
         if recorder is not None:
-            from pilosa_tpu import tracing
-
             parent = (tracing.extract_headers(headers)
                       if headers is not None else None)
             recorder.record_shed(
@@ -627,24 +833,37 @@ class Handler:
                 trace_id=parent.trace_id if parent is not None
                 else None)
 
+    def _respond(self, req, status: int, ctype: str | None, body: bytes,
+                 headers: dict | None = None) -> None:
+        """Every answer a route writes: status line, ``Server``,
+        ``Date``, ``Content-Type``, ``Content-Length``, the caller's
+        ``headers``, ``Connection: close`` when the connection will
+        close, and the body, in ONE send up to ``ONE_SEND_MAX`` bytes
+        of body; a larger body follows its head uncopied."""
+        sends = 1 if len(body) <= ONE_SEND_MAX else 2
+        # counted before the send: a client that has its answer finds
+        # it in the counters
+        with self._wire_lock:
+            self.responses += 1
+            self.sends += sends
+        # an HTTP/0.9 answer is its body alone, as the stdlib sent it
+        head = (b"" if req.request_version == "HTTP/0.9"
+                else response_head(status, ctype, len(body), headers,
+                                   req.close_connection))
+        if sends == 1:
+            req.wfile.write(head + body)
+        else:
+            req.wfile.write(head)
+            req.wfile.write(body)
+
     def _json(self, req, obj, status: int = 200,
               headers: dict | None = None) -> None:
-        data = json.dumps(obj).encode()
-        req.send_response(status)
-        req.send_header("Content-Type", "application/json")
-        req.send_header("Content-Length", str(len(data)))
-        for k, v in (headers or {}).items():
-            req.send_header(k, v)
-        req.end_headers()
-        req.wfile.write(data)
+        self._respond(req, status, "application/json",
+                      json.dumps(obj).encode(), headers)
 
     def _bytes(self, req, data: bytes, ctype: str = "application/octet-stream",
                status: int = 200) -> None:
-        req.send_response(status)
-        req.send_header("Content-Type", ctype)
-        req.send_header("Content-Length", str(len(data)))
-        req.end_headers()
-        req.wfile.write(data)
+        self._respond(req, status, ctype, data)
 
     def _error(self, req, status: int, msg: str,
                headers: dict | None = None) -> None:
@@ -712,8 +931,6 @@ class Handler:
         wire form TranslateKeysRequest/Response).  Accepts protobuf or
         JSON {"index", "field", "keys"}; ids are allocated via the
         single-writer path."""
-        from pilosa_tpu import proto
-
         if "protobuf" in req.headers.get("Content-Type", ""):
             d = proto.decode(proto.TRANSLATE_KEYS_REQUEST, body)
         else:
@@ -748,8 +965,6 @@ class Handler:
         answered in JSON, ``application/x-protobuf`` QueryRequest bodies
         answered in protobuf when Accept asks for it (reference
         handlePostQuery, http/handler.go:499,1002)."""
-        from pilosa_tpu import proto
-
         ctype = req.headers.get("Content-Type", "")
         proto_accept = "protobuf" in req.headers.get("Accept", "")
         shards = None
@@ -892,8 +1107,6 @@ class Handler:
         ImportResponse for protobuf clients (reference handlePostImport,
         http/handler.go:1161), JSON {} otherwise."""
         if "protobuf" in req.headers.get("Accept", ""):
-            from pilosa_tpu import proto
-
             self._proto(req, proto.encode(proto.IMPORT_RESPONSE, {}))
         else:
             self._json(req, {})
@@ -969,8 +1182,6 @@ class Handler:
         seconds or RFC3339 in JSON, unix NANOseconds in protobuf (the
         reference encodes time.Time.UnixNano)."""
         if "protobuf" in req.headers.get("Content-Type", ""):
-            from pilosa_tpu import proto
-
             # arrays=True: large packed ID fields stay ndarrays all the
             # way into field.import_bits' vectorized grouping (length
             # checks below must use len(), never truthiness)
@@ -1006,8 +1217,6 @@ class Handler:
        klass="ingest")
     def handle_import_value(self, req, params, path, body):
         if "protobuf" in req.headers.get("Content-Type", ""):
-            from pilosa_tpu import proto
-
             d = proto.decode(proto.IMPORT_VALUE_REQUEST, body)
             if not d.get("columnKeys"):
                 d["columnKeys"] = None
@@ -1029,8 +1238,6 @@ class Handler:
         ctype = req.headers.get("Content-Type", "")
         clear = params.get("clear") == "true"
         if "protobuf" in ctype:
-            from pilosa_tpu import proto
-
             d = proto.decode(proto.IMPORT_ROARING_REQUEST, body)
             views = {v["name"]: v["data"] for v in d["views"]}
             clear = clear or d["clear"]
@@ -1874,6 +2081,12 @@ class Handler:
         from pilosa_tpu.runtime import resultcache
 
         try:
+            with self._wire_lock:
+                responses, sends = self.responses, self.sends
+            # answers written and the sends they took: equal while
+            # every body fitted ONE_SEND_MAX
+            self.stats.gauge("http.responses", responses)
+            self.stats.gauge("http.sends", sends)
             devobs.observer().publish_gauges(self.stats)
             resultcache.cache().publish_gauges(self.stats)
             compactor.compactor().publish_gauges(self.stats)
@@ -1912,6 +2125,10 @@ class Handler:
             _observe_mod.publish_journal_gauges(self.stats)
         except Exception:  # noqa: BLE001
             pass
+
+
+#: built once every ``@route`` of ``Handler`` has registered
+_ROUTE_INDEX = _index_routes()
 
 
 def _parse_ts(t):

@@ -17,7 +17,9 @@ section 6 (the span spine, ``?profile=1``).
 
 from __future__ import annotations
 
+import email.parser
 import json
+import socket
 import time
 import urllib.request
 
@@ -301,7 +303,8 @@ def test_observer_off_is_no_call_no_lock_no_clock(ex, monkeypatch,
 #: request (the handler's ``http.request``, ``http.parse``,
 #: ``http.read``, ``admission.wait``, ``pql.parse`` and ``serialize``
 #: on top).  Ceilings, not targets: a PR that adds a span to the
-#: served read raises them here, in the open.
+#: served read raises them here, in the open.  PR 32's own request
+#: parse and single send read the same clocks: unchanged.
 LONE_DENSE = {"executor": (24, 13), "http": (35, 19)}
 
 
@@ -375,3 +378,95 @@ def test_recorder_on_lone_dense_count_clock_reads_and_spans(
     assert len(names) <= spans, names
     assert clock.n <= reads, clock.n
     assert locks == (2, 1, 0)
+
+
+class _Sends:
+    """Every ``send``/``sendall`` on a socket whose local port is the
+    server's, i.e. on its side of a connection: the bytes objects, in
+    order."""
+
+    def __init__(self, monkeypatch, port: int):
+        self.sent: list[bytes] = []
+        for name in ("send", "sendall"):
+            monkeypatch.setattr(socket.socket, name,
+                                self._counting(name, port))
+
+    def _counting(self, name: str, port: int):
+        real = getattr(socket.socket, name)
+
+        def method(sock, data, *args):
+            if sock.getsockname()[1] == port:
+                self.sent.append(data)
+            return real(sock, data, *args)
+
+        return method
+
+
+def _raw_post(sock, rfile, body: bytes) -> bytes:
+    """One POST of ``body`` to the query route on an open connection,
+    by hand: ``http.client`` would run ``email.parser`` over the answer
+    in this same process.  -> the answer's body."""
+    sock.sendall(b"POST /index/i/query?nomesh=1 HTTP/1.1\r\nHost: t\r\n"
+                 b"Content-Length: %d\r\n\r\n%s" % (len(body), body))
+    assert rfile.readline() == b"HTTP/1.1 200 OK\r\n"
+    length = 0
+    for line in iter(rfile.readline, b"\r\n"):
+        if line.lower().startswith(b"content-length:"):
+            length = int(line.split(b":")[1])
+    return rfile.read(length)
+
+
+def _wire_counts(srv) -> tuple[int, int]:
+    with srv.handler._wire_lock:
+        return srv.handler.responses, srv.handler.sends
+
+
+#: what the served connection's protocol work costs, as counts: the
+#: socket sends of one answer, and calls into ``email.parser``
+WIRE = ("lone dense Count", "answer over 64 KiB")
+
+
+@pytest.mark.parametrize("answer", WIRE)
+def test_served_answer_is_one_parse_and_one_send(served, monkeypatch,
+                                                 answer):
+    """A served lone dense Count leaves in exactly ONE send on its
+    connection, ``http.sends`` == ``http.responses``, and
+    ``email.parser`` never runs while it is served; an answer over 64
+    KiB is two sends, the second of them the body object itself."""
+    from pilosa_tpu.server import handler as h
+
+    sock = socket.create_connection(("127.0.0.1", served.handler.port),
+                                    timeout=60)
+    rfile = sock.makefile("rb")
+    try:
+        assert json.loads(_raw_post(sock, rfile, QUERY.encode()))[
+            "results"][0] > 0  # staged and compiled
+        sends = _Sends(monkeypatch, served.handler.port)
+        parsestr = _calls(monkeypatch, email.parser.Parser, "parsestr")
+        bodies = []
+        respond = h.Handler._respond
+
+        def spy(self, req, status, ctype, body, headers=None):
+            bodies.append(body)
+            return respond(self, req, status, ctype, body, headers)
+
+        monkeypatch.setattr(h.Handler, "_respond", spy)
+        before = _wire_counts(served)
+        if answer == "lone dense Count":
+            got = _raw_post(sock, rfile, QUERY.encode())
+            assert len(sends.sent) == 1
+            assert sends.sent[0].endswith(got) and got == bodies[0]
+            took = 1
+        else:
+            got = _raw_post(sock, rfile, b"Row(f=1)")
+            assert len(got) > h.ONE_SEND_MAX
+            head, body = sends.sent
+            assert head.endswith(b"\r\n\r\n") and len(head) < 512
+            assert body is bodies[0] and body == got  # sent uncopied
+            took = 2
+        assert parsestr.n == 0
+        responses, sent = _wire_counts(served)
+        assert (responses - before[0], sent - before[1]) == (1, took)
+    finally:
+        rfile.close()
+        sock.close()

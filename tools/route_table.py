@@ -2,12 +2,16 @@
 """A traced benchmark run that also prints PERF.md section 5's route
 table: the window's reads by route (result cache; dense or tape; batch
 leader, follower or alone), each with the medians of its host phases
-from the flight records' spans and of the client's service time, the
-share of coalescer flushes by what ended the leader's wait (`why`:
-idle, busy, full, cap; absent on a program that predates it), and the
-share of staged leaves whose cached stacks were validated against the
+from the flight records' spans (`http.parse` first: from the request
+line read to the root span's opening, which is the header block, the
+target and the route) and of the client's service time, the share of
+coalescer flushes by what ended the leader's wait (`why`: idle, busy,
+full, cap; absent on a program that predates it), and the share of
+staged leaves whose cached stacks were validated against the
 view's write token alone (`fast` of `leaves` on the `stage` spans;
-absent likewise).
+absent likewise), and the answers the server wrote between the two ends
+of the window beside the socket sends they took (`http.responses`,
+`http.sends` of `/debug/vars`; absent likewise).
 
     python3 tools/route_table.py --workload seg-dense --seed <n> \\
         --seconds 51 --trace 1
@@ -29,7 +33,7 @@ if ROOT not in sys.path:
 from perfbench import run as harness  # noqa: E402
 from perfbench import spans as sp  # noqa: E402
 
-PHASES = ("stage", "coalesce.wait", "launch", "launch.stack",
+PHASES = ("http.parse", "stage", "coalesce.wait", "launch", "launch.stack",
           "launch.dispatch", "launch.ready", "reduce")
 
 
@@ -57,8 +61,8 @@ def say_table(records) -> None:
                 fast += s["fast"]
                 leaves += s["leaves"]
         rows.setdefault(route_of(r.profile), []).append(
-            [sp.self_total(spans, "stage")]
-            + [sp.total(spans, name) for name in PHASES[1:]]
+            [sp.total(spans, "http.parse"), sp.self_total(spans, "stage")]
+            + [sp.total(spans, name) for name in PHASES[2:]]
             + [sp.ms(sp.root(spans)), (r.done - r.sent) * 1e3])
         co = r.profile.get("coalescer")
         if co and co["leader"] and not r.profile.get("cached"):
@@ -84,8 +88,24 @@ def say_routes(records) -> None:
     say_table(records)
 
 
-_say_routes = harness.say_routes
-harness.say_routes = say_routes
+def measure(ses, *args):
+    """The window between two reads of the server's wire counters: the
+    window's reads, the harness's own calls around them (this read,
+    `/debug/devices` twice, the profiler's start and stop) and nothing
+    else.  Each answer over 64 KiB is one send more than responses."""
+    before = ses.srv.call("GET", "/debug/vars")
+    out = _measure(ses, *args)
+    after = ses.srv.call("GET", "/debug/vars")
+    if "http.responses" in after:
+        responses, sends = (after[k] - before[k]
+                            for k in ("http.responses", "http.sends"))
+        harness.say(f"wire: http.responses +{responses} http.sends "
+                    f"+{sends} ({sends / responses:.4f} a response)")
+    return out
+
+
+_say_routes, _measure = harness.say_routes, harness.measure
+harness.say_routes, harness.measure = say_routes, measure
 
 if __name__ == "__main__":
     sys.exit(harness.main())
