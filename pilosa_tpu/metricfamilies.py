@@ -182,6 +182,14 @@ FAMILIES: tuple[Family, ...] = (
            "captures (pilosa_tpu.perfobs)",
            live_prefixes=("cost_",), group="engine",
            doc="administration.md"),
+    Family("launch", "launch_",
+           "device round trips of a sampled launch: launch_fetched "
+           "counts launches whose one wait was the fetch of their "
+           "counts, launch_refetched those of the dense engine whose "
+           "counts were asked of the device again after the wait "
+           "(pilosa_tpu.perfobs, ops/expr.py)",
+           live_prefixes=("launch_",), group="engine",
+           doc="administration.md"),
     Family("tenant", "tenant_",
            "per-tenant isolation totals: admission admitted/shed/"
            "waiting, result-cache bytes, residency HBM/host bytes "

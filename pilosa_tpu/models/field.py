@@ -18,12 +18,15 @@ import threading
 import time as _time
 from dataclasses import dataclass
 
+import jax
 import numpy as np
 
 from pilosa_tpu import observe as _observe
 from pilosa_tpu import stagecheck as _stagecheck
 from pilosa_tpu.models.timequantum import TimeQuantum, views_by_time, views_by_time_range
 from pilosa_tpu.models.view import VIEW_BSI_PREFIX, VIEW_STANDARD, View
+from pilosa_tpu.ops import bitmap as bm
+from pilosa_tpu.ops import containers as ct
 from pilosa_tpu.runtime import residency
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
@@ -77,16 +80,12 @@ def _padded_rows(n: int) -> int:
     which is every local device by default and 1 — no padding — when
     the mesh is disabled); multi-process placement pads to the
     node-local device count for parallel/spmd.py's per-node stacks."""
-    import jax
-
     if jax.process_count() > 1:
         n_dev = len(jax.local_devices())
         if n_dev <= 1:
             return n
         return ((n + n_dev - 1) // n_dev) * n_dev
-    from pilosa_tpu.parallel import meshexec
-
-    a = meshexec.pad_axis()
+    a = _meshexec().pad_axis()
     if a <= 1:
         return n
     return ((n + a - 1) // a) * a
@@ -114,24 +113,36 @@ def _leaf_pair_live(pair) -> bool:
     return _live(pair[0].pool) and _live(pair[1].pool)
 
 
+_mx = None
+
+
+def _meshexec():
+    """``parallel/meshexec.py``, bound on first use.  It cannot be
+    imported at the top: importing the ``pilosa_tpu.parallel`` package
+    runs ``executor``, which imports this module.  An import statement
+    a call is what every staged leaf paid three times over."""
+    global _mx
+    if _mx is None:
+        from pilosa_tpu.parallel import meshexec
+
+        _mx = meshexec
+    return _mx
+
+
 def _placement_token():
     """The [mesh] placement flavor in force (parallel/meshexec.py),
     joined into every device-stack cache's invalidation tuple: a mesh
     toggle or axis resize must MISS and re-place — a stack laid out
     for the previous shard plan would otherwise keep serving under
     fresh config."""
-    from pilosa_tpu.parallel import meshexec
-
-    return meshexec.placement_token()
+    return _meshexec().placement_token()
 
 
 def _placement_devices() -> int:
     """How many devices the active placement spreads a stack over —
     the residency manager's per-device accounting (devobs/residency
     follow the shard plan)."""
-    from pilosa_tpu.parallel import meshexec
-
-    return meshexec.axis_size()
+    return _meshexec().axis_size()
 
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_-]{0,63}$")
@@ -257,6 +268,10 @@ class Field:
         # ("delta" | "dcont", row, shards) -> stamp: "no fragment has
         # an overlay for this row", as of that view write token
         self._no_delta: dict = {}
+        # ("cont", row, shards) -> stamp: "this row is kept dense in
+        # some shard of the set" (its container leaf has dense slots),
+        # as of that view write token and those [containers] settings
+        self._kept_dense: dict = {}
         # shards-tuple -> (gens, row_ids, shard_pos, pos_dev, mat_dev):
         # concatenated cross-shard row matrices for the fused TopN scan
         self._matrix_stack_cache: dict = {}
@@ -446,8 +461,6 @@ class Field:
         the per-fragment mutation generations, which are compared only
         when the view's write token has moved since the entry was last
         proved good (_stamped_hit, then _walked_hit)."""
-        from pilosa_tpu.ops import bitmap as bm
-
         view = self.view(VIEW_STANDARD)
         key = (row_id, shards)
         stamp = (_stagecheck.view_token(view), _placement_token())
@@ -599,10 +612,6 @@ class Field:
         dispatches host arrays to numpy + the native popcount kernels
         (ops/hostkernels.py), which beat XLA:CPU codegen ~8x at query
         shapes."""
-        import jax
-
-        from pilosa_tpu.ops import bitmap as bm
-
         if bm.host_mode():
             return np.ascontiguousarray(stack)
         if jax.process_count() > 1:
@@ -623,9 +632,7 @@ class Field:
                 return pmesh.shard_stack(pmesh.local_device_mesh(), stack)
             return bm.device_put(stack, local[0],
                                          label="field.stack")
-        from pilosa_tpu.parallel import meshexec
-
-        return meshexec.place_stack(stack, label="field.stack")
+        return _meshexec().place_stack(stack, label="field.stack")
 
     def device_time_row_stack(self, row_id: int, shards: tuple[int, ...],
                               view_names: tuple[str, ...]):
@@ -637,8 +644,6 @@ class Field:
         per view.  Cached per (row, shards, views); every contributing
         fragment's generation invalidates, compared only when one of
         the covering views' write tokens has moved (_stamped_hit)."""
-        from pilosa_tpu.ops import bitmap as bm
-
         key = ("time", row_id, shards, view_names)
         views = [self.view(vn) for vn in view_names]
         stamp = (tuple(_stagecheck.view_token(v) for v in views),
@@ -750,8 +755,6 @@ class Field:
         is idempotent: the executor stages these BEFORE the base stack,
         and re-applying an already-merged overlay reproduces the same
         effective words ((b&~c|s)&~c|s == b&~c|s)."""
-        from pilosa_tpu.ops import bitmap as bm
-
         view = self.view(VIEW_STANDARD)
         key = ("delta", row_id, shards)
         stamp = (_stagecheck.view_token(view), _placement_token())
@@ -851,8 +854,6 @@ class Field:
         stages these BEFORE the base leaf, and re-applying an
         already-merged overlay is idempotent ((b&~c|s)&~c|s ==
         b&~c|s)."""
-        from pilosa_tpu.ops import containers as ct
-
         view = self.view(VIEW_STANDARD)
         key = ("dcont", row_id, shards)
         stamp = (_stagecheck.view_token(view), _placement_token())
@@ -869,8 +870,6 @@ class Field:
                                 tier=False)
         if pair is not None:
             return pair
-        from pilosa_tpu.ops import bitmap as bm
-
         cpr = SHARD_WIDTH // ct.CONTAINER_BITS
         planes: list[list] = [[], []]  # per kind: (set, clear) words
         for fr in frags:
@@ -937,26 +936,54 @@ class Field:
         shards x shard-width — the capacity multiplier of the roaring
         layout.  The per-fragment tokens are compared only when the
         view's write token, the placement or a setting below has
-        moved since the leaf was last proved good (_stamped_hit)."""
-        from pilosa_tpu.ops import containers as ct
-        from pilosa_tpu.parallel import meshexec
-
+        moved since the leaf was last proved good (_stamped_hit).
+        A leaf with dense slots leaves its verdict behind under the
+        same stamp (row_kept_dense), so the next read of the row can
+        decline the compressed engines without staging anything."""
         view = self.view(VIEW_STANDARD)
-
-        # the fill-ratio threshold joins the token: a cached leaf
-        # froze each fragment's sparse-vs-hot verdict, so a runtime
-        # [containers] threshold change must miss and re-evaluate —
-        # not wait for the next base mutation.  The effective
-        # kind-selection knobs join it too (they decide the pool
-        # layout), and kinds switch off entirely while a mesh is
-        # active: the kind-dispatched programs are single-device, so
-        # mesh-routed queries keep the exact legacy all-bitmap leaves
-        cfg = ct.config()
-        eff_kinds = bool(cfg.kinds) and not meshexec.active()
-        settings = (cfg.threshold, eff_kinds, cfg.array_max, cfg.run_cap,
-                    _placement_token())
-        stamp = (_stagecheck.view_token(view),) + settings
         key = ("cont", row_id, shards)
+        settings = self._container_settings()
+        stamp = (_stagecheck.view_token(view),) + settings
+        leaf = self._container_leaf(view, key, settings, stamp)
+        if leaf.dense_slots():
+            if len(self._kept_dense) >= self._NO_DELTA_CAP:
+                self._kept_dense.clear()
+            self._kept_dense[key] = stamp
+        return leaf
+
+    @staticmethod
+    def _container_settings() -> tuple:
+        """What a container leaf froze besides fragment state, joined
+        into its tokens.  The fill-ratio threshold: a cached leaf froze
+        each fragment's sparse-vs-hot verdict, so a runtime
+        [containers] threshold change must miss and re-evaluate — not
+        wait for the next base mutation.  The effective kind-selection
+        knobs (they decide the pool layout), and kinds switch off
+        entirely while a mesh is active: the kind-dispatched programs
+        are single-device, so mesh-routed queries keep the exact
+        legacy all-bitmap leaves.  settings[1] is that effective
+        ``kinds``."""
+        cfg = ct.config()
+        eff_kinds = bool(cfg.kinds) and not _meshexec().active()
+        return (cfg.threshold, eff_kinds, cfg.array_max, cfg.run_cap,
+                _placement_token())
+
+    def row_kept_dense(self, row_id: int, shards: tuple[int, ...]) -> bool:
+        """Whether this standard-view row is KNOWN to be kept dense in
+        some shard of the set: ``device_container_leaf`` found dense
+        slots, and neither the view's write token nor a setting the
+        verdict froze has moved since (the stamp is read before the
+        lookup, as ever).  Then the compressed engines' all-or-nothing
+        decline is certain and needs no leaf staged
+        (containers.kept_dense).  False = not known: never asked, or
+        something moved; the caller stages and finds out."""
+        stamp = ((_stagecheck.view_token(self.view(VIEW_STANDARD)),)
+                 + self._container_settings())
+        return self._kept_dense.get(("cont", row_id, shards)) == stamp
+
+    def _container_leaf(self, view, key, settings, stamp):
+        row_id, shards = key[1], key[2]
+        eff_kinds = settings[1]
         self._note_access(self._row_stack_cache, key)
         leaf = self._stamped_hit(key, stamp, _leaf_live)
         if leaf is not None:
@@ -1004,8 +1031,6 @@ class Field:
                 blocks_list.append(blocks)
                 kinds_list.append(ks)
                 n_dir += len(keys)
-        from pilosa_tpu.ops import bitmap as bm
-
         flat_kinds = (np.concatenate(kinds_list) if kinds_list
                       else np.empty(0, dtype=np.uint8))
         if eff_kinds and bool((flat_kinds != 1).any()):
@@ -1092,8 +1117,6 @@ class Field:
         tail row (empty bitmap block / card-0 array / all-invalid run
         pairs) — the absent-container gather targets — and device row
         counts pad to pow2 per pool (host pools stay tight)."""
-        from pilosa_tpu.ops import bitmap as bm
-        from pilosa_tpu.ops import containers as ct
         from pilosa_tpu.ops import kindpools as kp
 
         flat_blocks = (np.concatenate(blocks_list, axis=0)
@@ -1146,20 +1169,14 @@ class Field:
         active mesh the pool REPLICATES onto every mesh device and the
         gather DOMAIN axis shards instead (ops/expr
         _build_mesh_gather_program)."""
-        import jax
-
-        from pilosa_tpu.ops import bitmap as bm
-
         if bm.host_mode():
             return np.ascontiguousarray(pool)
         if jax.process_count() > 1:
             return bm.device_put(pool, jax.local_devices()[0],
                                          label="field.containers")
-        from pilosa_tpu.parallel import meshexec
-
-        if meshexec.active():
-            return meshexec.place_replicated(pool,
-                                             label="field.containers")
+        if _meshexec().active():
+            return _meshexec().place_replicated(
+                pool, label="field.containers")
         return bm.device_put(pool, label="field.containers")
 
     def flush_deltas(self, shards=None) -> int:
@@ -1381,7 +1398,6 @@ class Field:
         and generation-invalidated like device_row_stack (the BSI
         view's write token first, the per-fragment tokens when it has
         moved); shard axis is padded and mesh-sharded the same way."""
-        from pilosa_tpu.ops import bitmap as bm
         from pilosa_tpu.ops import bsi as bsi_ops
 
         self._require_int()
@@ -1632,7 +1648,6 @@ class Field:
         per-shard path via _classify_range.  op '><' takes [lo, hi];
         op '!=' with value None means not-null.  Returns uint32
         [n_shards, words]."""
-        import jax
         import jax.numpy as jnp
 
         from pilosa_tpu.ops import bsi as bsi_ops

@@ -107,11 +107,24 @@ def host_mode() -> bool:
     Placement (Field._place_on_devices, Fragment.device_*) consults this
     once per stack build; every op below then dispatches on operand
     type, so host stacks flow through numpy + native C++ and device
-    stacks through the jit kernels."""
-    import jax
+    stacks through the jit kernels.  Asked of JAX once a backend: its
+    devices cannot change while it lives (``forget_backend``)."""
+    global _host_mode
+    mode = _host_mode
+    if mode is None:
+        devs = jax.devices()
+        mode = _host_mode = len(devs) == 1 and devs[0].platform == "cpu"
+    return mode
 
-    devs = jax.devices()
-    return len(devs) == 1 and devs[0].platform == "cpu"
+
+#: ``host_mode``'s answer for the live backend; None = not asked yet
+_host_mode: bool | None = None
+
+
+def forget_backend() -> None:
+    """The backend is being replaced (``meshexec.backend_reset``)."""
+    global _host_mode
+    _host_mode = None
 
 
 def _host(*xs) -> bool:

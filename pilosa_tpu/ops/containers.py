@@ -57,6 +57,10 @@ import numpy as np
 from pilosa_tpu import observe as _observe
 from pilosa_tpu import perfobs as _perfobs
 from pilosa_tpu import stagecheck as _stagecheck
+from pilosa_tpu.ops import bitmap as bm
+from pilosa_tpu.ops import expr
+from pilosa_tpu.ops import tape as _tp
+from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 #: Container geometry: 2^16 bits = 1024 uint64 = 2048 uint32 words —
 #: the reference's container size and storage/roaring.py's block shape.
@@ -527,8 +531,6 @@ class Plan:
             bump("container.empty_domains")
             # the dense path would still have launched once; tick the
             # dispatch hook so launch accounting is route-invariant
-            from pilosa_tpu.ops import bitmap as bm
-
             bm.note_dispatch("fused_gather")
             return None
         with _observe.span("launch") as sp:
@@ -539,7 +541,6 @@ class Plan:
     def _launch(self, counts: bool, idxs: list, total: int,
                 mesh: Any) -> Any:
         """The one launch of ``_gathered``, by the cheapest arm."""
-        from pilosa_tpu.ops import expr
         from pilosa_tpu.ops import pallas_kernels as pk
 
         pools = [leaf.pool for leaf in self.leaves]
@@ -589,8 +590,6 @@ class Plan:
         compact rows, decode to dense blocks, fold the tree — still
         ONE launch).  Bit-exact with the dense route by construction:
         every arm computes the same container algebra."""
-        from pilosa_tpu.ops import bitmap as bm
-        from pilosa_tpu.ops import expr
         from pilosa_tpu.ops import pallas_kernels as pk
 
         if counts and self.shape == ("and", ("leaf", 0), ("leaf", 1)):
@@ -731,8 +730,6 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
     back dense — the delta leaves stage BEFORE the base leaf, which
     makes a concurrent compaction safe (idempotent re-apply, the
     device_delta_stacks discipline)."""
-    from pilosa_tpu.ops import tape as _tp
-
     if not _cfg.enabled or not shards:
         _tp.bump("vm.fallbacks.disabled")
         return None
@@ -831,8 +828,6 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
         else:
             _tp.bump("vm.fallbacks.max_prefetch")
         return None
-    from pilosa_tpu.shardwidth import SHARD_WIDTH
-
     cpr = SHARD_WIDTH // CONTAINER_BITS
     n_leaves = len(leaves)
     bump("container.containers_gathered", total * n_leaves)
@@ -1045,6 +1040,59 @@ def _megapool_kinds(order: list) -> tuple:
     return (MegaPools(bpool, apool, acard, rpool), bases, zero_index)
 
 
+def _row_leaf(idx: Any, call: Any) -> tuple | None:
+    """``(field, row id)`` of a ``Row`` call that names one plain
+    standard-view row, else None: BSI conditions, time ranges, keys
+    and bool literals, unknown or int fields."""
+    if call.condition_arg() is not None:
+        return None
+    if "from" in call.args or "to" in call.args:
+        return None
+    try:
+        fname = call.field_arg()
+    except ValueError:
+        return None
+    row_id = call.args.get(fname)
+    if not isinstance(row_id, int) or isinstance(row_id, bool):
+        return None
+    f = idx.field(fname)
+    if f is None:
+        return None
+    o = f.options
+    if o.type == "int" or (o.type == "time" and o.no_standard_view):
+        return None
+    return f, row_id
+
+
+def kept_dense(idx: Any, call: Any, shards: tuple) -> bool:
+    """Whether the tree is KNOWN to hold a leaf row that is kept dense
+    in some shard (``Field.row_kept_dense``: a verdict a staged leaf
+    left under the view's write token).  ``stage_vm`` and
+    ``plan_fused`` are all-or-nothing, so such a tree is certain to be
+    declined and nothing need be staged to learn it: a dense read
+    stages its leaves once, for the engine that will run it.  Stops at
+    the first such leaf.  False = not known, or the engine is off:
+    the caller offers the tree and the offer accounts for itself."""
+    if not _cfg.enabled or not shards:
+        return False
+    return _any_kept_dense(idx, call, shards)
+
+
+def _any_kept_dense(idx: Any, call: Any, shards: tuple) -> bool:
+    name = call.name
+    if name == "Row":
+        leaf = _row_leaf(idx, call)
+        return leaf is not None and leaf[0].row_kept_dense(leaf[1],
+                                                           shards)
+    if name == "Not":
+        ef = idx.existence_field()
+        if ef is not None and ef.row_kept_dense(0, shards):
+            return True
+    elif name not in ("Union", "Intersect", "Difference", "Xor"):
+        return False
+    return any(_any_kept_dense(idx, c, shards) for c in call.children)
+
+
 def _walk(idx: Any, call: Any, leaves: list) -> tuple | None:
     """Shape + (field, row) leaf descriptors for a tree whose every
     leaf is a plain standard-view row — the container-eligible grammar.
@@ -1052,24 +1100,10 @@ def _walk(idx: Any, call: Any, leaves: list) -> tuple | None:
     container boundaries), and anything unknown."""
     name = call.name
     if name == "Row":
-        if call.condition_arg() is not None:
+        leaf = _row_leaf(idx, call)
+        if leaf is None:
             return None
-        if "from" in call.args or "to" in call.args:
-            return None
-        try:
-            fname = call.field_arg()
-        except ValueError:
-            return None
-        row_id = call.args.get(fname)
-        if not isinstance(row_id, int) or isinstance(row_id, bool):
-            return None
-        f = idx.field(fname)
-        if f is None:
-            return None
-        o = f.options
-        if o.type == "int" or (o.type == "time" and o.no_standard_view):
-            return None
-        leaves.append((f, row_id))
+        leaves.append(leaf)
         return ("leaf", len(leaves) - 1)
     if name in ("Union", "Intersect", "Difference", "Xor"):
         op = {"Union": "or", "Intersect": "and",
@@ -1112,9 +1146,6 @@ def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
     gathering would both tick a launch the dense route doesn't (the
     route-invariant accounting would break) and redo work the stack
     cache already holds."""
-    from pilosa_tpu.ops import bitmap as bm
-    from pilosa_tpu.shardwidth import SHARD_WIDTH
-
     if not _cfg.enabled or not shards:
         return None
     if opt is not None and not getattr(opt, "containers", True):
@@ -1124,6 +1155,11 @@ def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
     if shape is None or not leaf_descs:
         return None
     if not counts and shape[0] == "leaf":
+        return None
+    if any(f.row_kept_dense(row_id, shards) for f, row_id in leaf_descs):
+        # known from the last read of that row: declined as below,
+        # with nothing staged and no overlay looked for
+        bump("container.fallbacks")
         return None
     use_delta = opt is None or opt.delta
     for f, row_id in leaf_descs:

@@ -546,7 +546,11 @@ def evaluate(shape: tuple, leaves: tuple, counts: bool = False,
 
     ``leaves`` — tuple of uint32 stacks, all the same shape ([S, W], or
     [B, S, W] for a coalesced cross-query batch).  Returns the result
-    bitmap stack, or int32 per-row counts with ``counts=True``.
+    bitmap stack, left where it was computed, or int32 per-row counts
+    with ``counts=True``: host values from the numpy engine and from
+    the single-device program (fetched in the launch's one wait), the
+    sharded device array from the mesh program (``counts_to_host``
+    takes either).
 
     ``mesh`` — an active device mesh (meshexec.query_mesh) routes the
     shard_map program: the same tree body per device over shard-axis
@@ -601,9 +605,32 @@ def evaluate(shape: tuple, leaves: tuple, counts: bool = False,
     fn = _compiled(shape, counts)
     _note_program_cache_pressure()
     out = fn(*leaves)
-    _perfobs.sample("dense", out, t0,
-                    nbytes=_touched_bytes(*leaves, out))
-    return out
+    nbytes = _touched_bytes(*leaves, out)
+    if not counts:
+        _perfobs.sample("dense", out, t0, nbytes=nbytes)
+        return out
+    # ONE round trip a launch: every caller wants the counts on the
+    # host, so the copy is asked for here, still inside
+    # launch.dispatch and before anybody waits: the transfer queues
+    # behind the kernel.  The launch's one wait is then the fetch
+    # itself (np.asarray finds the copy on its way), not a block with
+    # a fetch after it (two wake-ups of this thread for one kernel),
+    # whether or not anything observes it.
+    out.copy_to_host_async()
+    return _perfobs.sample("dense", out, t0, nbytes=nbytes,
+                           fetch=np.asarray)
+
+
+def counts_to_host(counts: Any) -> np.ndarray:
+    """What every caller of ``evaluate(counts=True)`` does first with
+    what it returned: int64 on the host.  Host values already (the
+    single-device route's fetch, the numpy engine) convert in place.
+    A device array (the mesh route, which blocks and leaves its
+    sharded output where it is) is asked for AGAIN here, a second
+    round trip after the wait, and counted: ``launch.refetched``."""
+    if _perfobs.enabled and not isinstance(counts, np.ndarray):
+        _perfobs.bump("launch.refetched")
+    return np.asarray(counts, dtype=np.int64)
 
 
 def evaluate_gathered(shape: tuple, pools: tuple, idxs: tuple,

@@ -74,8 +74,11 @@ from pilosa_tpu import perfobs as _perfobs
 from pilosa_tpu import stagecheck as _stagecheck
 from pilosa_tpu import stats as _stats
 from pilosa_tpu import tracing
+from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.ops import containers as _containers
+from pilosa_tpu.ops import expr
 from pilosa_tpu.ops import tape as _tape
+from pilosa_tpu.runtime import residency as _residency
 from pilosa_tpu.serve.deadline import DeadlineExceededError
 
 
@@ -94,8 +97,6 @@ def resolve_enabled(mode) -> bool:
     if s != "auto":
         raise ValueError(
             f"coalescer.enabled must be auto/true/false, got {mode!r}")
-    from pilosa_tpu.ops import bitmap as bm
-
     return not bm.host_mode()
 
 
@@ -301,7 +302,18 @@ class Coalescer:
         front and stages plain leaves) batches with a delta-reading
         one only when the programs are identical anyway."""
         vmstage = None
-        if self.vm and self.ragged and use_vm and mesh is None:
+        offer = self.vm and self.ragged and use_vm
+        if (offer and mesh is None
+                and _containers.kept_dense(idx, child, shards)):
+            # a leaf row is known to be kept dense (the verdict its
+            # last staging left under the view's write token): the VM
+            # offer is certain to be declined, so it is declined here,
+            # before anything is staged for it, and counted as
+            # stage_vm would have.  The leaves are staged once, below.
+            _containers.bump("container.fallbacks")
+            _tape.bump("vm.fallbacks.ineligible_leaf")
+            _tape.bump("vm.fallbacks")
+        elif offer and mesh is None:
             # the bitmap VM: stage compressed (directories + local
             # gather rows, NO dense stacks) and key on the tape size
             # class alone — domain widths re-pad to the bucket max at
@@ -325,7 +337,7 @@ class Coalescer:
                         fast=_stagecheck.fast_leaves() - fast0)
             if vmstage is None:
                 _tape.bump("vm.fallbacks")
-        elif self.vm and self.ragged and use_vm:
+        elif offer:
             # mesh-routed query: informational reason cell ONLY — the
             # shard_map interpreter is a route, not a degradation, so
             # the central vm.fallbacks total stays untouched
@@ -418,7 +430,11 @@ class Coalescer:
                 # a follower's record names the batch leader's trace —
                 # the span that owns the shared device launch
                 rec.coalesce["launch_trace"] = bucket.flush_trace
-        arr = np.asarray(counts, dtype=np.int64)
+        # the dense engine's counts came home inside the launch's one
+        # wait (expr.evaluate): host values, and this a sum of them.  A
+        # tape or VM batch hands each member its own device row
+        arr = (np.asarray(counts, dtype=np.int64) if bucket.tape_final
+               else expr.counts_to_host(counts))
         if entry.vm is not None:
             # VM results are per-domain-slot counts over the bucket's
             # padded domain — pad slots gather the megapool zero row
@@ -475,8 +491,8 @@ class Coalescer:
         scattered to every waiter.  Appends are impossible once sealed
         (sealing happens under the same lock that guards appends).
         EVERYTHING here runs inside the try: any failure — including
-        stats/tracing backends or the ops import — must resolve every
-        waiter's future, or followers would block forever."""
+        stats/tracing backends — must resolve every waiter's future,
+        or followers would block forever."""
         # deadline-aware launch: entries whose budget died while the
         # window was open are dropped from the batch BEFORE launch —
         # their futures resolve to DeadlineExceededError, and their
@@ -507,8 +523,6 @@ class Coalescer:
         if n == 0:
             return
         try:
-            from pilosa_tpu.ops import expr
-
             # heterogeneity accounting (the before/after evidence for
             # the ragged engine): a query whose flushed batch held no
             # same-shape partner is a shape MISS — with ragged off it
@@ -543,8 +557,6 @@ class Coalescer:
                 lead = _observe.current()
                 if lead is not None:
                     bucket.launch_span = [lead.trace_id, span.id]
-                from pilosa_tpu.runtime import residency as _residency
-
                 # the batch's workload signature for the engine
                 # observatory: dense-equivalent uint32 words (the
                 # size-class key every engine's cost-table cell shares)
@@ -660,7 +672,7 @@ class Coalescer:
                     bucket.engine = ("mesh" if live[0].mesh is not None
                                      else "dense")
                     with _perfobs.context(work=sig_work):
-                        counts = np.asarray(
+                        counts = expr.counts_to_host(
                             _residency.run_with_oom_retry(
                                 lambda: expr.evaluate(
                                     shape, stacked, counts=True,
@@ -668,8 +680,7 @@ class Coalescer:
                                     # live occupancy, not the pow2-
                                     # padded batch rows, feeds the
                                     # mesh.queries counter
-                                    mesh_queries=n)),
-                            dtype=np.int64)
+                                    mesh_queries=n)))
                     results = [counts[b] for b in range(n)]
                 else:
                     # heterogeneous bucket: the whole ragged batch as

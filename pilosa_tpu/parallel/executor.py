@@ -45,6 +45,9 @@ from pilosa_tpu.parallel.cluster import (
 from pilosa_tpu.models.timequantum import parse_time
 from pilosa_tpu.models.view import VIEW_STANDARD
 from pilosa_tpu.ops import bitmap as bm
+from pilosa_tpu.ops import containers as _containers
+from pilosa_tpu.ops import expr
+from pilosa_tpu.parallel import meshexec
 from pilosa_tpu.parallel.results import (
     FieldRow,
     GroupCount,
@@ -332,9 +335,7 @@ class Executor:
             # fused paths consult _query_mesh at several call sites
             # (staging + per-group batch fns), which must not each
             # count
-            from pilosa_tpu.parallel import meshexec as _meshexec
-
-            _meshexec.note_fallback()
+            meshexec.note_fallback()
         rec = None
         if self.recorder is not None and self.recorder.enabled:
             # str() on a parsed Query re-serializes the AST — only pay
@@ -1211,8 +1212,6 @@ class Executor:
         ``mesh`` (``_query_mesh``) routes the shard_map program so the
         one launch spans every mesh device; None is the pre-mesh
         single-device program (?nomesh=1 / [mesh] disabled)."""
-        from pilosa_tpu.ops import expr
-
         shape, leaves = self._fused_expr(idx, call, shards, use_delta)
         with _observe.span("launch") as sp:
             out = expr.evaluate(shape, leaves, mesh=mesh)
@@ -1224,8 +1223,6 @@ class Executor:
         """The device mesh this request's fused dispatches run under:
         the active [mesh] layout, or None for ?nomesh=1 (counted as a
         mesh fallback) and whenever the mesh cannot activate."""
-        from pilosa_tpu.parallel import meshexec
-
         return meshexec.query_mesh(opt is None or opt.mesh)
 
     @staticmethod
@@ -1406,9 +1403,7 @@ class Executor:
         # under the previous device layout — and when the operator
         # toggles BACK, the old flavor's still-generation-valid
         # entries become warm again instead of having been overwritten
-        from pilosa_tpu.parallel import meshexec as _meshexec
-
-        placement = _meshexec.placement_token(
+        placement = meshexec.placement_token(
             opt is None or opt.mesh)
         key = resultcache.Key(
             (self.holder.uid, idx.name, kind, sig, extra, shards,
@@ -1485,8 +1480,6 @@ class Executor:
             # (ops/containers.py): one launch over the pooled
             # directory-matched containers, scattered back to dense
             # per-shard words here
-            from pilosa_tpu.ops import containers as _containers
-
             with _observe.span("plan"):
                 m = self._query_mesh(opt)
                 cplan = _containers.plan_fused(self, idx, call, g, opt,
@@ -1738,9 +1731,6 @@ class Executor:
             # compressed container engine first (ops/containers.py):
             # same single launch, but only the directory-matched
             # container blocks are ever read
-            from pilosa_tpu.ops import containers as _containers
-            from pilosa_tpu.ops import expr
-
             with _observe.span("plan"):
                 m = self._query_mesh(opt)
                 cplan = _containers.plan_fused(self, idx, child,
@@ -1754,7 +1744,7 @@ class Executor:
                 sp.note_engine()
             with _observe.span("reduce"):
                 return [int(c) for c in
-                        np.asarray(counts, dtype=np.int64)[:len(group)]]
+                        expr.counts_to_host(counts)[:len(group)]]
 
         def compute_counts(group):
             # device-dispatch resilience: a backend RESOURCE_EXHAUSTED

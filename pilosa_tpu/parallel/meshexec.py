@@ -45,7 +45,11 @@ from __future__ import annotations
 import threading
 from typing import Any
 
+import jax
 import numpy as np
+
+from pilosa_tpu import devobs
+from pilosa_tpu.ops import bitmap as bm
 
 #: The one data axis of a bitmap index (SURVEY.md §2.5: sharding is
 #: the reference's entire parallelism strategy) — shared with
@@ -79,6 +83,15 @@ _refs = 0
 #: (axis_size, device ids) -> Mesh — meshes are cached singletons so
 #: program caches keyed on the Mesh object stay warm across queries.
 _mesh_cache: dict = {}
+#: What a read used to ask JAX for ten times over (PERF.md section 6,
+#: PR 37), kept until the event that can change it.  ``_eligible``'s
+#: answer holds while the backend lives (``backend_reset`` forgets it);
+#: ``axis_size``'s holds until the [mesh] config next changes
+#: (``configure`` / ``release`` / ``reset`` forget it under
+#: ``_cfg_lock``, where it is also computed, so a racing reader cannot
+#: put back a value from before the change).  None = not asked yet.
+_eligible_cached: bool | None = None
+_axis_cached: int | None = None
 
 
 def config() -> MeshRuntimeConfig:
@@ -100,11 +113,13 @@ def configure(enabled=None, axis_size: int | None = None) -> MeshRuntimeConfig:
                      "0", "false", "no", "off", "auto"):
             raise ValueError(
                 f"mesh.enabled must be auto/true/false, got {enabled!r}")
+    global _axis_cached
     with _cfg_lock:
         if enabled is not None:
             _cfg.enabled = enabled
         if axis_size is not None:
             _cfg.axis_size = int(axis_size)
+        _axis_cached = None
     return _cfg
 
 
@@ -122,25 +137,40 @@ def retain() -> None:
 def release() -> None:
     """Drop a server reference; the LAST holder restores the captured
     baseline for every other user of the process."""
-    global _refs, _baseline
+    global _refs, _baseline, _axis_cached
     with _cfg_lock:
         if _refs > 0:
             _refs -= 1
         if _refs == 0 and _baseline is not None:
             _cfg.enabled, _cfg.axis_size = _baseline
             _baseline = None
+            _axis_cached = None
 
 
 def reset() -> MeshRuntimeConfig:
     """Restore defaults, drop any held baseline and cached meshes
     (tests)."""
-    global _cfg, _baseline, _refs
+    global _cfg, _baseline, _refs, _axis_cached
     with _cfg_lock:
         _cfg = MeshRuntimeConfig()
         _baseline = None
         _refs = 0
         _mesh_cache.clear()
+        _axis_cached = None
     return _cfg
+
+
+def backend_reset() -> None:
+    """The JAX backend was torn down and will come back with other
+    devices (``jax.extend.backend.clear_backends``, which
+    ``__graft_entry__`` does for its virtual mesh): forget what was
+    learned from the old one, here and in ``bm.host_mode``."""
+    global _eligible_cached, _axis_cached
+    with _cfg_lock:
+        bm.forget_backend()
+        _eligible_cached = None
+        _axis_cached = None
+        _mesh_cache.clear()
 
 
 def resolve_enabled(mode) -> bool:
@@ -165,22 +195,33 @@ def _eligible() -> bool:
     device, single process (the multi-process global mesh belongs to
     parallel/spmd.py's collective plans), and not host mode (one CPU
     device runs the numpy/native engine — there is nothing to
-    shard)."""
-    import jax
-
-    from pilosa_tpu.ops import bitmap as bm
-
-    if bm.host_mode():
-        return False
-    if jax.process_count() > 1:
-        return False
-    return len(jax.local_devices()) > 1
+    shard).  Asked of JAX once a backend: none of the three can change
+    while it lives."""
+    global _eligible_cached
+    ok = _eligible_cached
+    if ok is None:
+        ok = _eligible_cached = (not bm.host_mode()
+                                 and jax.process_count() == 1
+                                 and len(jax.local_devices()) > 1)
+    return ok
 
 
 def axis_size() -> int:
     """The shard-axis size in force: ``[mesh] axis-size`` clamped to
     the local device count (0 = all local devices).  1 when the mesh
-    cannot activate."""
+    cannot activate.  Computed once a [mesh] config (every staged leaf
+    asks, through ``placement_token``)."""
+    global _axis_cached
+    n = _axis_cached
+    if n is None:
+        with _cfg_lock:
+            n = _axis_cached
+            if n is None:
+                n = _axis_cached = _axis_size_locked()
+    return n
+
+
+def _axis_size_locked() -> int:
     if not _eligible():
         return 1
     try:
@@ -188,8 +229,6 @@ def axis_size() -> int:
             return 1
     except ValueError:
         return 1
-    import jax
-
     n = len(jax.local_devices())
     want = _cfg.axis_size
     if want and want > 0:
@@ -210,7 +249,6 @@ def active_mesh():
     n = axis_size()
     if n <= 1:
         return None
-    import jax
     from jax.sharding import Mesh
 
     devs = tuple(jax.local_devices()[:n])
@@ -296,16 +334,11 @@ def place_stack(stack: np.ndarray, label: str = "field.stack",
     The caller pads axis 0 to a mesh-size multiple (``pad_axis``);
     transfer metering rides devobs under ``mesh_label``/``label`` for
     the sharded/single-device flavors like every other placement."""
-    import jax
     from jax.sharding import NamedSharding
 
     m = active_mesh()
     if m is None:
-        from pilosa_tpu.ops import bitmap as bm
-
         return bm.device_put(stack, label=label)
-    from pilosa_tpu import devobs
-
     devobs.note_transfer(stack.nbytes, m.size, mesh_label)
     bump("mesh.placements")
     bump("mesh.placed_bytes", stack.nbytes)
@@ -316,16 +349,11 @@ def place_replicated(arr, mesh=None, label: str = "field.containers"):
     """Place an array replicated on every mesh device (container word
     pools: gather indices address arbitrary pool rows, so the pool
     must be whole everywhere — the domain axis shards instead)."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     m = mesh if mesh is not None else active_mesh()
     if m is None:
-        from pilosa_tpu.ops import bitmap as bm
-
         return bm.device_put(arr, label=label)
-    from pilosa_tpu import devobs
-
     devobs.note_transfer(arr.nbytes * m.size, m.size, label)
     bump("mesh.placements")
     bump("mesh.placed_bytes", arr.nbytes * m.size)
@@ -340,7 +368,6 @@ def ensure_placed(arr, mesh, shard_dim: int):
     leaf arrived single-device (a cold cache filled under ?nomesh, a
     test's monkeypatched placement) it is one explicit transfer
     instead of an error."""
-    import jax
     from jax.sharding import NamedSharding
 
     return jax.device_put(
@@ -348,7 +375,6 @@ def ensure_placed(arr, mesh, shard_dim: int):
 
 
 def ensure_replicated(arr, mesh):
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     return jax.device_put(arr, NamedSharding(mesh, P()))
@@ -462,8 +488,6 @@ def debug(n_shards: int | None = None) -> dict[str, Any]:
     axis layout (devices joined to the shard axis), the per-device
     shard plan for an ``n_shards``-wide query (the widest index, when
     the handler knows it), and the mesh.* counters."""
-    import jax
-
     m = active_mesh()
     try:
         n_local = len(jax.local_devices())

@@ -209,6 +209,12 @@ _counters = {
     "engine.bytes": 0,          # analytic bytes across sampled launches
     "cost.samples": 0,          # cost-table sample insertions
     "cost.profiles": 0,         # completed profiler captures
+    # sampled launches whose ONE wait was the fetch of their counts
+    # (sample's ``fetch``), and launches of expr.evaluate(counts=True)
+    # whose counts were asked of the device AGAIN after the wait
+    # (expr.counts_to_host): a second round trip a read
+    "launch.fetched": 0,
+    "launch.refetched": 0,
 }
 
 
@@ -356,7 +362,8 @@ def t0() -> Any:
 
 
 def sample(engine: str, out: Any, t0_ns: Any, nbytes: int,
-           work: int = 0, sparsity: float = 1.0) -> None:
+           work: int = 0, sparsity: float = 1.0,
+           fetch: Any = None) -> Any:
     """Complete one launch sample: block on ``out`` (OUTSIDE any lock
     — the P3 rule), then fold wall/bytes/bandwidth into the cost table
     and stamp the engine onto the active flight record.  When
@@ -368,14 +375,22 @@ def sample(engine: str, out: Any, t0_ns: Any, nbytes: int,
     size-class key (defaults to nbytes/4); ``sparsity`` — bytes
     touched / dense-equivalent bytes (1.0 for the dense engines).  A
     thread-local :class:`context` overrides engine/sparsity when the
-    orchestration layer knows better than the ops layer."""
+    orchestration layer knows better than the ops layer.
+
+    ``fetch`` — the caller's own wait, for a launch whose result it
+    wants on the host: ``fetch(out)`` is called exactly once IN PLACE
+    OF the block and its value returned, so the launch synchronises
+    with the device once and the wait this sample times is the fetch
+    itself (``launch.ready`` notes ``fetched=1``).  It is the
+    caller's: with nothing observing it is still called, here, and
+    what it raises is the query's error, not telemetry's."""
     if not t0_ns:
-        return
+        return None if fetch is None else fetch(out)
     dispatch = None if isinstance(t0_ns, int) else t0_ns
     if dispatch is not None:
         dispatch.__exit__(None, None, None)
         if not enabled:
-            return
+            return None if fetch is None else fetch(out)
     ctx = _ctx()
     if ctx is not None:
         if ctx.engine is not None:
@@ -384,18 +399,22 @@ def sample(engine: str, out: Any, t0_ns: Any, nbytes: int,
             sparsity = ctx.sparsity
         if ctx.work is not None:
             work = ctx.work
+    wait = _block if fetch is None else fetch
     if dispatch is None:
-        _block(out)
+        got = wait(out)
         wall_ns = _clock() - t0_ns
     else:
-        with _observe.span("launch.ready",
-                           start_ns=dispatch.end_ns) as ready:
-            _block(out)
+        noted = {} if fetch is None else {"fetched": 1}
+        with _observe.span("launch.ready", start_ns=dispatch.end_ns,
+                           **noted) as ready:
+            got = wait(out)
         wall_ns = ready.end_ns - dispatch.start_ns
-    record_sample(engine, wall_ns, nbytes, work, sparsity)
+    record_sample(engine, wall_ns, nbytes, work, sparsity,
+                  fetched=fetch is not None)
     rec = _observe.current()
     if rec is not None:
         rec.note_engine(engine)
+    return got
 
 
 def launch(engine: str, fn: Any) -> Any:
@@ -427,10 +446,11 @@ def _block(out: Any) -> None:
 
 
 def record_sample(engine: str, wall_ns: int, nbytes: int,
-                  work: int = 0, sparsity: float = 1.0) -> None:
+                  work: int = 0, sparsity: float = 1.0,
+                  fetched: bool = False) -> None:
     """Fold one measured launch into the cost table (the pure math
     under :func:`sample` — tests drive it directly with a fake
-    clock)."""
+    clock).  ``fetched``: the wait was the fetch of the result."""
     wall_us = wall_ns / 1e3
     gbps = ((nbytes / (wall_ns / 1e9)) / 1e9) if wall_ns > 0 else 0.0
     key = (engine, size_class(work if work > 0 else max(1, nbytes // 4)),
@@ -443,6 +463,8 @@ def record_sample(engine: str, wall_ns: int, nbytes: int,
         _counters["engine.launches"] += 1
         _counters["engine.bytes"] += nbytes
         _counters["cost.samples"] += 1
+        if fetched:
+            _counters["launch.fetched"] += 1
 
 
 # ------------------------------------------------------------------- exports

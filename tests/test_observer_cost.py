@@ -304,8 +304,10 @@ def test_observer_off_is_no_call_no_lock_no_clock(ex, monkeypatch,
 #: ``http.read``, ``admission.wait``, ``pql.parse`` and ``serialize``
 #: on top).  Ceilings, not targets: a PR that adds a span to the
 #: served read raises them here, in the open.  PR 32's own request
-#: parse and single send read the same clocks: unchanged.
-LONE_DENSE = {"executor": (24, 13), "http": (35, 19)}
+#: parse and single send read the same clocks: unchanged.  PR 37: a
+#: dense read declines the VM offer before its ``stage`` span is opened
+#: (one span and two clock reads fewer; was (24, 13) and (35, 19)).
+LONE_DENSE = {"executor": (22, 12), "http": (33, 18)}
 
 
 @pytest.fixture

@@ -9,9 +9,13 @@ coalescer flushes by what ended the leader's wait (`why`: idle, busy,
 full, cap; absent on a program that predates it), and the share of
 staged leaves whose cached stacks were validated against the
 view's write token alone (`fast` of `leaves` on the `stage` spans;
-absent likewise), and the answers the server wrote between the two ends
+absent likewise), the answers the server wrote between the two ends
 of the window beside the socket sends they took (`http.responses`,
-`http.sends` of `/debug/vars`; absent likewise).
+`http.sends` of `/debug/vars`; absent likewise), and the share of
+launches whose one wait for the device was the fetch of their counts:
+by route, the reads whose `launch.ready` span notes `fetched`, and over
+the window `launch.fetched` and `launch.refetched` beside
+`engine.launches` (`/debug/vars`; absent likewise).
 
     python3 tools/route_table.py --workload seg-dense --seed <n> \\
         --seconds 51 --trace 1
@@ -51,6 +55,7 @@ def route_of(profile: dict) -> str:
 def say_table(records) -> None:
     rows: dict[str, list] = {}
     why: dict[str, int] = {}
+    fetched: dict[str, int] = {}
     fast = leaves = 0
     for r in records:
         spans = sp.of(r) if r.status == 200 and r.profile else None
@@ -60,6 +65,9 @@ def say_table(records) -> None:
             if s["name"] == "stage" and "fast" in s:
                 fast += s["fast"]
                 leaves += s["leaves"]
+            elif s["name"] == "launch.ready" and s.get("fetched"):
+                route = route_of(r.profile)
+                fetched[route] = fetched.get(route, 0) + 1
         rows.setdefault(route_of(r.profile), []).append(
             [sp.total(spans, "http.parse"), sp.self_total(spans, "stage")]
             + [sp.total(spans, name) for name in PHASES[2:]]
@@ -81,6 +89,10 @@ def say_table(records) -> None:
     if leaves:
         harness.say(f"leaves staged: {leaves}, without a walk over the "
                     f"shards: {fast} ({100 * fast / leaves:.2f}%)")
+    if fetched:
+        harness.say("reads whose launch.ready was the fetch: " + ", ".join(
+            f"{route} {n} of {len(rows[route])}"
+            for route, n in sorted(fetched.items())))
 
 
 def say_routes(records) -> None:
@@ -101,6 +113,11 @@ def measure(ses, *args):
                             for k in ("http.responses", "http.sends"))
         harness.say(f"wire: http.responses +{responses} http.sends "
                     f"+{sends} ({sends / responses:.4f} a response)")
+    if "launch.fetched" in after:
+        launches, got, again = (after[k] - before[k] for k in (
+            "engine.launches", "launch.fetched", "launch.refetched"))
+        harness.say(f"launches: engine.launches +{launches} "
+                    f"launch.fetched +{got} launch.refetched +{again}")
     return out
 
 
