@@ -22,6 +22,13 @@ KEYS = {"configs": {"name", "source", "file", "reduced", "why"},
         "per_layer": {"name", "unit", "better", "source", "layer", "moves"}}
 
 
+#: "2/5 of the 240/s knee", "four fifths of its knee", "half of the knee"
+KNEE = re.compile(r"(?:(\d+)/(\d+)|(one|two|three|four) fifths?|(half)) of "
+                  r"(?:the |a |its )?(?:(\d+(?:\.\d+)?)/s )?knee")
+OFFERED = re.compile(r"open loop at (\d+(?:\.\d+)?)/s")
+FIFTHS = {"one": 1, "two": 2, "three": 3, "four": 4}
+
+
 class ManifestError(Exception):
     pass
 
@@ -39,6 +46,52 @@ def _line(s, what: str) -> None:
 def load(root: str = ROOT) -> dict:
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def knee_of(traffic: dict) -> float | None:
+    """The knee that a traffic file's ``sweep`` rows show, by
+    ``sweep.py``'s rule: the highest step that, like every step under
+    it, kept its p95 inside the latency limit with nothing unfinished
+    at the close."""
+    sweep = traffic.get("sweep") or {}
+    knee = None
+    try:
+        rate, p95, unfinished = (sweep["columns"].index(c) for c in (
+            "rate_qps", "read_p95_ms", "unfinished_at_close"))
+        for row in sorted(sweep["rows"], key=lambda r: r[rate]):
+            if row[p95] > traffic["latency_limit_ms"] or row[unfinished]:
+                break
+            knee = float(row[rate])
+    except (KeyError, ValueError, IndexError, TypeError):
+        return None  # no sweep, or one without those columns
+    return knee
+
+
+def _why_agrees_with_traffic(w: dict, traffic: dict) -> None:
+    """A cell's ``why`` that names its rate or a share of a knee says
+    what its traffic file says (``seg-dense`` said four fifths of the
+    knee for twelve PRs after the knee had doubled)."""
+    cell = f"cell {w['name']}"
+    said = OFFERED.search(w["why"])
+    _need(not said or float(said[1]) == traffic.get("rate_qps"),
+          f"{cell}: its why says {said and said[1]}/s, its traffic file "
+          f"rate_qps {traffic.get('rate_qps')}")
+    said = KNEE.search(w["why"])
+    if not said:
+        return
+    _need(traffic["loop"] == "open", f"{cell}: a knee, and no open loop")
+    knee = knee_of(traffic)
+    _need(knee is not None, f"{cell}: its why names a knee and no step of "
+                            "its traffic file's sweep held the limit")
+    share = (int(said[1]) / int(said[2]) if said[1] else
+             FIFTHS[said[3]] / 5 if said[3] else 0.5)
+    _need(not said[5] or float(said[5]) == knee,
+          f"{cell}: its why says a {said[5]}/s knee, its sweep's rows "
+          f"{knee:g}/s")
+    _need(abs(traffic["rate_qps"] - share * knee) <= 0.05 * knee,
+          f"{cell}: its why says {said[0]!r}; rate_qps "
+          f"{traffic['rate_qps']:g} is {traffic['rate_qps'] / knee:.2f} of "
+          f"the {knee:g}/s that its sweep's rows show")
 
 
 def metric_cells(m: dict, metric: dict) -> list[str]:
@@ -102,9 +155,12 @@ def check(m: dict, root: str = ROOT) -> None:
               f"cell {w['name']}: bad traffic name")
         _need(w["chips"] in (1, 4), f"cell {w['name']}: chips 1 or 4")
         _line(w["why"], f"cell {w['name']} why")
-        _need(os.path.isfile(os.path.join(
-            root, "perfbench", "traffic", w["traffic"] + ".json")),
-            f"cell {w['name']}: no traffic file {w['traffic']}.json")
+        traffic = os.path.join(root, "perfbench", "traffic",
+                               w["traffic"] + ".json")
+        _need(os.path.isfile(traffic),
+              f"cell {w['name']}: no traffic file {w['traffic']}.json")
+        with open(traffic) as f:
+            _why_agrees_with_traffic(w, json.load(f))
     for c in configs:
         _need(any(w["config"] == c for w in m["workloads"]),
               f"configuration {c} has no cell")

@@ -410,9 +410,48 @@ def main(argv=None) -> int:
     return rc
 
 
-def run(args, manifest: dict, cell: dict, work: str) -> dict:
+@dataclasses.dataclass
+class Measured:
+    """One run's window, closed and checked: what a result line, and
+    ``steady.py``'s reading of it, are made from."""
+    ses: Session
+    mix: object
+    setup_s: float
+    win: Window
+    devices_before: dict
+    devices_after: dict
+    span: list | None  # the traced span of a ``--trace 1`` run
+    e2e: dict          # ``loadgen.summarize`` of the window
+    check: dict        # ``check_answers`` of the window
+
+    @property
+    def correct(self) -> bool:
+        return self.check["wrong"] == 0 and self.check["compared"] > 0
+
+    @property
+    def failed(self) -> int:
+        """Reads that failed, and answers that differ from the oracle's."""
+        return self.e2e["failed"] + self.check["wrong"]
+
+    def compared(self) -> dict:
+        """Each number that ``correct`` rests on, beside its limit."""
+        return {"differing_answers": {"value": self.check["wrong"],
+                                      "limit": 0},
+                "answers_compared": {"value": self.check["compared"],
+                                     "at_least": 1}}
+
+    def values(self) -> dict:
+        """The end-to-end metrics, by their names in the manifest."""
+        return {**{k: self.e2e[k] for k in ("read_p50_ms", "read_p95_ms",
+                                            "goodput_qps")},
+                "setup_s": self.setup_s}
+
+
+def window(args, manifest: dict, cell: dict, work: str) -> Measured:
+    """Set-up, the measured window, and the comparison with the oracle
+    once the server has gone."""
     ses = open_session(args, manifest, cell, work)
-    ds, traffic, backend = ses.ds, ses.traffic, ses.backend
+    ds, traffic = ses.ds, ses.traffic
     mix = mixmod.build(traffic, ds.n_rows, args.seed, args.seconds)
     setup_s = time.monotonic() - T_START
     win, devices_before, devices_after, span = measure(
@@ -422,14 +461,22 @@ def run(args, manifest: dict, cell: dict, work: str) -> dict:
     # ---- after the server has gone -----------------------------------
     e2e = summarize(win.records, args.seconds, traffic["latency_limit_ms"])
     check = check_answers(ds, mix, win.records, traffic, args.seed)
-    correct = check["wrong"] == 0 and check["compared"] > 0
     say("phases: " + " ".join(f"{k}={v:.1f}" for k, v in ses.phases.items())
         + f" setup_s={setup_s:.1f} oracle_s={check['seconds']:.1f}")
     say(window_line(e2e, traffic["latency_limit_ms"])
         + f"; compared {check['compared']} answers of {check['calls']} "
         f"calls, {check['wrong']} wrong")
-    line = {"correct": bool(correct), "attempted": e2e["attempted"],
-            "failed": e2e["failed"] + check["wrong"]}
+    return Measured(ses, mix, setup_s, win, devices_before, devices_after,
+                    span, e2e, check)
+
+
+def run(args, manifest: dict, cell: dict, work: str) -> dict:
+    got = window(args, manifest, cell, work)
+    ds, backend, mix, win = got.ses.ds, got.ses.backend, got.mix, got.win
+    e2e = got.e2e
+    devices_before, devices_after = got.devices_before, got.devices_after
+    line = {"correct": got.correct, "attempted": e2e["attempted"],
+            "failed": got.failed}
     dev = devices_after["devices"]
     line["device"] = {
         "platform": backend["platform"], "kind": backend["deviceKind"],
@@ -439,13 +486,12 @@ def run(args, manifest: dict, cell: dict, work: str) -> dict:
     mine = lambda e: cell["name"] in check_manifest.metric_cells(  # noqa: E731
         manifest, e)
     if not args.trace:
-        values = {**{k: e2e[k] for k in ("read_p50_ms", "read_p95_ms",
-                                         "goodput_qps")}, "setup_s": setup_s}
+        values = got.values()
         line["metrics"] = {
             e["name"]: {"value": values[e["name"]], "unit": e["unit"]}
             for e in manifest["end_to_end"] if mine(e)}
     else:
-        t0, t1, t2, trace_dir = span
+        t0, t1, t2, trace_dir = got.span
         trace = reduce_trace(trace_dir)
         cap = Capture(
             records=win.records, queries=mix.queries, meta=ds.meta(),
@@ -479,6 +525,11 @@ def run(args, manifest: dict, cell: dict, work: str) -> dict:
         line = {"rehearsal": True, "correct": line["correct"],
                 "attempted": line["attempted"], "failed": line["failed"],
                 "read": sorted(line["metrics"])}
+    line["compared"] = got.compared()  # last in the line, and on stderr
+    for name, c in line["compared"].items():
+        limit = " ".join(f"{k}={v}" for k, v in c.items() if k != "value")
+        print(f"perfbench: compared {name}={c['value']} {limit}",
+              file=sys.stderr, flush=True)
     return line
 
 

@@ -147,6 +147,70 @@ def test_a_broken_manifest_is_refused(wide, what):
         check(manifest, root)
 
 
+def _retell(root, cell, why=None, **traffic):
+    """Rewrites a cell's ``why`` and keys of its traffic file in the
+    copy under ``root``."""
+    if why is not None:
+        cell["why"] = why
+    path = os.path.join(root, "perfbench", "traffic",
+                        cell["traffic"] + ".json")
+    with open(path) as f:
+        t = json.load(f)
+    t.update(traffic)
+    with open(path, "w") as f:
+        json.dump(t, f)
+    return t
+
+
+SWEEP = {"columns": ["rate_qps", "read_p95_ms", "unfinished_at_close"],
+         "rows": [[240, 9.0, 0], [80, 5.0, 0], [160, 6.0, 0],
+                  [320, 31.0, 0], [400, 20.0, 7]]}
+
+
+@pytest.mark.parametrize("why, rate, refused", [
+    ("open loop at 96/s, 2/5 of the 240/s knee: Count trees", 96.0, None),
+    ("open loop at 192/s, 4/5 of the knee: Count trees", 192.0, None),
+    ("open loop at 192/s, four fifths of its knee", 192.0, None),
+    ("open loop at 120/s, half of the 240/s knee", 120.0, None),
+    ("open loop at 200/s, about 4/5 of a 240/s knee", 200.0, None),
+    ("open loop, Count trees, no share of anything named", 96.0, None),
+    # what seg-dense said from PR 25 to PR 38
+    ("open loop at 96/s, 4/5 of the knee: Count trees", 96.0,
+     "is 0.40 of the 240/s"),
+    ("open loop at 96/s, 4/5 of the 120/s knee", 96.0,
+     "says a 120/s knee, its sweep's rows 240/s"),
+    ("open loop at 96/s, 2/5 of the 240/s knee", 192.0,
+     "says 96/s, its traffic file rate_qps 192"),
+    ("2/5 of the knee", 120.0, "is 0.50 of the 240/s"),
+])
+def test_a_why_that_names_a_share_of_a_knee_agrees_with_the_traffic_file(
+        wide, why, rate, refused):
+    manifest, root = wide
+    _retell(root, manifest["workloads"][1], why, rate_qps=rate,
+            latency_limit_ms=25, sweep=SWEEP)
+    if refused is None:
+        check(manifest, root)
+    else:
+        with pytest.raises(ManifestError, match=refused):
+            check(manifest, root)
+
+
+def test_a_knee_needs_a_step_that_held_the_limit(wide):
+    manifest, root = wide
+    t = _retell(root, manifest["workloads"][1], "4/5 of the knee",
+                latency_limit_ms=4, sweep=SWEEP)
+    assert check_manifest.knee_of(t) is None
+    with pytest.raises(ManifestError, match="no step of"):
+        check(manifest, root)
+
+
+def test_the_knee_is_the_highest_step_that_held_with_all_under_it():
+    t = {"latency_limit_ms": 25, "sweep": SWEEP}
+    assert check_manifest.knee_of(t) == 240.0   # 400/s held p95, not the close
+    assert check_manifest.knee_of({**t, "latency_limit_ms": 5.5}) == 80.0
+    assert check_manifest.knee_of({"latency_limit_ms": 25}) is None
+
+
 def test_one_four_chip_cell_is_always_allowed(manifest):
     manifest["workloads"][0]["chips"] = 4
     check(manifest)

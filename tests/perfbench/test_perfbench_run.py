@@ -43,6 +43,13 @@ def test_rehearsal_runs_the_whole_flow_and_reports_no_device_number(cell):
             "compiles_in_window", "launches_per_read",
             "coalesce_batch"} <= set(line["read"])
     assert "limit=0" in out.stdout  # each compared number, by its limit
+    # ... and as the last lines of stderr, and last in the result's line
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["differing_answers"] == {"value": 0, "limit": 0}
+    assert out.stderr.strip().splitlines()[-2:] == [
+        "perfbench: compared differing_answers=0 limit=0",
+        "perfbench: compared answers_compared="
+        f"{line['compared']['answers_compared']['value']} at_least=1"]
     assert not os.path.exists(os.path.join(ROOT, "perfbench", ".work", cell))
 
 
@@ -55,6 +62,8 @@ def test_a_lost_import_comes_out_not_correct():
                     "lost-shard")
     assert out.returncode == 0, out.stderr[-2000:]
     assert line["correct"] is False and line["failed"] > 0
+    assert line["compared"]["differing_answers"]["value"] > 0
+    assert "perfbench: compared differing_answers=" in out.stderr
 
 
 def test_no_tpu_no_result():
@@ -92,6 +101,37 @@ def test_the_sweep_steps_the_load_on_one_server():
     assert [r["step"] for r in table] == [10.0, 30.0, 20.0]
     assert "warm-up: 20 requests" in out.stdout
     assert all(r["failed"] == 0 and r["attempted"] > 0 for r in table)
+
+
+def steady(*args):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "steady.py"),
+         "--workload", "seg-dense", "--seed", str(2 ** 31 + 39), *args],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=MINUTE)
+    return out, out.stdout.strip().splitlines()
+
+
+def test_the_a_a_rehearsal_runs_the_cell_and_reports_no_value():
+    out, lines = steady("--runs", "1", "--seconds", "2", "--rehearse")
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert len(lines) == 1    # the runs' own lines went to stderr
+    line = json.loads(lines[0])
+    assert line["rehearsal"] is True and line["correct"] is True
+    assert line["failed"] == 0 and line["attempted"][0] > 20
+    assert line["seeds"] == [2 ** 31 + 39]
+    assert line["read"] == ["goodput_qps", "read_p50_ms", "read_p95_ms",
+                            "setup_s"]
+    assert "metrics" not in line and "verdict" not in line
+    assert "limit=0" in out.stderr and "window: attempted=" in out.stderr
+    assert not os.path.exists(os.path.join(ROOT, "perfbench", ".work",
+                                           "seg-dense"))
+
+
+def test_the_a_a_rehearsal_reads_nothing_without_a_tpu():
+    out, lines = steady("--runs", "2", "--seconds", "1")
+    assert out.returncode != 0 and not lines
+    assert "needs 1 TPU chip" in out.stderr
 
 
 @pytest.mark.parametrize("sig", ["SIGTERM", "SIGKILL"])
