@@ -1275,13 +1275,14 @@ class Executor:
         state change flips at least one component, while an unchanged
         view reproduces the stamp exactly.
 
-        Memoized per (field, view): ``Intersect(Row(f=a), Row(f=b))``
+        Memoized per (field name, view) of the one index a probe
+        reads: ``Intersect(Row(f=a), Row(f=b))``
         touches the same view twice but needs one stamp.  The single
         pass keeps a probe that misses to one walk over the shards: the
         common fully-populated case batches all dict lookups into one
         C-level ``itemgetter`` call, falling back to the filtering loop
         only when some shard has no fragment."""
-        mkey = (id(f), view_name)
+        mkey = ("" if f is None else f.name, view_name)
         if mkey in out:
             return
         view = None if f is None else f.view(view_name)
@@ -1309,12 +1310,22 @@ class Executor:
         out[mkey] = (len(fs), sg, sq, su, mu)
 
     def _rc_sig(self, idx, call: Call, shards: tuple[int, ...],
-                gens_out: list):
+                gens_out: dict, moved: list):
         """Canonical identity of one fused-supported bitmap tree: the
         expression shape with leaf identities (field, view, row /
         op+value) substituted at the slots — distinct queries over the
         same shape get distinct keys, unlike the coalescer's value-
-        erased bucket key.  Collects every participating fragment's
+        erased bucket key.  Canonical in operand order too: the
+        operands of Union / Intersect / Xor, and those of Difference
+        after its first, join the tuple sorted, so
+        ``Intersect(a, b)`` and ``Intersect(b, a)`` are one key and
+        one entry.  Nothing else is rewritten (no flattening, no De
+        Morgan).  The order is that of the operands' ``repr``: total
+        over anything a level can hold (a row beside a range, a nested
+        operator, an int row id beside a string one: tuple comparison
+        raises there), and the same in every process.  A level whose
+        operands were written in another order appends to ``moved``.
+        Collects every participating fragment's
         generation token into ``gens_out``; the caller captures this
         stamp BEFORE any fragment data is read (resultcache
         stamp-before-read discipline — the reverse order could stamp
@@ -1346,23 +1357,30 @@ class Executor:
             self._rc_collect_gens(f, VIEW_STANDARD, shards, gens_out)
             return ("row", fname, call.args[fname])
         if name in ("Union", "Intersect", "Difference", "Xor"):
-            return (name, *(self._rc_sig(idx, c, shards, gens_out)
-                            for c in call.children))
+            sigs = [self._rc_sig(idx, c, shards, gens_out, moved)
+                    for c in call.children]
+            # Difference is its first operand minus all the others
+            keep = name == "Difference"
+            rest = sigs[keep:]
+            tail = sorted(rest, key=repr)
+            if tail != rest:
+                moved.append(name)
+            return (name, *sigs[:keep], *tail)
         if name == "Not":
             ef = idx.existence_field()
             self._rc_collect_gens(ef, VIEW_STANDARD, shards, gens_out)
             return ("not", ef.name,
                     self._rc_sig(idx, call.children[0], shards,
-                                 gens_out))
+                                 gens_out, moved))
         if name == "Shift":
             n = call.int_arg("n")
             return ("shift", 1 if n is None else n,
                     self._rc_sig(idx, call.children[0], shards,
-                                 gens_out))
+                                 gens_out, moved))
         raise ExecutionError(f"uncacheable call: {name}")
 
     def _rc_probe(self, idx, kind: str, shards: tuple[int, ...],
-                  opt: ExecOptions | None, tree: Call | None = None,
+                  opt: ExecOptions | None, span, tree: Call | None = None,
                   extra=None, gen_fields=()):
         """(cache, key, gens) for one fused read, or None when caching
         is off (process config or the request's ?nocache=1) or the
@@ -1371,7 +1389,9 @@ class Executor:
         (field, view_name) pairs whose fragments participate beyond
         the tree leaves (e.g. the scanned TopN matrix).  Stamps the
         key digest onto the active flight record so every record
-        carries its cacheKey, hit or miss.
+        carries its cacheKey, hit or miss.  A tree whose operands the
+        signature had to reorder counts in ``cache.reordered`` and
+        notes ``reordered`` on ``span``, the open ``cache.probe``.
 
         ``?nodelta=1`` bypasses the probe too: its contract is an
         up-front compaction and a REAL pure-base read — a cached value
@@ -1383,9 +1403,10 @@ class Executor:
                               and not (opt.cache and opt.delta)):
             return None
         gens_out: dict = {}
+        moved: list = []
         try:
             sig = (None if tree is None
-                   else self._rc_sig(idx, tree, shards, gens_out))
+                   else self._rc_sig(idx, tree, shards, gens_out, moved))
             for f, vn in gen_fields:
                 # gen_fields means a whole-matrix read (TopN refresh,
                 # GroupBy Rows scan), and those merge pending deltas
@@ -1398,6 +1419,9 @@ class Executor:
         except (ExecutionError, ValueError, KeyError, TypeError,
                 AttributeError):
             return None
+        if moved:
+            rc.note_reordered()
+            span.note(reordered=1)
         # the active placement flavor joins the key (PR 12 follow-up):
         # a [mesh] toggle or axis resize must not serve fills staged
         # under the previous device layout — and when the operator
@@ -1411,9 +1435,12 @@ class Executor:
         rec = _observe.current()
         if rec is not None:
             rec.cache_key = resultcache.key_digest(key)
-        # dict values in traversal (insertion) order — deterministic
-        # per shape, so fill and probe stamps always align slot-wise
-        return rc, key, tuple(gens_out.values())
+        # one stamp a (field, view), in the order of the memo's keys
+        # and NOT in the order the leaves were met: two written orders
+        # of one tree share a key, and a stamp that followed the
+        # traversal would read the other order's fill as invalidated.
+        # The keys are unique, so the sort never compares a stamp
+        return rc, key, tuple([gens_out[k] for k in sorted(gens_out)])
 
     @staticmethod
     def _rc_mark_hit() -> None:
@@ -1429,7 +1456,8 @@ class Executor:
         -> ``(hit, value, probe)``; ``probe`` is None with caching off
         and otherwise what :meth:`_rc_put` fills."""
         with _observe.span("cache.probe") as sp:
-            probe = self._rc_probe(idx, kind, shards, opt, **probe_kw)
+            probe = self._rc_probe(idx, kind, shards, opt, sp,
+                                   **probe_kw)
             if probe is None:
                 return False, None, None
             rc, key, gens = probe

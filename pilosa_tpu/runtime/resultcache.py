@@ -72,7 +72,7 @@ are never consulted — byte-identical behavior, regression-pinned.
 Surface: ``[cache]`` config (budget bytes, max entry bytes, ttl,
 enabled), ``?nocache=1`` on the query route (symmetric with
 ``?nocoalesce``), ``cached``/``cacheKey`` on every flight record,
-``cache.{hits,misses,fills,evictions,invalidations,bytes,
+``cache.{hits,reordered,misses,fills,evictions,invalidations,bytes,
 flight_joins,flight_served}`` gauge families on /metrics, per-tenant
 bytes/hit-rates on ``GET /debug/tenants``, and
 ``GET /debug/resultcache``.
@@ -211,6 +211,9 @@ class ResultCache:
         self.skipped_oversize = 0
         self.flight_joins = 0
         self.flight_served = 0
+        #: probes whose tree was written in another operand order than
+        #: its key's (Executor._rc_sig)
+        self.reordered = 0
         # ---------------- per-tenant accounting ([tenants]) --------
         # tenant -> live bytes; tenant -> ordered key set (per-tenant
         # LRU, mirroring the global order's move-to-end); tenant ->
@@ -447,6 +450,10 @@ class ResultCache:
                     self._tc_locked(ve.tenant)[3] += 1
             return True
 
+    def note_reordered(self) -> None:
+        with self._lock:
+            self.reordered += 1
+
     def _resolve_flight_locked(self, key: Any) -> None:
         fl = self._flights.pop(key, None)
         if fl is not None:
@@ -497,6 +504,7 @@ class ResultCache:
                 "flightJoins": self.flight_joins,
                 "flightServed": self.flight_served,
                 "flightsOpen": len(self._flights),
+                "reordered": self.reordered,
                 "tenantPrefEvictions": self.tenant_pref_evictions,
             }
 
@@ -553,6 +561,7 @@ class ResultCache:
         devobs.publish_gauges)."""
         s = self.stats_dict()
         stats.gauge("cache.hits", s["hits"])
+        stats.gauge("cache.reordered", s["reordered"])
         stats.gauge("cache.misses", s["misses"])
         stats.gauge("cache.fills", s["fills"])
         stats.gauge("cache.evictions", s["evictions"])

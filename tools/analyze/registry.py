@@ -84,7 +84,7 @@ CLASS_LOCKS: dict[tuple, ClassLockRule] = {
             "_entries", "_flights", "_noflight", "bytes", "hits",
             "misses", "fills", "evictions", "invalidations",
             "skipped_oversize", "flight_joins", "flight_served",
-            "_tenant_bytes", "_tenant_lru", "_tenant_counters",
+            "reordered", "_tenant_bytes", "_tenant_lru", "_tenant_counters",
             "tenant_pref_evictions",
         }),
         helpers={

@@ -15,7 +15,12 @@ of the window beside the socket sends they took (`http.responses`,
 launches whose one wait for the device was the fetch of their counts:
 by route, the reads whose `launch.ready` span notes `fetched`, and over
 the window `launch.fetched` and `launch.refetched` beside
-`engine.launches` (`/debug/vars`; absent likewise).
+`engine.launches` (`/debug/vars`; absent likewise), and how often the
+result cache's key put a tree's operands in another order than the
+query wrote them: the probes whose `cache.probe` span notes
+`reordered`, and how many of them hit, beside `cache.hits`,
+`cache.reordered` and `cache.invalidations` over the window
+(`/debug/vars`; absent likewise).
 
     python3 tools/route_table.py --workload seg-dense --seed <n> \\
         --seconds 51 --trace 1
@@ -56,7 +61,7 @@ def say_table(records) -> None:
     rows: dict[str, list] = {}
     why: dict[str, int] = {}
     fetched: dict[str, int] = {}
-    fast = leaves = 0
+    fast = leaves = probes = moved = moved_hits = 0
     for r in records:
         spans = sp.of(r) if r.status == 200 and r.profile else None
         if spans is None:
@@ -68,6 +73,11 @@ def say_table(records) -> None:
             elif s["name"] == "launch.ready" and s.get("fetched"):
                 route = route_of(r.profile)
                 fetched[route] = fetched.get(route, 0) + 1
+            elif s["name"] == "cache.probe":
+                probes += 1
+                if s.get("reordered"):
+                    moved += 1
+                    moved_hits += bool(s.get("hit"))
         rows.setdefault(route_of(r.profile), []).append(
             [sp.total(spans, "http.parse"), sp.self_total(spans, "stage")]
             + [sp.total(spans, name) for name in PHASES[2:]]
@@ -93,6 +103,10 @@ def say_table(records) -> None:
         harness.say("reads whose launch.ready was the fetch: " + ", ".join(
             f"{route} {n} of {len(rows[route])}"
             for route, n in sorted(fetched.items())))
+    if moved:
+        harness.say(f"cache probes: {probes}, operands reordered for the "
+                    f"key: {moved}, of which hits: {moved_hits} of "
+                    f"{len(rows.get('cached', ()))} cached reads")
 
 
 def say_routes(records) -> None:
@@ -118,6 +132,10 @@ def measure(ses, *args):
             "engine.launches", "launch.fetched", "launch.refetched"))
         harness.say(f"launches: engine.launches +{launches} "
                     f"launch.fetched +{got} launch.refetched +{again}")
+    harness.say("cache: " + " ".join(
+        f"{k} +{after[k] - before[k]}" for k in (
+            "cache.hits", "cache.misses", "cache.reordered",
+            "cache.invalidations") if k in after))
     return out
 
 
