@@ -19,6 +19,7 @@ import numpy as np
 def _ts_iso(ts):
     return None if ts is None else ts.isoformat()
 
+from pilosa_tpu import observe as _observe
 from pilosa_tpu.models.field import FieldOptions
 from pilosa_tpu.models.index import IndexOptions
 from pilosa_tpu.shardwidth import SHARD_WIDTH
@@ -132,7 +133,6 @@ class API:
             # This check runs BEFORE the write-limit branch below, which
             # rebinds pql to a parsed Query and would otherwise make the
             # upgrade unreachable on config-launched servers.
-            from pilosa_tpu import observe as _observe
             from pilosa_tpu import tracing as _tracing
             from pilosa_tpu.parallel import spmd
 
@@ -176,42 +176,47 @@ class API:
                 return res
             if rec is not None:
                 recorder.discard(rec)
-        if self.max_writes_per_request > 0:
-            from pilosa_tpu.pql import Query, parse as _parse
+        # ``api.open``: from where the handler's last span ended (the
+        # body read) to the executor's call: the route's dispatch, this
+        # method's prologue, the parse (its child) and the options
+        text = pql if isinstance(pql, str) else None
+        with _observe.span("api.open", start_ns=_observe.since()):
+            if self.max_writes_per_request > 0:
+                from pilosa_tpu.pql import Query, parse as _parse
 
-            # the parsed Query skips the executor's re-parse, so the
-            # sentinel gate must apply here too (remote-only spellings)
-            q = pql
-            if isinstance(pql, str):
-                from pilosa_tpu import observe as _observe
-
-                with _observe.span("pql.parse"):
-                    q = _parse(pql, allow_internal=remote)
-            if isinstance(q, Query) and (
-                    q.write_call_n() > self.max_writes_per_request):
-                raise ApiError(
-                    f"too many writes in one request "
-                    f"({q.write_call_n()} > {self.max_writes_per_request})")
-            pql = q
-        opt = ExecOptions(
-            remote=remote,
-            column_attrs=column_attrs,
-            exclude_row_attrs=exclude_row_attrs,
-            exclude_columns=exclude_columns,
-            shards=None if shards is None else list(shards),
-            coalesce=coalesce,
-            cache=cache,
-            delta=delta,
-            containers=containers,
-            mesh=mesh,
-            tiers=tiers,
-            vm=vm,
-            deadline=dl,
-            partial=partial,
-            missing=set() if partial else None,
-            tenant=tenant,
-        )
-        results = self.executor.execute(index, pql, opt=opt)
+                # the parsed Query skips the executor's re-parse, so
+                # the sentinel gate must apply here too (remote-only
+                # spellings)
+                q = pql
+                if isinstance(pql, str):
+                    with _observe.span("pql.parse"):
+                        q = _parse(pql, allow_internal=remote)
+                if isinstance(q, Query) and (
+                        q.write_call_n() > self.max_writes_per_request):
+                    raise ApiError(
+                        f"too many writes in one request "
+                        f"({q.write_call_n()} > "
+                        f"{self.max_writes_per_request})")
+                pql = q
+            opt = ExecOptions(
+                remote=remote,
+                column_attrs=column_attrs,
+                exclude_row_attrs=exclude_row_attrs,
+                exclude_columns=exclude_columns,
+                shards=None if shards is None else list(shards),
+                coalesce=coalesce,
+                cache=cache,
+                delta=delta,
+                containers=containers,
+                mesh=mesh,
+                tiers=tiers,
+                vm=vm,
+                deadline=dl,
+                partial=partial,
+                missing=set() if partial else None,
+                tenant=tenant,
+            )
+        results = self.executor.execute(index, pql, opt=opt, text=text)
         if partial_meta is not None:
             miss = sorted(opt.missing or ())
             partial_meta["missingShards"] = miss

@@ -15,7 +15,14 @@ map-reduce fan-out):
   wrapped by :func:`instrument`, which detects a jit cache miss (via
   the jitted callable's ``_cache_size``, falling back to first-seen
   shape keys on jax versions without it) and times the first-lowering
-  call, keyed per (kernel, canonical operand shape).
+  call, keyed per (kernel, canonical operand shape).  Each detected
+  compile is also an EVENT on the span clock (``compile.events``, the
+  newest 256): which program and shape, when, on which thread, paid by
+  which read, how long in JAX's own phases (trace, lower, backend) and
+  what the persistent compile cache said (``hit``, ``miss`` or
+  ``off``), from ``jax.monitoring`` listeners that fire only when JAX
+  compiles.  The paying read gets a ``compile`` span, the event journal
+  a ``compile`` event.
 - **Host→device transfer bursts** — ``ops/bitmap.device_put``
   (the one staging funnel for fragment matrices, BSI planes, and field
   row stacks) reports bytes/chunks per labeled owner through
@@ -44,8 +51,75 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 
 from pilosa_tpu import observe as _observe
+
+#: compile events kept for ``/debug/devices`` (the newest)
+MAX_EVENTS = 256
+
+# what JAX said while it compiled: (thread, span clock, slot, value)
+# appended by the ``jax.monitoring`` listeners below on the compiling
+# thread.  Appends are GIL-atomic; nothing reads it but a detected
+# compile, which picks its own thread's marks inside its own interval
+_marks: deque[tuple] = deque(maxlen=1024)
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "traceMs",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerMs",
+           "/jax/core/compile/backend_compile_duration": "backendMs"}
+_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+          "/jax/compilation_cache/cache_misses": "miss"}
+_listening = False
+
+
+def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+    slot = _PHASES.get(event)
+    if slot is not None:
+        _marks.append((threading.get_ident(), time.perf_counter_ns(),
+                       slot, duration_secs * 1e3))
+
+
+def _on_event(event: str, **_kw) -> None:
+    said = _CACHE.get(event)
+    if said is not None:
+        _marks.append((threading.get_ident(), time.perf_counter_ns(),
+                       "persistent", said))
+
+
+def _listen() -> None:
+    """Register the two listeners, once a process (JAX keeps no way to
+    take one back, and they cost nothing until it compiles)."""
+    global _listening
+    with _global_lock:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def _said(t0: int, t1: int) -> dict:
+    """JAX's own account of what this thread compiled in [t0, t1]: ms
+    by phase and what the persistent cache said.  Inner functions are
+    traced inside the outer one's trace, so ``traceMs`` is the longest
+    and not the sum; a cache written to says ``miss`` whatever else
+    hit; ``off``: it was not asked, or keeps no entry this small."""
+    out = {"traceMs": 0.0, "lowerMs": 0.0, "backendMs": 0.0,
+           "persistent": "off"}
+    me = threading.get_ident()
+    for thread, at, slot, value in list(_marks):
+        if thread != me or not t0 <= at <= t1:
+            continue
+        if slot == "persistent":
+            if out[slot] != "miss":
+                out[slot] = value
+        elif slot == "traceMs":
+            out[slot] = max(out[slot], value)
+        else:
+            out[slot] += value
+    return {k: round(v, 3) if isinstance(v, float) else v
+            for k, v in out.items()}
 
 
 class _CompileStat:
@@ -75,6 +149,10 @@ class DeviceObserver:
         self._compiles: dict[str, dict[str, _CompileStat]] = {}
         self.compile_count = 0
         self.compile_ns = 0
+        # compiles the persistent cache had no entry for
+        self.compile_cold = 0
+        # the newest compiles as events on the span clock
+        self._events: deque[dict] = deque(maxlen=MAX_EVENTS)
         # transfer metering: label -> [bytes, chunks, puts]
         self._transfers: dict[str, list[int]] = {}
         self.transfer_bytes = 0
@@ -86,11 +164,24 @@ class DeviceObserver:
 
     # -------------------------------------------------------------- events
 
-    def note_compile(self, kernel: str, shape_key: str, ns: int) -> None:
+    def note_compile(self, kernel: str, shape_key: str, ns: int,
+                     end_ns: int | None = None) -> None:
         """One detected compile (cache-miss first lowering) of
-        ``kernel`` at ``shape_key``, costing ``ns`` wall time.  Also
+        ``kernel`` at ``shape_key``, costing ``ns`` wall time up to
+        ``end_ns`` on the span clock (now, when not given).  Also
         stamps the query record active on this thread, so the query
-        that PAID the compile carries it."""
+        that PAID the compile carries it: ``compiled``, and a
+        ``compile`` span under whatever span is open."""
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        start_ns = end_ns - ns
+        rec = _observe.current()
+        event = {"kernel": kernel, "shape": shape_key,
+                 "startNs": start_ns, "endNs": end_ns,
+                 "ms": round(ns / 1e6, 3),
+                 "thread": threading.get_ident(),
+                 "rid": rec.trace_id if rec is not None else None,
+                 **_said(start_ns, end_ns)}
         with self._lock:
             per_shape = self._compiles.setdefault(kernel, {})
             st = per_shape.get(shape_key)
@@ -107,9 +198,17 @@ class DeviceObserver:
             st.last_ns = ns
             self.compile_count += 1
             self.compile_ns += ns
-        rec = _observe.current()
+            self.compile_cold += event["persistent"] == "miss"
+            self._events.append(event)
         if rec is not None:
             rec.note_compile(kernel, ns)
+            rec.add_span("compile", start_ns, end_ns, kernel=kernel,
+                         persistent=event["persistent"])
+        if _observe.journal_on:
+            # after the lock: the journal takes its own
+            _observe.emit("compile", event["rid"], kernel=kernel,
+                          shape=shape_key, ms=event["ms"],
+                          persistent=event["persistent"])
         stats = self.stats
         if stats is not None:
             try:
@@ -198,6 +297,9 @@ class DeviceObserver:
                     "totalMs": round(self.compile_ns / 1e6, 3),
                     "programEvictions": _program_evictions(),
                     "kernels": kernels,
+                    # the newest compiles, oldest first; startNs and
+                    # endNs on the clock of a record's rootStartNs
+                    "events": list(self._events),
                 },
                 "transfer": {
                     "bytes": self.transfer_bytes,
@@ -229,6 +331,7 @@ class DeviceObserver:
 
         with self._lock:
             stats.gauge("compile.count", self.compile_count)
+            stats.gauge("compile.cold", self.compile_cold)
             stats.gauge("compile.total_ms",
                         round(self.compile_ns / 1e6, 3))
             # fused-program cache pressure (ops/expr._compiled): a
@@ -397,8 +500,9 @@ class _InstrumentedJit:
                 except Exception:  # noqa: BLE001
                     grew = False
                 if grew:
+                    t1 = time.perf_counter_ns()
                     obs.note_compile(self.name, _shape_key(args, kwargs),
-                                     time.perf_counter_ns() - t0)
+                                     t1 - t0, t1)
             return out
         key = _shape_key(args, kwargs)
         if key in self._seen:
@@ -406,7 +510,8 @@ class _InstrumentedJit:
         t0 = time.perf_counter_ns()
         out = self.fn(*args, **kwargs)
         self._seen.add(key)
-        obs.note_compile(self.name, key, time.perf_counter_ns() - t0)
+        t1 = time.perf_counter_ns()
+        obs.note_compile(self.name, key, t1 - t0, t1)
         return out
 
     def __getattr__(self, item):
@@ -419,6 +524,8 @@ def instrument(name: str, fn):
     timed, and recorded under ``name`` — the one hook every ``_jit_*``
     kernel (ops/bitmap.py, ops/bsi.py, the fused expression programs,
     the Pallas entry points) routes through."""
+    if not _listening:
+        _listen()
     return _InstrumentedJit(name, fn)
 
 

@@ -211,6 +211,9 @@ class _NoSpan:
     def note_engine(self) -> None:
         pass
 
+    def before(self, name: str) -> None:
+        pass
+
 
 NOSPAN = _NoSpan()
 
@@ -242,6 +245,20 @@ class _Span:
         engine = getattr(self.sink, "engine", None)
         if engine is not None:
             self.note(engine=engine)
+
+    def before(self, name: str) -> None:
+        """Name what this thread did between its last phase under this
+        span's parent (:func:`since`) and this span's start, as a
+        sibling written from those two clock reads: ``route`` before
+        ``stage``.  Called inside the ``with``; nothing is written
+        where no earlier stamp is known."""
+        sink = self.sink
+        if sink is None:
+            return
+        t0 = _since(sink, self.parent)
+        if 0 < t0 <= self.start_ns and len(sink.spans) < MAX_SPANS:
+            sink.spans.append((next(sink.ids), self.parent, name, t0,
+                               self.start_ns, _thread_id(), None))
 
     def __enter__(self):
         sink = self.sink
@@ -297,8 +314,9 @@ def span(name: str, start_ns: int = 0, timer: tuple | None = None,
     ``export`` opens the ``tracing.py`` span of that name when a
     recording tracer is installed (OTLP export), tagged with the
     counts; ``start_ns`` backdates the start to a clock read the caller
-    already took.  With no record, no timer and no recording tracer
-    this returns the shared :data:`NOSPAN` and reads no clock."""
+    already took (:func:`since` has the end of the phase before).  With
+    no record, no timer and no recording tracer this returns the shared
+    :data:`NOSPAN` and reads no clock."""
     sink = getattr(_tls, "rec", None) or getattr(_tls, "req", None)
     if export is not None and not isinstance(
             _tracing.global_tracer(), _tracing.MemTracer):
@@ -306,6 +324,26 @@ def span(name: str, start_ns: int = 0, timer: tuple | None = None,
     if sink is None and timer is None and export is None:
         return NOSPAN
     return _Span(sink, name, start_ns, timer, export, counts or None)
+
+
+def _since(sink, parent: int) -> int:
+    me = _thread_id()
+    for s in reversed(sink.spans):
+        if s[1] == parent and s[5] == me:
+            return s[4]
+    return sink.t0_ns if parent == getattr(sink, "exec_id", None) else 0
+
+
+def since() -> int:
+    """The clock read that ended this thread's last phase under the
+    span now open: the end of the newest span THIS thread wrote with
+    that parent, else the record's ``t0_ns`` when the open span is its
+    ``exec``, else 0 (unknown: a span given that reads its own clock).
+    A ``start_ns`` for a span that names the work since then at no
+    clock read (``api.open``); never earlier than the parent's start,
+    never another thread's stamp."""
+    sink = getattr(_tls, "rec", None) or getattr(_tls, "req", None)
+    return 0 if sink is None else _since(sink, getattr(_tls, "open", 0))
 
 
 def take_last() -> "QueryRecord | None":

@@ -349,8 +349,11 @@ class Coalescer:
             entry = _Entry(vmstage.shape, (), vmstage.tape, Future(),
                            deadline, mesh=None, vm=vmstage)
         else:
+            # ``route``: what this thread did since the cache's probe:
+            # eligible, which mesh, the VM's offer and its decline
             shape, leaves = executor._fused_expr(idx, child, shards,
-                                                 use_delta=use_delta)
+                                                 use_delta=use_delta,
+                                                 before="route")
             key, tp = self._bucket_key(idx, shape, shards, leaves,
                                        mesh=mesh)
             entry = _Entry(shape, leaves, tp, Future(), deadline,

@@ -298,9 +298,13 @@ class Executor:
 
     # ------------------------------------------------------------- public
 
-    def execute(self, index_name: str, query, shards=None, opt: ExecOptions | None = None):
+    def execute(self, index_name: str, query, shards=None,
+                opt: ExecOptions | None = None, text: str | None = None):
         """Execute a PQL query string or Query -> list of results
-        (reference executor.Execute, executor.go:113)."""
+        (reference executor.Execute, executor.go:113).  ``text`` is
+        the PQL a parsed ``query`` came from, where the caller has it:
+        the flight record then holds it instead of re-serialising the
+        tree."""
         opt = opt or ExecOptions()
         raw_query = query
         if isinstance(query, str):
@@ -339,9 +343,10 @@ class Executor:
         rec = None
         if self.recorder is not None and self.recorder.enabled:
             # str() on a parsed Query re-serializes the AST — only pay
-            # it when a record is actually being assembled
+            # it when a record is actually being assembled, and the
+            # caller did not hand the text over
             pql_text = (raw_query if isinstance(raw_query, str)
-                        else str(raw_query))
+                        else text or str(raw_query))
             rec = self.recorder.begin(index_name, pql_text,
                                       trace_id=tracing.active_trace_id())
         t0 = _time.perf_counter() if rec is None else 0.0
@@ -375,7 +380,10 @@ class Executor:
                 _deadline.check(opt.deadline, "translate")
                 calls = query.calls
                 if not opt.remote:
-                    with _observe.span("translate"):
+                    with _observe.span("translate") as sp:
+                        # the record's own opening, from
+                        # ``recorder.begin`` through the scopes above
+                        sp.before("exec.open")
                         calls = [self._translate_call(idx, c)
                                  for c in calls]
                 results = []
@@ -399,7 +407,11 @@ class Executor:
                             export="executor.execute" + call.name):
                         results.append(
                             self._execute_call(idx, call, shards, opt))
-                if not opt.remote:
+                # a number holds no key: a read of Counts (or a write's
+                # booleans) has nothing to translate back, and opens no
+                # span over an identity
+                if not opt.remote and any(type(res) not in (int, bool)
+                                          for res in results):
                     with _observe.span("translateResults"):
                         results = [
                             self._translate_result(idx, call, res)
@@ -1092,7 +1104,7 @@ class Executor:
                                               f.time_quantum)))
 
     def _fused_expr(self, idx, call: Call, shards: tuple[int, ...],
-                    use_delta: bool = True):
+                    use_delta: bool = True, before: str | None = None):
         """Stage a supported tree for ONE-launch evaluation: returns
         ``(shape, leaves)`` where ``shape`` is the canonical structure
         key (row ids and values erased into leaf slots — distinct rows
@@ -1103,9 +1115,15 @@ class Executor:
 
         ``use_delta=False`` is the ?nodelta=1 escape: pending delta
         planes on the touched fragments are compacted up front and
-        every leaf stays a plain base leaf."""
+        every leaf stays a plain base leaf.
+
+        ``before`` names, as a span of its own, what the caller's
+        thread did between its last phase and this staging (the
+        coalescer's ``route``)."""
         leaves: list = []
         with _observe.span("stage") as sp:
+            if before is not None:
+                sp.before(before)
             fast0 = _stagecheck.fast_leaves()
             shape = self._fused_shape(idx, call, shards, leaves,
                                       use_delta)

@@ -839,7 +839,10 @@ class Handler:
         ``Date``, ``Content-Type``, ``Content-Length``, the caller's
         ``headers``, ``Connection: close`` when the connection will
         close, and the body, in ONE send up to ``ONE_SEND_MAX`` bytes
-        of body; a larger body follows its head uncopied."""
+        of body; a larger body follows its head uncopied.  The write
+        is the ``http.send`` span, the root's last child on the record
+        the recorder's ring keeps (an inline ``?profile=1`` is
+        rendered before its own send and cannot hold it)."""
         sends = 1 if len(body) <= ONE_SEND_MAX else 2
         # counted before the send: a client that has its answer finds
         # it in the counters
@@ -850,11 +853,12 @@ class Handler:
         head = (b"" if req.request_version == "HTTP/0.9"
                 else response_head(status, ctype, len(body), headers,
                                    req.close_connection))
-        if sends == 1:
-            req.wfile.write(head + body)
-        else:
-            req.wfile.write(head)
-            req.wfile.write(body)
+        with observe.span("http.send"):
+            if sends == 1:
+                req.wfile.write(head + body)
+            else:
+                req.wfile.write(head)
+                req.wfile.write(body)
 
     def _json(self, req, obj, status: int = 200,
               headers: dict | None = None) -> None:
@@ -1085,7 +1089,11 @@ class Handler:
                 ]
             self._proto(req, proto.encode(proto.QUERY_RESPONSE, pb))
             return
-        with observe.span("serialize"):
+        with observe.span("serialize") as sp:
+            # from the executor's last clock read (the end of ``exec``)
+            # to here: the recorder's ring and latency histogram,
+            # ``API.query``'s return, the result post-processing
+            sp.before("api.close")
             resp = {"results": [serialize_result(r) for r in results]}
         if attr_sets is not None:
             resp["columnAttrs"] = attr_sets

@@ -127,6 +127,163 @@ class TestInstrument:
         assert key and snap[key[0]]["count"] == 1
 
 
+# ----------------------------------------------------------- compile events
+
+
+def _compile_one(name="t.ev", n=6):
+    """One real compile through an instrumented jit -> its event."""
+    import jax.numpy as jnp
+
+    fn = devobs.instrument(name, jax.jit(lambda a: (a * 3).sum()))
+    fn(jnp.arange(n, dtype=jnp.int32))
+    return devobs.observer().snapshot()["compile"]["events"][-1]
+
+
+def _event_on_the_span_clock():
+    devobs.reset()
+    before = observe.clock_ns()
+    ev = _compile_one()
+    after = observe.clock_ns()
+    assert before <= ev["startNs"] <= ev["endNs"] <= after
+    assert ev["ms"] == round((ev["endNs"] - ev["startNs"]) / 1e6, 3)
+    assert (ev["kernel"], ev["shape"]) == ("t.ev", "(int32[6])")
+    import threading
+
+    assert ev["thread"] == threading.get_ident() and ev["rid"] is None
+
+
+def _event_phases_and_cache_off():
+    """JAX's own account of the compile, from the listeners: each phase
+    ran, none longer than the whole, and with no cache directory (the
+    test process's) the persistent cache said nothing."""
+    devobs.reset()
+    ev = _compile_one()
+    assert ev["persistent"] == "off"
+    for phase in ("traceMs", "lowerMs", "backendMs"):
+        assert 0 < ev[phase] <= ev["ms"], (phase, ev)
+    # a warm call fires no listener and adds no event (the marks are a
+    # bounded deque: compare its newest entries, not its length)
+    import jax.numpy as jnp
+
+    def newest():
+        return list(devobs._marks)[-8:]
+
+    before = newest()
+    fn = devobs.instrument("t.warm", jax.jit(lambda a: a + 2))
+    a = jnp.arange(4, dtype=jnp.int32)
+    fn(a)
+    grown = newest()
+    assert grown != before
+    fn(a)
+    assert newest() == grown
+    assert len(devobs.observer().snapshot()["compile"]["events"]) == 2
+
+
+def _event_list_is_capped():
+    obs = devobs.reset()
+    for i in range(devobs.MAX_EVENTS + 44):
+        obs.note_compile("t.cap", f"(int32[{i}])", 1000)
+    snap = obs.snapshot()["compile"]
+    assert snap["total"] == devobs.MAX_EVENTS + 44
+    assert len(snap["events"]) == devobs.MAX_EVENTS == 256
+    assert snap["events"][-1]["shape"] == f"(int32[{299}])"
+    assert snap["events"][0]["shape"] == "(int32[44])"
+
+
+def _event_names_the_paying_record():
+    """The read that paid: ``rid`` is its trace id, and its record
+    carries a ``compile`` span under the span that was open, inside
+    it on the clock."""
+    devobs.reset()
+    rec = observe.FlightRecorder().begin("i", "Count(Row(f=1))")
+    with observe.attach(rec):
+        with observe.span("launch.dispatch") as outer:
+            ev = _compile_one("t.paid", 9)
+    assert ev["rid"] == rec.trace_id
+    spans = {s[2]: s for s in rec.spans}
+    sid, parent, _, start, end, _, counts = spans["compile"]
+    assert parent == outer.id and sid != outer.id
+    assert (start, end) == (ev["startNs"], ev["endNs"])
+    assert outer.start_ns <= start <= end <= outer.end_ns
+    assert counts == {"kernel": "t.paid", "persistent": "off"}
+    assert [d["name"] for d in rec.to_dict()["spans"]
+            if d["parent"] == outer.id] == ["compile"]
+
+
+def _event_reaches_the_journal():
+    devobs.reset()
+    journal = observe.reset_journal()
+    rec = observe.QueryRecord(8, "i", "q")
+    with observe.attach(rec):
+        ev = _compile_one("t.journal", 10)
+    (said,) = journal.events(kind="compile")
+    assert (said["kernel"], said["shape"], said["ms"],
+            said["persistent"]) == ("t.journal", "(int32[10])",
+                                    ev["ms"], "off")
+    assert journal.events(trace_id=rec.trace_id) == [said]
+    # off: the emission site stops at the module bool
+    observe.journal_on = False
+    try:
+        _compile_one("t.journal", 11)
+    finally:
+        observe.journal_on = True
+    assert len(journal.events(kind="compile")) == 1
+
+
+def _event_says_hit_or_miss(tmp_path):
+    """With a persistent cache directory the first compile of a program
+    writes its entry (``miss``, counted ``compile.cold``) and the same
+    program compiled again after the jit caches are dropped reads it
+    back (``hit``)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    obs = devobs.reset()
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        said = []
+        for _ in range(2):
+            jax.clear_caches()
+            said.append(_compile_one("t.cache", 12)["persistent"])
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert said == ["miss", "hit"]
+    assert obs.compile_cold == 1
+    stats = _stats.MemStatsClient()
+    obs.publish_gauges(stats)
+    snap = stats.snapshot()
+    assert (snap["compile.cold"], snap["compile.count"]) == (1, 2)
+
+
+COMPILE_EVENT = {
+    "on the span clock": _event_on_the_span_clock,
+    "phases, and persistent off": _event_phases_and_cache_off,
+    "capped at 256": _event_list_is_capped,
+    "rid and the compile span": _event_names_the_paying_record,
+    "journal": _event_reaches_the_journal,
+    "persistent hit or miss": _event_says_hit_or_miss,
+}
+
+
+@pytest.mark.parametrize("case", list(COMPILE_EVENT))
+def test_compile_event(case, tmp_path):
+    """A detected compile is an event on ``/debug/devices``
+    ``compile.events``: which program and shape, when on the span
+    clock, paid by which read, how long by JAX's phases, and what the
+    persistent cache said."""
+    fn = COMPILE_EVENT[case]
+    fn(tmp_path) if case.startswith("persistent") else fn()
+
+
 # ------------------------------------------------------ compile attribution
 
 
@@ -254,7 +411,8 @@ class TestDebugDevices:
         d = _get(srv.uri, "/debug/devices")
         assert d["enabled"] is True
         assert set(d["compile"]) == {"total", "totalMs", "kernels",
-                                     "programEvictions"}
+                                     "programEvictions", "events"}
+        assert len(d["compile"]["events"]) == d["compile"]["total"]
         for k in d["compile"]["kernels"].values():
             assert k["compiles"] >= 1 and "shapes" in k
         assert d["transfer"]["bytes"] > 0

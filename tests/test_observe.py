@@ -19,7 +19,8 @@ from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.parallel.executor import Executor
 from pilosa_tpu.server.server import Server
 from pilosa_tpu.shardwidth import SHARD_WIDTH
-from tests.coalesce_batch import map_behind_launch, run_behind_launch
+from tests.coalesce_batch import (map_behind_launch, run_behind_launch,
+                                   wait_until)
 
 
 def _post(uri, path, obj=None):
@@ -73,10 +74,30 @@ class TestRecorder:
         names = [s["name"] for s in d["stages"]]
         assert "translate" in names
         assert "execute.Count" in names
-        assert "translateResults" in names
+        # a Count holds no key: nothing to translate back, no stage
+        # (PR 43); a Row could, so its read has one
+        assert "translateResults" not in names
+        ex.execute("i", "Row(f=7)")
+        assert "translateResults" in [
+            s["name"] for s in
+            ex.recorder.recent_records()[-1].to_dict()["stages"]]
         # single-node host mode: the fused all-shard path
         assert d["path"] == "fused"
         assert any(s["name"] == "map.fused" for s in d["stages"])
+
+    def test_record_holds_the_text_its_caller_had(self, ex, monkeypatch):
+        """``API.query`` parses (the write limit) and hands the
+        executor the tree AND the text it came from: the record holds
+        that text and the tree is not serialised again for it (PR 43:
+        the gap table's ``pql.parse -> exec`` held that)."""
+        from pilosa_tpu.pql import Query, parse
+
+        q = parse("Count(Row(f=7))")
+        ex.execute("i", q)
+        assert ex.recorder.recent_records()[-1].pql == "Count(Row(f=7))"
+        monkeypatch.setattr(Query, "__str__", lambda self: 1 / 0)
+        ex.execute("i", q, text="Count( Row(f=7) )")
+        assert ex.recorder.recent_records()[-1].pql == "Count( Row(f=7) )"
 
     def test_per_shard_timings_on_unfused_path(self, ex):
         ex.fuse_shards = False
@@ -265,10 +286,44 @@ class TestHTTPSurface:
         assert prof["shards"] == 3
         assert prof["deviceLaunches"] >= 1
         assert {s["name"] for s in prof["stages"]} >= {
-            "translate", "execute.Count", "translateResults"}
+            "translate", "execute.Count"}
         # no profile key without the param
         r = _post(srv.uri, "/index/i/query", {"query": "Count(Row(f=9))"})
         assert "profile" not in r
+
+    @pytest.mark.parametrize("where", ["ring", "inline"])
+    def test_http_send_is_the_roots_last_child(self, srv, where):
+        """Open question (e), PR 43: ``Handler._respond`` times its
+        write.  The record in the recorder's ring holds ``http.send``
+        as the root's last child and its root ends after the send (it
+        did before PR 43 too: ``observe.Request.__exit__`` runs after
+        the route's handler has written; only the child is new).  An
+        inline ``?profile=1`` is rendered before its own send: its
+        root stays ``open`` and holds no ``http.send``; the ring's
+        copy of the same read does."""
+        r = _post(srv.uri, "/index/i/query?profile=1",
+                  {"query": "Count(Row(f=9))"})
+        if where == "inline":
+            spans = r["profile"]["spans"]
+            [root] = [s for s in spans if not s["parent"]]
+            assert root["open"] is True
+            assert "http.send" not in {s["name"] for s in spans}
+            return
+        recorder = srv.node.executor.recorder
+        # the handler closes the root after the client has its answer
+        assert wait_until(lambda: any(
+            s[2] == "http.request"
+            for s in recorder.recent_records()[-1].spans), timeout=10)
+        ring = _get(srv.uri, "/debug/queries")["recent"]
+        [d] = [d for d in ring if d["traceID"] == r["profile"]["traceID"]]
+        [root] = [s for s in d["spans"] if not s["parent"]]
+        assert root["name"] == "http.request" and "open" not in root
+        last = max((s for s in d["spans"] if s["parent"] == root["id"]),
+                   key=lambda s: s["endNs"])
+        assert last["name"] == "http.send"
+        assert last["startNs"] < last["endNs"] <= root["endNs"]
+        by_name = {s["name"]: s for s in d["spans"]}
+        assert by_name["serialize"]["endNs"] <= last["startNs"]
 
     def test_debug_queries_roundtrip(self, srv):
         for _ in range(2):
@@ -602,6 +657,96 @@ class TestSpans:
         assert sp.id == 0 and not reads and not built
         assert observe.open_span() == 0
 
+    @pytest.mark.parametrize("sink", ["request", "record", "none"])
+    def test_before_names_the_glue_at_no_clock_read(self, monkeypatch,
+                                                    sink):
+        """``sp.before(name)`` inside a span: what this thread did
+        since its last phase under the same parent (``observe.since``;
+        the first child of ``exec``: since the record opened) is a
+        span of its own, a sibling up to this span's start, written
+        from clock reads already taken."""
+        real, reads = observe.clock_ns, []
+        monkeypatch.setattr(observe, "clock_ns",
+                            lambda: reads.append(1) or real())
+        if sink == "none":
+            sp = observe.span("serialize")
+            assert sp is observe.NOSPAN and observe.since() == 0
+            with sp:
+                sp.before("api.close")
+            assert not reads
+            return
+        with observe.Request() as rq:
+            with observe.span("http.read") as rd:
+                pass
+            n = len(reads)
+            if sink == "request":
+                assert observe.since() == rd.end_ns
+                with observe.span("serialize") as ps:
+                    ps.before("api.close")
+                assert len(reads) - n == 2  # the span's own two
+                spans = {s[2]: s for s in rq.spans}
+                want = (rd.end_ns, ps.start_ns)
+                glue, nxt = spans["api.close"], spans["serialize"]
+            else:
+                rec = observe.FlightRecorder().begin("i", "q")
+                with observe.attach(rec):
+                    n = len(reads)
+                    assert observe.since() == rec.t0_ns
+                    with observe.span("translate") as tr:
+                        tr.before("exec.open")
+                    assert len(reads) - n == 2
+                assert rec.spans is rq.spans  # adopted: one tree
+                spans = {s[2]: s for s in rec.spans}
+                # the last span written ended before ``exec`` opened:
+                # it is no sibling, and the gap starts with ``exec``
+                assert rd.end_ns < rec.t0_ns
+                want = (rec.t0_ns, tr.start_ns)
+                glue, nxt = spans["exec.open"], spans["translate"]
+        assert (glue[3], glue[4]) == want and glue[3] <= glue[4]
+        assert glue[1] == nxt[1] and glue[0] != nxt[0]  # siblings
+        assert glue[6] is None
+
+    @pytest.mark.parametrize("case", ["no_sibling", "other_thread",
+                                      "deeper", "sibling"])
+    def test_a_gap_span_never_starts_before_its_parent(self, case):
+        """``before`` takes its start from this thread's last span
+        under the SAME parent: a span written deeper, under another
+        parent or by another thread is not it, and with no such stamp
+        nothing is written."""
+        rec = observe.FlightRecorder().begin("i", "q")
+        with observe.attach(rec):
+            with observe.span("cache.probe"):
+                pass  # under ``exec``: no sibling of what follows
+            with observe.span("call.Count") as parent:
+                if case == "other_thread":
+                    t = threading.Thread(target=lambda: rec.add_span(
+                        "launch", rec.t0_ns, rec.t0_ns + 1,
+                        parent=parent.id))
+                    t.start()
+                    t.join()
+                elif case == "deeper":
+                    with observe.span("plan"):
+                        with observe.span("plan.inner"):
+                            pass
+                    with observe.span("map.fused"):
+                        # ``plan.inner`` was written last but lies
+                        # under ``plan``; ``map.fused`` has no child yet
+                        with observe.span("stage") as st:
+                            st.before("route")
+                elif case == "sibling":
+                    with observe.span("plan") as plan:
+                        pass
+                if case != "deeper":
+                    with observe.span("stage") as st:
+                        st.before("route")
+        spans = {s[2]: s for s in rec.spans}
+        if case == "sibling":
+            assert spans["route"][3:5] == (plan.end_ns, st.start_ns)
+            assert spans["route"][1] == parent.id
+            assert spans["route"][3] >= parent.start_ns
+        else:
+            assert "route" not in spans
+
     def test_ids_parents_and_one_trace(self, ex):
         ex.execute("i", "Count(Row(f=7))")
         rec = ex.recorder.recent_records()[-1]
@@ -652,7 +797,11 @@ class TestSpans:
         by_name = {s["name"]: s for s in d["spans"]}
         stages = {s["name"]: s["ms"] for s in d["stages"]}
         assert list(stages) == ["translate", "map.fused",
-                                "execute.Count", "translateResults"]
+                                "execute.Count"]
+        # a result that can hold a key has the fourth stage
+        ex.execute("i", "Row(f=7)")
+        assert [s["name"] for s in ex.recorder.recent_records()[-1]
+                .to_dict()["stages"]][-1] == "translateResults"
         call = by_name["call.Count"]
         assert stages["execute.Count"] == round(
             (call["endNs"] - call["startNs"]) / 1e6, 3)
@@ -774,10 +923,12 @@ class TestSpans:
 #: submit 1 (coalesce.wait starts there) and its end 1, the flush 1
 #: (launch starts there) and its end 1, launch.dispatch 2, launch.ready
 #: 1 (it starts where dispatch ended), the end of reduce 1 (it starts
-#: where launch ended), cache.fill 2, translateResults 2.  Behind the
-#: handler: admission.wait, http.read and serialize, 2 each, so 34; the
-#: parent commit read the clock 19 times on this path.
-CLOCK_READS_PER_COALESCED_COUNT = 28
+#: where launch ended), cache.fill 2; translateResults 2 until PR 43
+#: (a Count holds no key, so none is opened: 28 then).  ``exec.open``
+#: and ``route`` (PR 43) read no clock.  Behind the handler:
+#: admission.wait, http.read and serialize, 2 each, api.open 1 and
+#: http.send 2; the commit before PR 24 read the clock 19 times here.
+CLOCK_READS_PER_COALESCED_COUNT = 26
 
 
 @pytest.mark.parametrize("pql,kind", [

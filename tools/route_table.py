@@ -22,6 +22,23 @@ query wrote them: the probes whose `cache.probe` span notes
 `cache.reordered` and `cache.invalidations` over the window
 (`/debug/vars`; absent likewise).
 
+Then the gap table (`perfbench/gaps.py`): by route, every piece of the
+root that only an envelope covers, keyed (envelope, the span that ended
+before it or `start`, the span that opens after it or `end`), with the
+median ms over the reads that have the piece and the share of the
+route's reads that do, largest first, under the route's median
+`unattributed_ms`.  And what the program records of a compile
+(`perfbench/compiles.py`; absent on a program that predates the
+events): per compile event of the window its kernel, shape key, what
+the persistent cache said, its ms by phase, and the reads that stood
+behind it by the span they waited in; and from the recorder's ring
+(`/debug/queries`, the newest 256 records, read after the window) the
+median `http.send`, which a read's inline profile cannot hold.  Last,
+the four per-layer metrics whose readers and metric files stand under
+`perfbench/` with no entry in `BENCHMARK.json` yet (`WAITING`; `PERF.md`
+section 7 t says what keeps them out): `run.py` reads only what the
+manifest lists, so this prints what they read in the window.
+
     python3 tools/route_table.py --workload seg-dense --seed <n> \\
         --seconds 51 --trace 1
 
@@ -31,6 +48,7 @@ run's other lines."""
 
 from __future__ import annotations
 
+import importlib
 import os
 import statistics
 import sys
@@ -39,8 +57,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from perfbench import compiles, gaps  # noqa: E402
 from perfbench import run as harness  # noqa: E402
 from perfbench import spans as sp  # noqa: E402
+from perfbench.capture import Capture  # noqa: E402
+
+#: readers of `perfbench/readers/` that `BENCHMARK.json` does not list
+WAITING = ("unattributed_ms", "compile_stall_ms", "compile_cold_in_window",
+           "compile_blocked_reads")
 
 PHASES = ("http.parse", "stage", "coalesce.wait", "launch", "launch.stack",
           "launch.dispatch", "launch.ready", "reduce")
@@ -109,9 +133,82 @@ def say_table(records) -> None:
                     f"{len(rows.get('cached', ()))} cached reads")
 
 
+#: a piece of the gap table is printed from this median (ms) up, or
+#: when it is a tenth of its route's unattributed time
+GAP_FLOOR_MS = 0.005
+
+
+def say_gaps(records) -> None:
+    by_route: dict[str, list[dict]] = {}
+    for r in records:
+        spans = sp.of(r) if r.status == 200 and r.profile else None
+        if spans is not None:
+            by_route.setdefault(route_of(r.profile), []).append(
+                gaps.pieces(spans))
+    harness.say("gap table: envelope: before -> after | median ms of the "
+                "reads that have it | share of the route's reads")
+    for route, reads in sorted(by_route.items(), key=lambda kv: -len(kv[1])):
+        whole = statistics.median(sum(p.values()) for p in reads)
+        harness.say(f"  {route}: {len(reads)} reads, unattributed_ms "
+                    f"median {whole:.4f}")
+        keys: dict[tuple, list[float]] = {}
+        for p in reads:
+            for key, ms in p.items():
+                keys.setdefault(key, []).append(ms)
+        rows = sorted(((statistics.median(v), len(v) / len(reads), k)
+                       for k, v in keys.items()),
+                      key=lambda row: -row[0] * row[1])
+        for med, share, (env, before, after) in rows:
+            if med >= min(GAP_FLOOR_MS, whole / 10):
+                harness.say(f"    {env}: {before} -> {after} | {med:.4f} | "
+                            f"{100 * share:.1f}%")
+
+
+def say_compiles(cap: Capture) -> None:
+    events = compiles.in_window(cap)
+    if events is None:
+        return
+    stood = compiles.blocked(cap.profiled(), events)
+    harness.say(f"compile events in the window: {len(events)}, union "
+                f"{compiles.stall_ms(events):.1f} ms, reads blocked: "
+                f"{compiles.reads(stood)}")
+    for e in events:
+        where: dict[str, int] = {}
+        for ev, _, name in stood:
+            if ev is e:
+                where[name] = where.get(name, 0) + 1
+        harness.say(
+            f"  {e['kernel']} {e['shape']}: persistent={e['persistent']} "
+            f"{e['ms']:.1f} ms (trace {e['traceMs']:.1f}, lower "
+            f"{e['lowerMs']:.1f}, backend {e['backendMs']:.1f}), paid by "
+            f"{e['rid']}; blocked: " + (", ".join(
+                f"{n} in {name}" for name, n in sorted(
+                    where.items(), key=lambda kv: -kv[1])) or "none"))
+
+
+def say_waiting(cap: Capture) -> None:
+    listed = {p["name"] for p in harness.check_manifest.load()["per_layer"]}
+    for name in WAITING:
+        if name not in listed:
+            value = importlib.import_module(
+                "perfbench.readers." + name).read(cap)
+            if value is not None:
+                harness.say(f"metric not in the manifest: {name} {value}")
+
+
+def say_sends(ring: dict) -> None:
+    sends = [(s["endNs"] - s["startNs"]) / 1e6
+             for d in ring.get("recent", []) for s in d.get("spans", ())
+             if s["name"] == "http.send"]
+    if sends:
+        harness.say(f"http.send, the ring's newest {len(sends)} records: "
+                    f"median {statistics.median(sends):.4f} ms")
+
+
 def say_routes(records) -> None:
     _say_routes(records)
     say_table(records)
+    say_gaps(records)
 
 
 def measure(ses, *args):
@@ -122,6 +219,12 @@ def measure(ses, *args):
     before = ses.srv.call("GET", "/debug/vars")
     out = _measure(ses, *args)
     after = ses.srv.call("GET", "/debug/vars")
+    say_sends(ses.srv.call("GET", "/debug/queries"))
+    cap = Capture(records=out[0].records, queries=[], meta={},
+                  devices_before=out[1], devices_after=out[2],
+                  device_kind="", peaks={})
+    say_compiles(cap)
+    say_waiting(cap)
     if "http.responses" in after:
         responses, sends = (after[k] - before[k]
                             for k in ("http.responses", "http.sends"))
