@@ -27,7 +27,7 @@ Cache discipline (the point of the whole subsystem):
 - ``Fragment._delta_seq`` — monotone delta sequence, bumped on every
   delta-landing write, NEVER reset (compaction leaves it alone).  The
   result cache stamps extend to ``(base_gen, delta_seq)``
-  (``Executor._rc_collect_gens``), so a cached entry stays valid until
+  (``Executor._rc_view_stamp``), so a cached entry stays valid until
   *its* fragment's delta actually changes, and a compaction refill is
   one recompute against the already-resident base — not an eviction
   storm across every read path.

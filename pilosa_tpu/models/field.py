@@ -505,6 +505,65 @@ class Field:
         return self._place_and_cache_stack(key, gens, stack, stamp,
                                            t0_ns=t_build)
 
+    def stage_rows(self, row_ids: list, shards: tuple[int, ...],
+                   use_delta: bool = True) -> list[tuple]:
+        """The standard-view rows one read stages on this field, in
+        order -> ``[(base stack, delta pair or None), ...]``: what
+        ``device_delta_stacks`` then ``device_row_stack`` give row by
+        row, with the bookkeeping of the rows the view's write token
+        proves good done ONCE for all of them.  The token is read
+        first, before every lookup (stagecheck.py).  A row whose "no
+        overlay pending" is remembered under that token AND whose base
+        entry is stamped with it is good as of that one read of the
+        token: one take of this field's lock looks all such rows up,
+        and inside it one take of the residency manager's advances the
+        LRU, row by row in the order given; one take of the access
+        table's notes the accesses, one of the tally's the leaves.
+        Every other row (first read, a write to its view since, an
+        overlay pending, a dropped buffer) goes the builders' own way,
+        alone: the overlay first and the token read again before the
+        base, so a compaction racing the two can only re-apply the
+        overlay, never drop it.
+
+        ``use_delta=False`` (?nodelta=1): the touched fragments'
+        pending deltas are compacted up front and no overlay is looked
+        for."""
+        if not use_delta:
+            self.flush_deltas(shards)
+        stamp = (_stagecheck.view_token(self.view(VIEW_STANDARD)),
+                 _placement_token())
+        cache = self._row_stack_cache
+        no_delta = self._no_delta
+        out: list = [None] * len(row_ids)
+        ask = [(i, (row_id, shards)) for i, row_id in enumerate(row_ids)
+               if not use_delta
+               or no_delta.get(("delta", row_id, shards)) == stamp]
+        good: list = []
+        if ask:
+            with self._lock:
+                for i, key in ask:
+                    hit = cache.get(key)
+                    if (hit is not None and hit[2][0] == stamp
+                            and _live(hit[1])):
+                        out[i] = (hit[1], None)
+                        good.append(key)
+                if good:
+                    residency.manager().touch_many(cache, good)
+        if good:
+            cid = id(cache)
+            _observe.note_accesses([(cid, key) for key in good])
+            _stagecheck.leaves_fast(len(good))
+            self._note_tier("hbm", times=len(good))
+        if len(good) < len(row_ids):
+            for i, row_id in enumerate(row_ids):
+                if out[i] is None:
+                    mark = _stagecheck.mark()
+                    ds = (self.device_delta_stacks(row_id, shards)
+                          if use_delta else None)
+                    out[i] = (self.device_row_stack(row_id, shards), ds)
+                    _stagecheck.leaf_done(mark)
+        return out
+
     def _stamped_hit(self, key, stamp, live=_live, tier: bool = True):
         """Step one of validating a cached stack (stagecheck.py):
         ``stamp`` holds the write token of every view the entry was
@@ -552,16 +611,16 @@ class Field:
         residency.manager().touch(cache, key)
 
     @staticmethod
-    def _note_tier(outcome: str, ns: int = 0) -> None:
+    def _note_tier(outcome: str, ns: int = 0, times: int = 1) -> None:
         """Stamp one tiered stack access (hbm | promoted | fallback |
-        cold) onto the active flight record — the stall-vs-hit split
-        ?profile=1 and /debug/queries carry.  Silent under ?notiers
-        (the escape's profile must look pre-tier too)."""
+        cold; ``times`` of them) onto the active flight record — the
+        stall-vs-hit split ?profile=1 and /debug/queries carry.  Silent
+        under ?notiers (the escape's profile must look pre-tier too)."""
         if not residency.tiers_enabled():
             return
         rec = _observe.current()
         if rec is not None:
-            rec.note_tier(outcome, ns)
+            rec.note_tier(outcome, ns, times)
 
     @staticmethod
     def _note_access(cache: dict, key) -> None:

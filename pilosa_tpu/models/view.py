@@ -78,6 +78,10 @@ class View:
         #: every change of the map below; cached stacks are stamped
         #: with it (stagecheck.py)
         self.write_token = stagecheck.next_token()
+        #: shard set -> (write token, the result cache's aggregate
+        #: stamp of this view over that set), as last walked
+        #: (Executor._rc_view_stamp): good while the token stands
+        self.rc_stamps: dict[tuple, tuple] = {}
         self.fragments: dict[int, Fragment] = _FragmentMap(self)
         # guards fragment CREATION/DELETION only; reads stay lock-free
         # (GIL-atomic dict gets, the double-checked pattern)

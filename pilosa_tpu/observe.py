@@ -379,17 +379,19 @@ class AccessStats:
         # eid -> [score, last_monotonic]; insertion order = LRU
         self._scores: dict = {}
 
-    def note(self, eid) -> None:
+    def note(self, *eids) -> None:
         now = time.monotonic()
+        scores = self._scores
         with self._lock:
-            rec = self._scores.pop(eid, None)
-            if rec is None:
-                rec = [0.0, now]
-                if len(self._scores) >= self.MAX_ENTRIES:
-                    self._scores.pop(next(iter(self._scores)))
-            score, last = rec
-            score *= 0.5 ** ((now - last) / self.HALF_LIFE_S)
-            self._scores[eid] = [score + 1.0, now]
+            for eid in eids:
+                rec = scores.pop(eid, None)
+                if rec is None:
+                    rec = [0.0, now]
+                    if len(scores) >= self.MAX_ENTRIES:
+                        scores.pop(next(iter(scores)))
+                score, last = rec
+                score *= 0.5 ** ((now - last) / self.HALF_LIFE_S)
+                scores[eid] = [score + 1.0, now]
 
     def score(self, eid) -> float:
         now = time.monotonic()
@@ -410,6 +412,11 @@ def access_stats() -> AccessStats:
 
 def note_access(eid) -> None:
     _access.note(eid)
+
+
+def note_accesses(eids: list) -> None:
+    """One read's stack accesses, under one take of the table's lock."""
+    _access.note(*eids)
 
 
 def result_size(res) -> int:
@@ -576,7 +583,7 @@ class QueryRecord:
 
     def note_delta(self, n: int = 1) -> None:
         """``n`` fused leaves staged with a pending delta overlay
-        (Executor._fused_row_leaf) — list append, GIL-atomic."""
+        (Executor._stage_leaves) — list append, GIL-atomic."""
         if len(self.delta_notes) < MAX_SHARD_TIMINGS:
             self.delta_notes.append(n)
 
@@ -600,13 +607,15 @@ class QueryRecord:
         attributed to the engine that actually produced the result."""
         self.engine = engine
 
-    def note_tier(self, outcome: str, ns: int = 0) -> None:
-        """One tiered stack access: ``hbm`` | ``promoted`` |
-        ``fallback`` | ``cold``, with the wall time the access cost
-        this query (the promotion wait / rebuild — the stall side of
-        stall-vs-hit).  List append, GIL-atomic."""
-        if len(self.tier_notes) < MAX_SHARD_TIMINGS:
-            self.tier_notes.append((outcome, ns))
+    def note_tier(self, outcome: str, ns: int = 0,
+                  times: int = 1) -> None:
+        """One tiered stack access (``times`` alike): ``hbm`` |
+        ``promoted`` | ``fallback`` | ``cold``, with the wall time the
+        access cost this query (the promotion wait / rebuild — the
+        stall side of stall-vs-hit).  List append, GIL-atomic."""
+        room = MAX_SHARD_TIMINGS - len(self.tier_notes)
+        if room > 0:
+            self.tier_notes.extend([(outcome, ns)] * min(times, room))
 
     def note_missing(self, shard: int) -> None:
         """One shard accounted unavailable (partial degradation or a
@@ -703,7 +712,9 @@ class QueryRecord:
             "cached": self.cached and not self.launches,
         }
         if self.cache_key is not None:
-            d["cacheKey"] = self.cache_key
+            # the probe left the key itself; its digest is drawn when
+            # somebody reads the record
+            d["cacheKey"] = self.cache_key.digest()
         if self.tenant is not None:
             d["tenant"] = self.tenant
         # streaming-ingest annotations: present only when the query

@@ -715,7 +715,7 @@ class VMStage:
         self.pad = pad
 
 
-def stage_vm(idx: Any, call: Any, shards: tuple,
+def stage_vm(tree: Any, shards: tuple,
              use_delta: bool = True, max_tape: int | None = None,
              max_leaves: int | None = None,
              min_domain: int = VM_MIN_DOMAIN,
@@ -733,11 +733,10 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
     if not _cfg.enabled or not shards:
         _tp.bump("vm.fallbacks.disabled")
         return None
-    leaf_descs: list = []
-    shape = _walk(idx, call, leaf_descs)
-    if shape is None or not leaf_descs:
+    if not tree.plain:
         _tp.bump("vm.fallbacks.ineligible_leaf")
         return None
+    shape, leaf_descs = tree.shape, tree.rows()
     nodemap: dict = {}
     leaves: list[ContainerLeaf] = []
     for i, (f, row_id) in enumerate(leaf_descs):
@@ -774,7 +773,7 @@ def stage_vm(idx: Any, call: Any, shards: tuple,
             nodemap[i] = ("dfuse", ("leaf", bi), ("leaf", si),
                           ("leaf", ci))
             # same flight-record note as the dense delta fuse
-            # (executor._fused_row_leaf): this read met a pending delta
+            # (executor._stage_leaves): this read met a pending delta
             rec = _observe.current()
             if rec is not None:
                 rec.note_delta(1)
@@ -1040,105 +1039,33 @@ def _megapool_kinds(order: list) -> tuple:
     return (MegaPools(bpool, apool, acard, rpool), bases, zero_index)
 
 
-def _row_leaf(idx: Any, call: Any) -> tuple | None:
-    """``(field, row id)`` of a ``Row`` call that names one plain
-    standard-view row, else None: BSI conditions, time ranges, keys
-    and bool literals, unknown or int fields."""
-    if call.condition_arg() is not None:
-        return None
-    if "from" in call.args or "to" in call.args:
-        return None
-    try:
-        fname = call.field_arg()
-    except ValueError:
-        return None
-    row_id = call.args.get(fname)
-    if not isinstance(row_id, int) or isinstance(row_id, bool):
-        return None
-    f = idx.field(fname)
-    if f is None:
-        return None
-    o = f.options
-    if o.type == "int" or (o.type == "time" and o.no_standard_view):
-        return None
-    return f, row_id
-
-
-def kept_dense(idx: Any, call: Any, shards: tuple) -> bool:
-    """Whether the tree is KNOWN to hold a leaf row that is kept dense
-    in some shard (``Field.row_kept_dense``: a verdict a staged leaf
-    left under the view's write token).  ``stage_vm`` and
-    ``plan_fused`` are all-or-nothing, so such a tree is certain to be
-    declined and nothing need be staged to learn it: a dense read
-    stages its leaves once, for the engine that will run it.  Stops at
-    the first such leaf.  False = not known, or the engine is off:
-    the caller offers the tree and the offer accounts for itself."""
+def kept_dense(tree: Any, shards: tuple) -> bool:
+    """Whether the tree (a ``parallel/prepared.py`` Prepared) is KNOWN
+    to hold a leaf row that is kept dense in some shard
+    (``Field.row_kept_dense``: a verdict a staged leaf left under the
+    view's write token).  ``stage_vm`` and ``plan_fused`` are
+    all-or-nothing, so such a tree is certain to be declined and
+    nothing need be staged to learn it: a dense read stages its leaves
+    once, for the engine that will run it.  Stops at the first such
+    leaf.  False = not known, or the engine is off: the caller offers
+    the tree and the offer accounts for itself."""
     if not _cfg.enabled or not shards:
         return False
-    return _any_kept_dense(idx, call, shards)
+    return any(f.row_kept_dense(row_id, shards)
+               for f, row_id in tree.probe_rows())
 
 
-def _any_kept_dense(idx: Any, call: Any, shards: tuple) -> bool:
-    name = call.name
-    if name == "Row":
-        leaf = _row_leaf(idx, call)
-        return leaf is not None and leaf[0].row_kept_dense(leaf[1],
-                                                           shards)
-    if name == "Not":
-        ef = idx.existence_field()
-        if ef is not None and ef.row_kept_dense(0, shards):
-            return True
-    elif name not in ("Union", "Intersect", "Difference", "Xor"):
-        return False
-    return any(_any_kept_dense(idx, c, shards) for c in call.children)
-
-
-def _walk(idx: Any, call: Any, leaves: list) -> tuple | None:
-    """Shape + (field, row) leaf descriptors for a tree whose every
-    leaf is a plain standard-view row — the container-eligible grammar.
-    Returns None for BSI condition rows, time ranges, Shift (bits cross
-    container boundaries), and anything unknown."""
-    name = call.name
-    if name == "Row":
-        leaf = _row_leaf(idx, call)
-        if leaf is None:
-            return None
-        leaves.append(leaf)
-        return ("leaf", len(leaves) - 1)
-    if name in ("Union", "Intersect", "Difference", "Xor"):
-        op = {"Union": "or", "Intersect": "and",
-              "Difference": "andnot", "Xor": "xor"}[name]
-        kids = []
-        for c in call.children:
-            k = _walk(idx, c, leaves)
-            if k is None:
-                return None
-            kids.append(k)
-        if not kids:
-            return None
-        return (op, *kids)
-    if name == "Not":
-        if len(call.children) != 1:
-            return None
-        ef = idx.existence_field()
-        if ef is None:
-            return None
-        leaves.append((ef, 0))
-        exist = ("leaf", len(leaves) - 1)
-        child = _walk(idx, call.children[0], leaves)
-        if child is None:
-            return None
-        return ("not", exist, child)
-    return None
-
-
-def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
-               opt: Any, counts: bool = True) -> Plan | None:
+def plan_fused(tree: Any, shards: tuple, opt: Any,
+               counts: bool = True) -> Plan | None:
     """Stage a fused read for compressed execution, or None to route
     the exact pre-existing dense path.  All-or-nothing per query: every
     leaf row must be compression-eligible (under the fill-ratio
     threshold, no pending delta overlay) in EVERY shard — so the read
     costs one launch on either route and partial results never mix.
+    ``tree`` is the read's Prepared (parallel/prepared.py): the
+    container-eligible grammar is its ``plain`` (every leaf a plain
+    standard-view row; no BSI condition row, time range or Shift, whose
+    bits cross container boundaries), its shape and rows what is staged.
 
     ``counts`` is the root kind: a bare-leaf Row tree is declined when
     ``counts=False`` because the dense path answers it as a ZERO-launch
@@ -1150,10 +1077,9 @@ def plan_fused(executor: Any, idx: Any, call: Any, shards: tuple,
         return None
     if opt is not None and not getattr(opt, "containers", True):
         return None
-    leaf_descs: list = []
-    shape = _walk(idx, call, leaf_descs)
-    if shape is None or not leaf_descs:
+    if not tree.plain:
         return None
+    shape, leaf_descs = tree.shape, tree.rows()
     if not counts and shape[0] == "leaf":
         return None
     if any(f.row_kept_dense(row_id, shards) for f, row_id in leaf_descs):

@@ -392,6 +392,7 @@ def _make_compiled(maxsize: int,
         # a concurrent duplicate build is wasted work, never a wrong
         # count: only the first insert lands and no eviction is charged
         prog = builder(shape, counts)
+        evicted = False
         with lock:
             if key in cache:
                 return cache[key]
@@ -399,6 +400,9 @@ def _make_compiled(maxsize: int,
             while len(cache) > maxsize:
                 cache.pop(next(iter(cache)))
                 counters["evictions"] += 1
+                evicted = True
+        if evicted:
+            _note_program_eviction(maxsize)
         return prog
 
     def cache_info() -> _CacheInfo:
@@ -474,22 +478,22 @@ def set_program_cache_size(maxsize: int) -> None:
     _eviction_warned = False
 
 
-def _note_program_cache_pressure() -> None:
+def _note_program_eviction(maxsize: int) -> None:
     """One-line warning the FIRST time a compiled program is evicted:
     shape thrash otherwise shows up only as inexplicable recompile
-    latency (the devobs gauge carries the running count)."""
+    latency (the devobs gauge carries the running count).  Called
+    where the eviction happens (``_make_compiled``), so a launch that
+    evicts nothing asks no cache for its count."""
     global _eviction_warned
     if _eviction_warned:
         return
-    if program_evictions() > 0:
-        _eviction_warned = True
-        import logging
+    _eviction_warned = True
+    import logging
 
-        ci = _compiled.cache_info()
-        logging.getLogger("pilosa_tpu.ops.expr").warning(
-            "fused-program cache overflowed (maxsize=%d): tree shapes "
-            "now evict each other and re-trace on reuse; see "
-            "compile.program_evictions on /metrics", ci.maxsize)
+    logging.getLogger("pilosa_tpu.ops.expr").warning(
+        "fused-program cache overflowed (maxsize=%d): tree shapes "
+        "now evict each other and re-trace on reuse; see "
+        "compile.program_evictions on /metrics", maxsize)
 
 
 # ----------------------------------------------------------- host engine
@@ -587,7 +591,6 @@ def evaluate(shape: tuple, leaves: tuple, counts: bool = False,
                            for lv in leaves)
             fn = _compiled_mesh((shape, len(leaves), ndim, mesh),
                                 counts)
-            _note_program_cache_pressure()
             meshexec.note_launch(
                 mesh_queries if mesh_queries is not None
                 else (leaves[0].shape[0] if ndim == 3 else 1))
@@ -603,9 +606,10 @@ def evaluate(shape: tuple, leaves: tuple, counts: bool = False,
                             nbytes=_touched_bytes(*placed, out))
             return out
     fn = _compiled(shape, counts)
-    _note_program_cache_pressure()
     out = fn(*leaves)
-    nbytes = _touched_bytes(*leaves, out)
+    # the leaves of one launch share a shape: one stack's size, times
+    # how many there are
+    nbytes = len(leaves) * leaves[0].nbytes + out.nbytes
     if not counts:
         _perfobs.sample("dense", out, t0, nbytes=nbytes)
         return out
@@ -673,7 +677,6 @@ def evaluate_gathered(shape: tuple, pools: tuple, idxs: tuple,
                 jnp.asarray(ix), mesh, 0) for ix in idxs)
             fn = _compiled_mesh_gather((shape, len(pools), mesh),
                                        counts)
-            _note_program_cache_pressure()
             meshexec.note_launch()
             with meshexec.launch_lock():  # see evaluate's mesh route
                 out = fn(*placed_pools, *placed_idxs)
@@ -683,7 +686,6 @@ def evaluate_gathered(shape: tuple, pools: tuple, idxs: tuple,
                                       out))
             return out
     fn = _compiled_gather(shape, counts)
-    _note_program_cache_pressure()
     out = fn(*pools, *(jnp.asarray(ix) for ix in idxs))
     # the gathered pool rows are what the launch actually reads — the
     # whole point of the compressed engine is touching D gathered
@@ -752,7 +754,6 @@ def evaluate_gathered_kinds(shape: tuple, leafops: tuple,
                                + apool.shape[-1] * 2 + 4
                                + rpool.shape[-1] * 2)
     fn = _compiled_gather_kinds((shape, spec), counts)
-    _note_program_cache_pressure()
     out = fn(*args)
     _perfobs.sample("gather_kinds", out, t0,
                     nbytes=gathered + _touched_bytes(out))

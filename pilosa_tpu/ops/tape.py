@@ -222,9 +222,13 @@ _prewarm_state: dict[str, Any] = {"state": "idle", "warmed": 0,
                                   "skipped": [], "error": None}
 
 
-def bump(name: str, value: int = 1) -> None:
+def bump(name: str, value: int = 1, also: str | None = None) -> None:
+    """``also``: a second counter that moves with the first (a reason
+    cell and its total), under the same take of the lock."""
     with _lock:
         _counters[name] += value
+        if also is not None:
+            _counters[also] += value
 
 
 def counters() -> dict[str, int]:

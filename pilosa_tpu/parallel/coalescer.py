@@ -270,14 +270,18 @@ class Coalescer:
                          mesh), tp)
         return (idx.name, shape, shards, mesh), None
 
-    def count(self, executor, idx, child, shards: tuple[int, ...],
+    def count(self, executor, idx, tree, shards: tuple[int, ...],
               deadline=None, cache_fill=None,
               use_delta: bool = True, mesh=None,
               tenant: str | None = None,
               use_vm: bool = True) -> int:
         """One Count(tree) query through the batching window -> total.
-        Staging runs on the CALLER's thread (fragment locks, and a
-        staging error belongs to this query alone).
+        ``tree`` is the read's Prepared (parallel/prepared.py): nothing
+        here walks the call tree again, and the read's counters and
+        timings go to its books (``tree.books``, settled once by
+        ``Executor.execute``; a caller that brought none is settled
+        here).  Staging runs on the CALLER's thread (fragment locks,
+        and a staging error belongs to this query alone).
 
         ``cache_fill`` is the executor's result-cache probe triple
         ``(cache, key, gens)`` for THIS query — the executor already
@@ -301,18 +305,21 @@ class Coalescer:
         actually costs — and a ?nodelta=1 query (which compacts up
         front and stages plain leaves) batches with a delta-reading
         one only when the programs are identical anyway."""
+        books = tree.books
+        own_books = books is None
+        if own_books:
+            books = _stats.Batch()
         vmstage = None
         offer = self.vm and self.ragged and use_vm
         if (offer and mesh is None
-                and _containers.kept_dense(idx, child, shards)):
+                and _containers.kept_dense(tree, shards)):
             # a leaf row is known to be kept dense (the verdict its
             # last staging left under the view's write token): the VM
             # offer is certain to be declined, so it is declined here,
             # before anything is staged for it, and counted as
             # stage_vm would have.  The leaves are staged once, below.
             _containers.bump("container.fallbacks")
-            _tape.bump("vm.fallbacks.ineligible_leaf")
-            _tape.bump("vm.fallbacks")
+            _tape.bump("vm.fallbacks.ineligible_leaf", also="vm.fallbacks")
         elif offer and mesh is None:
             # the bitmap VM: stage compressed (directories + local
             # gather rows, NO dense stacks) and key on the tape size
@@ -327,7 +334,7 @@ class Coalescer:
                 fast0 = _stagecheck.fast_leaves()
                 leaves0 = _stagecheck.leaves()
                 vmstage = _containers.stage_vm(
-                    idx, child, shards, use_delta=use_delta,
+                    tree, shards, use_delta=use_delta,
                     max_tape=self.max_tape, max_leaves=self.max_leaves,
                     min_domain=self.vm_min_domain,
                     max_prefetch=self.vm_max_prefetch)
@@ -351,7 +358,7 @@ class Coalescer:
         else:
             # ``route``: what this thread did since the cache's probe:
             # eligible, which mesh, the VM's offer and its decline
-            shape, leaves = executor._fused_expr(idx, child, shards,
+            shape, leaves = executor._fused_expr(idx, tree, shards,
                                                  use_delta=use_delta,
                                                  before="route")
             key, tp = self._bucket_key(idx, shape, shards, leaves,
@@ -394,7 +401,7 @@ class Coalescer:
                         if not bucket.sealed:
                             self._seal_locked(key, bucket, why)
                 wait.note(why=bucket.why)
-            self._flush(bucket)
+            self._flush(bucket, books)
         counts = entry.fut.result()
         launch_end = bucket.flush_t0 + bucket.launch_ns
         rec = _observe.current()
@@ -450,7 +457,7 @@ class Coalescer:
             # the live shard rows, in Python ints (int32 could wrap)
             total = int(arr[:len(shards)].sum())
         end = _observe.clock_ns()
-        self.stats.timing("coalescer.query_ns", end - t0)
+        books.timing(self.stats, "coalescer.query_ns", end - t0)
         if rec is not None:
             # the host tail, from the end of the shared launch: the
             # leader's scatter to the batch, this member's wake-up and
@@ -460,6 +467,8 @@ class Coalescer:
             with _observe.span("cache.fill"):
                 rc, key, gens = cache_fill
                 rc.put(key, gens, total, 32, tenant=tenant)
+        if own_books:
+            books.settle()
         return total
 
     # ------------------------------------------------------------- flush
@@ -489,9 +498,10 @@ class Coalescer:
                     self._drains += 1
                     self._wake.notify_all()
 
-    def _flush(self, bucket: _Bucket) -> None:
+    def _flush(self, bucket: _Bucket, books) -> None:
         """Leader-side: ONE launch for the sealed bucket, results
-        scattered to every waiter.  Appends are impossible once sealed
+        scattered to every waiter; the flush's counters go to the
+        leader's ``books``.  Appends are impossible once sealed
         (sealing happens under the same lock that guards appends).
         EVERYTHING here runs inside the try: any failure — including
         stats/tracing backends — must resolve every waiter's future,
@@ -519,8 +529,8 @@ class Coalescer:
         bucket.flush_t0 = _observe.clock_ns()
         if expired:
             try:
-                self.stats.count("coalescer.deadline_dropped",
-                                 len(expired))
+                books.count(self.stats, "coalescer.deadline_dropped",
+                            len(expired))
             except Exception:  # noqa: BLE001 — telemetry must never
                 pass  # strand the live waiters below
         if n == 0:
@@ -541,11 +551,12 @@ class Coalescer:
                 _tape.bump("coalescer.shape_misses", misses)
             if bucket.shapes_final > 1:
                 _tape.bump("coalescer.shape_flushes")
-            self.stats.count("coalescer.dispatches", 1)
-            self.stats.count("coalescer.flush_" + bucket.why, 1)
-            self.stats.histogram("coalescer.batch_occupancy", n)
-            self.stats.histogram("coalescer.shape_distinct",
-                                 bucket.shapes_final)
+            stats = self.stats
+            books.count(stats, "coalescer.dispatches", 1)
+            books.count(stats, "coalescer.flush_" + bucket.why, 1)
+            books.histogram(stats, "coalescer.batch_occupancy", n)
+            books.histogram(stats, "coalescer.shape_distinct",
+                            bucket.shapes_final)
             # the batch's ONE launch span, on the leader's record (and,
             # under a recording tracer, the exported coalescer.flush
             # span); it starts where the wait ended.  The launch is in
@@ -553,7 +564,7 @@ class Coalescer:
             # by the time the futures resolve.
             with self.in_flight(), _observe.span(
                     "launch", start_ns=bucket.flush_t0,
-                    timer=(self.stats, "coalescer.launch_ns"),
+                    timer=(books.timer(stats), "coalescer.launch_ns"),
                     export="coalescer.flush", batch=n,
                     shapes=bucket.shapes_final) as span:
                 bucket.flush_trace = tracing.active_trace_id()
@@ -566,8 +577,10 @@ class Coalescer:
                 # and bytes-touched / dense-equivalent sparsity — the
                 # perfobs.context scope threads both to the ops-layer
                 # launch sample
+                # (an entry's leaf stacks share one shape)
                 sig_work = sum(
-                    int(lv.size) for it in live for lv in it.leaves)
+                    len(it.leaves) * int(it.leaves[0].size)
+                    for it in live if it.leaves)
                 sig_sparsity = 1.0
                 if live[0].vm is not None:
                     # bitmap-VM bucket (every entry staged compressed

@@ -48,6 +48,7 @@ from pilosa_tpu.ops import bitmap as bm
 from pilosa_tpu.ops import containers as _containers
 from pilosa_tpu.ops import expr
 from pilosa_tpu.parallel import meshexec
+from pilosa_tpu.parallel import prepared as _prep
 from pilosa_tpu.parallel.results import (
     FieldRow,
     GroupCount,
@@ -74,6 +75,17 @@ def _next_pow2(n: int) -> int:
     """Smallest power of two >= n (shape padding so batched kernels
     compile O(log) distinct programs, not one per group count)."""
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
+
+
+def _with_slots(shape: tuple, slots: list) -> tuple:
+    """``shape`` with prepared leaf slot i replaced by ``slots[i]``: a
+    staged leaf's own slot, or the ``dfuse`` node of a row that has a
+    pending delta (its set and clear stacks take the two slots after
+    its base, so every later leaf moves up by two)."""
+    if shape[0] == "leaf":
+        return slots[shape[1]]
+    return tuple([_with_slots(c, slots) if isinstance(c, tuple) else c
+                  for c in shape])
 
 
 @dataclass
@@ -256,6 +268,104 @@ _NOOP_CALL = "_Noop"
 _EMPTY_ROWS_CALL = "_EmptyRows"
 
 
+_tls = threading.local()  # .scope: the _Scope open on this thread
+
+
+class _Scope:
+    """What ``Executor.execute`` holds open around one query, as ONE
+    object: the flight record attached to the thread
+    (``observe.attach``), the ?notiers escape (``residency.no_tiers``),
+    the tenant (``tenant.scope``), the exported ``executor.Execute``
+    span and the trace the query's RPCs carry
+    (``tracing.propagate``).  ``__enter__`` does only what the options
+    make necessary: with tiers on, no tenant, the nop tracer and no
+    inbound trace id (a read as a server's defaults send it) that is
+    the record's attach and the record's own id pushed as the active
+    trace, so downstream RPCs (shard map, hedges) still carry a
+    joinable traceparent and /debug/trace/{id} can assemble the
+    cross-node tree.  Every effect of the five scopes it replaces is
+    there when an option is not a default, and is restored on exit in
+    the reverse order.
+
+    It also keeps the query's books (``stats.Batch``): the counters
+    and timings of this read are collected there, by the coalescer
+    too, and written once by :meth:`settle`."""
+
+    __slots__ = ("ex", "rec", "opt", "index", "books",
+                 "_prev_scope", "_attach", "_notiers", "_tenant",
+                 "_span", "_ctx", "_walks0")
+
+    def __init__(self, ex: "Executor", rec, opt: ExecOptions,
+                 index: str):
+        self.ex = ex
+        self.rec = rec
+        self.opt = opt
+        self.index = index
+        self.books = _stats.Batch()
+
+    def __enter__(self):
+        rec, opt = self.rec, self.opt
+        self._walks0 = _prep.walks()
+        self._prev_scope = getattr(_tls, "scope", None)
+        _tls.scope = self
+        self._attach = _observe.attach(rec)
+        self._attach.__enter__()
+        # a scope that would write the value that is there writes none
+        self._notiers = self._tenant = self._span = self._ctx = None
+        if _residency.tiers_off_scope() != (not opt.tiers):
+            self._notiers = _residency.no_tiers(not opt.tiers)
+            self._notiers.__enter__()
+        if _tenantmod.current() != opt.tenant:
+            self._tenant = _tenantmod.scope(opt.tenant)
+            self._tenant.__enter__()
+        parent = tracing.current_span()
+        trace_id = None
+        if (type(tracing.global_tracer()) is not tracing.Tracer
+                or (parent is not None and parent.trace_id)):
+            # a recording tracer, or an inbound trace to carry on
+            self._span = span = tracing.start_span("executor.Execute")
+            span.__enter__()
+            span.set_tag("index", self.index)
+            trace_id = span.trace_id
+        if rec is not None:
+            rec.tenant = opt.tenant
+            rec.remote = bool(opt.remote)
+            if trace_id:
+                # span -> record linkage: the record carries the
+                # exported trace id, the span the record id
+                rec.trace_id = trace_id
+            else:
+                # the propagate fallback: under the nop tracer with no
+                # inbound traceparent the record's self-generated id
+                # becomes the active trace
+                self._ctx = tracing.push_context(rec.trace_id)
+            if self._span is not None:
+                self._span.set_tag("query.record", rec.qid)
+        return self
+
+    def __exit__(self, *exc):
+        tracing.pop_context(self._ctx)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        if self._tenant is not None:
+            self._tenant.__exit__(*exc)
+        if self._notiers is not None:
+            self._notiers.__exit__(*exc)
+        self._attach.__exit__(*exc)
+        _tls.scope = self._prev_scope
+        return False
+
+    def settle(self) -> None:
+        """The one place this query's counters, histograms and timings
+        are written (one take of the registry's lock), with the tree
+        walks its thread made (``plan.walks``)."""
+        books = self.books
+        walked = _prep.walks() - self._walks0
+        if walked:
+            books.count(self.ex.stats, "plan.walks", walked)
+        books.settle()
+
+
 class Executor:
     def __init__(self, holder, worker_pool_size: int | None = None, cluster=None):
         self.holder = holder
@@ -350,30 +460,10 @@ class Executor:
             rec = self.recorder.begin(index_name, pql_text,
                                       trace_id=tracing.active_trace_id())
         t0 = _time.perf_counter() if rec is None else 0.0
+        scope = _Scope(self, rec, opt, index_name)
+        books = scope.books
         try:
-            with _observe.attach(rec), \
-                    _residency.no_tiers(not opt.tiers), \
-                    _tenantmod.scope(opt.tenant), \
-                    tracing.start_span("executor.Execute") as span, \
-                    tracing.propagate(rec.trace_id
-                                      if rec is not None
-                                      and not span.trace_id
-                                      else None):
-                # the propagate fallback: under the nop tracer with no
-                # inbound traceparent the record's self-generated id
-                # becomes the active trace, so downstream RPCs (shard
-                # map, hedges) still carry a joinable traceparent and
-                # /debug/trace/{id} can assemble the cross-node tree
-                span.set_tag("index", index_name)
-                if rec is not None:
-                    rec.tenant = opt.tenant
-                    rec.remote = bool(opt.remote)
-                if rec is not None:
-                    # span -> record linkage: the record carries the
-                    # exported trace id, the span the record id
-                    if span.trace_id:
-                        rec.trace_id = span.trace_id
-                    span.set_tag("query.record", rec.qid)
+            with scope:
                 # Key translation happens once at the originating node,
                 # never on remote re-execution (reference
                 # executor.Execute, executor.go:146).
@@ -382,28 +472,33 @@ class Executor:
                 if not opt.remote:
                     with _observe.span("translate") as sp:
                         # the record's own opening, from
-                        # ``recorder.begin`` through the scopes above
+                        # ``recorder.begin`` through the scope above
                         sp.before("exec.open")
-                        calls = [self._translate_call(idx, c)
+                        # a Count holds no key of its own, and its
+                        # tree's keys are translated in the tree's one
+                        # walk (_execute_count, parallel/prepared.py)
+                        calls = [c if c.name == "Count"
+                                 else self._translate_call(idx, c)
                                  for c in calls]
                 results = []
+                timer = books.timer(self.stats)
                 for call in calls:
-                    self.stats.count_with_tags(
-                        "query", 1, 1.0, [f"index:{index_name}",
-                                          f"call:{call.name}"])
+                    books.count(self.stats, "query", 1,
+                                (f"index:{index_name}",
+                                 f"call:{call.name}"))
                     # ONE span per call: the record's ``call.<Name>``
                     # span (rendered as the ``execute.<Name>`` stage),
                     # the per-op stats timing (exception-safe: failed
                     # calls record too) and, under a recording tracer,
                     # the exported span.  That one parents implicitly
                     # on purpose: under the nop tracer the active span
-                    # here is the propagate fallback's ContextSpan, not
-                    # the bare Execute span — an explicit traceless
-                    # parent would bury the trace for the whole call
-                    # (map fan-out RPCs, replica writes, hint stamps)
+                    # here is the scope's ContextSpan, not a bare
+                    # Execute span — an explicit traceless parent would
+                    # bury the trace for the whole call (map fan-out
+                    # RPCs, replica writes, hint stamps)
                     with _observe.span(
                             "call." + call.name,
-                            timer=(self.stats, "execute." + call.name),
+                            timer=(timer, "execute." + call.name),
                             export="executor.execute" + call.name):
                         results.append(
                             self._execute_call(idx, call, shards, opt))
@@ -426,12 +521,18 @@ class Executor:
                     # flight record too, not just the HTTP body
                     for s in e.shards:
                         rec.note_missing(s)
+            scope.settle()
+            if rec is not None:
                 self.recorder.publish(rec,
                                       error=f"{type(e).__name__}: {e}")
             raise
         if opt.missing:
             with self._hedge_lock:
                 self._partial_degraded += 1
+        # the read's books are settled inside its ``exec`` span, where
+        # the single writes were (the latency histogram is the
+        # recorder's own, written once the span has its end)
+        scope.settle()
         if rec is not None:
             rec.result_sizes = [_observe.result_size(r) for r in results]
             self.recorder.publish(rec)
@@ -1013,72 +1114,26 @@ class Executor:
 
     # ------------------------------------------------ fused all-shard path
 
-    def _fused_supported(self, idx, call: Call) -> bool:
-        """True when the bitmap tree can evaluate as ONE stacked device
-        computation over all shards: plain standard-view Row leaves,
-        time-range Rows, and BSI condition rows, combined with
-        Union/Intersect/Difference/Xor/Not/Shift."""
-        name = call.name
-        if name == "Row":
-            cond = call.condition_arg()
-            if cond is not None:
-                # BSI condition rows fuse via the stacked range kernels
-                fname, condition = cond
-                f = idx.field(fname)
-                if f is None or f.options.type != FieldType.INT:
-                    return False
-                if condition.op == "><":
-                    v = condition.value
-                    return (isinstance(v, list) and len(v) == 2
-                            and all(isinstance(x, int)
-                                    and not isinstance(x, bool) for x in v))
-                if condition.value is None:
-                    return condition.op == "!="
-                return (isinstance(condition.value, int)
-                        and not isinstance(condition.value, bool))
-            try:
-                fname = call.field_arg()
-            except ValueError:
-                return False
-            v = call.args.get(fname)
-            if not isinstance(v, int) or isinstance(v, bool):
-                return False
-            f = idx.field(fname)
-            if f is None:
-                return False
-            if "from" in call.args or "to" in call.args:
-                # time-range Row: the cover unions host-side into one
-                # cached stack, so the cap only bounds the generation
-                # tuple the cache must compare per hit
-                if not f.time_quantum:
-                    return False
-                views = self._time_range_views(f, call)
-                return views is not None and len(views) <= 256
-            o = f.options
-            return not (o.type == FieldType.INT
-                        or (o.type == FieldType.TIME and o.no_standard_view))
-        if name == "Not":
-            return (len(call.children) == 1
-                    and idx.existence_field() is not None
-                    and self._fused_supported(idx, call.children[0]))
-        if name == "Shift":
-            n = call.int_arg("n")
-            return (len(call.children) == 1
-                    and (n is None or n >= 0)
-                    and self._fused_supported(idx, call.children[0]))
-        if name in ("Union", "Intersect", "Difference", "Xor"):
-            return bool(call.children) and all(
-                self._fused_supported(idx, c) for c in call.children)
-        return False
+    def _prepare(self, idx, tree: Call | None,
+                 translate: bool = False) -> _prep.Prepared | None:
+        """The tree's ONE walk (parallel/prepared.py): translation where
+        asked, whether it fuses, its shape and leaves, its cache key.
+        Every later stage reads the result; None for no tree."""
+        if tree is None:
+            return None
+        return _prep.prepare(self, idx, tree, translate)
 
-    def _fuse_eligible(self, idx, shards, call: Call | None = None,
+    def _fuse_eligible(self, shards,
+                       tree: _prep.Prepared | None = None,
                        extra: bool = True) -> bool:
         """The shared precondition of every fused all-shard dispatch:
         fusion enabled, a real multi-shard batch, any op-specific
         `extra` condition, and (when the op carries a bitmap tree) the
-        tree being stack-evaluable."""
+        tree being stack-evaluable: plain standard-view Row leaves,
+        time-range Rows and BSI condition rows, combined with
+        Union/Intersect/Difference/Xor/Not/Shift."""
         return (self.fuse_shards and len(shards) > 1 and extra
-                and (call is None or self._fused_supported(idx, call)))
+                and (tree is None or tree.fused))
 
     def _time_range_views(self, f, call: Call) -> list[str] | None:
         """The time views covering a Row(from=, to=) query — the same
@@ -1103,15 +1158,18 @@ class Executor:
                 else list(views_by_time_range(VIEW_STANDARD, start, end,
                                               f.time_quantum)))
 
-    def _fused_expr(self, idx, call: Call, shards: tuple[int, ...],
-                    use_delta: bool = True, before: str | None = None):
+    def _fused_expr(self, idx, tree: _prep.Prepared,
+                    shards: tuple[int, ...], use_delta: bool = True,
+                    before: str | None = None):
         """Stage a supported tree for ONE-launch evaluation: returns
         ``(shape, leaves)`` where ``shape`` is the canonical structure
         key (row ids and values erased into leaf slots — distinct rows
         share a compiled program) and ``leaves`` the operand stacks, for
         ops.expr.  Leaf staging is the cached stack builders
-        (device_row_stack & friends); no compute dispatches here beyond
-        what BSI range leaves inherently cost.
+        (Field.stage_rows & friends); no compute dispatches here beyond
+        what BSI range leaves inherently cost.  The tree is not walked
+        again: ``tree.leaves`` lists what to stage and ``tree.shape``
+        is the answer unless a row has a pending delta.
 
         ``use_delta=False`` is the ?nodelta=1 escape: pending delta
         planes on the touched fragments are compacted up front and
@@ -1120,13 +1178,11 @@ class Executor:
         ``before`` names, as a span of its own, what the caller's
         thread did between its last phase and this staging (the
         coalescer's ``route``)."""
-        leaves: list = []
         with _observe.span("stage") as sp:
             if before is not None:
                 sp.before(before)
             fast0 = _stagecheck.fast_leaves()
-            shape = self._fused_shape(idx, call, shards, leaves,
-                                      use_delta)
+            shape, leaves = self._stage_leaves(tree, shards, use_delta)
             # fast: leaves whose cached stacks were validated against
             # the view's write token alone, with no walk over the
             # shards (stagecheck.py)
@@ -1134,92 +1190,79 @@ class Executor:
                     fast=_stagecheck.fast_leaves() - fast0)
         return shape, tuple(leaves)
 
-    def _fused_row_leaf(self, f, row_id, shards: tuple[int, ...],
-                        leaves: list, use_delta: bool):
-        """One standard-view row leaf, delta-aware: the base stack is
-        resident under its base token (delta writes don't evict it);
-        when a pending delta touches this row in any fragment, the
-        overlay stacks join as ``dfuse`` operands — staged BEFORE the
-        base stack, so a compaction racing the two reads can only
+    def _stage_leaves(self, tree: _prep.Prepared,
+                      shards: tuple[int, ...], use_delta: bool):
+        """The stacks of ``tree.leaves`` in slot order.  The plain
+        rows of one field are staged together (``Field.stage_rows``:
+        the view's write token read once, before every lookup, and one
+        take of each owner's lock for the rows it proves good).  A
+        standard-view row is delta-aware: its base stack is resident
+        under its base token (delta writes don't evict it); when a
+        pending delta touches the row in any fragment, the overlay
+        stacks join as ``dfuse`` operands, staged BEFORE the base
+        stack, so a compaction racing the two reads can only
         double-apply the (idempotent) overlay, never drop it."""
-        mark = _stagecheck.mark()
-        if not use_delta:
-            f.flush_deltas(shards)
-            ds = None
-        else:
-            ds = f.device_delta_stacks(row_id, shards)
-        leaves.append(f.device_row_stack(row_id, shards))
-        _stagecheck.leaf_done(mark)
-        shape = ("leaf", len(leaves) - 1)
-        if ds is not None:
-            leaves.append(ds[0])
-            si = len(leaves) - 1
-            leaves.append(ds[1])
-            shape = ("dfuse", shape, ("leaf", si), ("leaf", len(leaves) - 1))
-            rec = _observe.current()
-            if rec is not None:
-                rec.note_delta(1)
-        return shape
-
-    def _fused_shape(self, idx, call: Call, shards: tuple[int, ...],
-                     leaves: list, use_delta: bool = True):
-        name = call.name
-        if name == "Row":
-            cond = call.condition_arg()
-            if cond is not None:
-                fname, condition = cond
-                value = (condition.int_slice_value()
-                         if condition.op == "><" else condition.value)
-                # the range compare dispatches while it stages: its
-                # launch hangs under the stage span
-                mark = _stagecheck.mark()
-                leaves.append(_perfobs.launch(
-                    self._raw_engine(self._query_mesh(None)),
-                    lambda: idx.field(fname).device_range_stack(
-                        condition.op, value, shards)))
-                _stagecheck.leaf_done(mark)
-                return ("leaf", len(leaves) - 1)
-            fname = call.field_arg()
-            f = idx.field(fname)
-            if "from" in call.args or "to" in call.args:
+        descs = tree.leaves
+        by_field: dict = {}
+        for i, d in enumerate(descs):
+            if d[0] == "row":
+                by_field.setdefault(d[1], []).append(i)
+        staged: list = [None] * len(descs)
+        for f, slots in by_field.items():
+            got = f.stage_rows([descs[i][2] for i in slots], shards,
+                               use_delta)
+            if len(slots) == len(descs) and not any(
+                    [ds for _, ds in got]):
+                # the common read, done: every leaf a row of one field
+                # and no overlay (the tail below gives the same)
+                return tree.shape, [base for base, _ in got]
+            for i, pair in zip(slots, got):
+                staged[i] = pair
+        leaves: list = []
+        slots_of: list = []
+        deltas = 0
+        for i, d in enumerate(descs):
+            kind = d[0]
+            if kind == "row":
+                base, ds = staged[i]
+                leaves.append(base)
+                at = ("leaf", len(leaves) - 1)
+                if ds is not None:
+                    leaves.append(ds[0])
+                    leaves.append(ds[1])
+                    n = len(leaves)
+                    at = ("dfuse", at, ("leaf", n - 2), ("leaf", n - 1))
+                    deltas += 1
+                slots_of.append(at)
+                continue
+            mark = _stagecheck.mark()
+            if kind == "time":
                 # time-range Row: ONE cached stack holding the
                 # host-side union over the covering views (f.row_time's
                 # union, batched across shards).  Delta overlays apply
                 # inside the builder (effective reads; token carries
                 # the delta seq) — no dfuse leaves needed.
-                views = self._time_range_views(f, call) or []
-                mark = _stagecheck.mark()
-                leaves.append(f.device_time_row_stack(
-                    call.args[fname], shards, tuple(views)))
-                _stagecheck.leaf_done(mark)
-                return ("leaf", len(leaves) - 1)
-            # arg is a plain int row id (bool literals were excluded by
-            # _fused_supported)
-            return self._fused_row_leaf(f, call.args[fname], shards,
-                                        leaves, use_delta)
-        if name in ("Union", "Intersect", "Difference", "Xor"):
-            op = {"Union": "or", "Intersect": "and",
-                  "Difference": "andnot", "Xor": "xor"}[name]
-            return (op, *(self._fused_shape(idx, c, shards, leaves,
-                                            use_delta)
-                          for c in call.children))
-        if name == "Not":
-            exist = self._fused_row_leaf(idx.existence_field(), 0,
-                                         shards, leaves, use_delta)
-            return ("not", exist,
-                    self._fused_shape(idx, call.children[0], shards,
-                                      leaves, use_delta))
-        if name == "Shift":
-            n = call.int_arg("n")
-            # per-shard semantics batch directly: bits shift within
-            # each shard's row and drop at the shard edge, exactly as
-            # the per-shard path does (executor.go:1730)
-            return ("shift", 1 if n is None else n,
-                    self._fused_shape(idx, call.children[0], shards,
-                                      leaves, use_delta))
-        raise ExecutionError(f"unsupported fused call: {name}")
+                leaves.append(d[1].device_time_row_stack(d[2], shards,
+                                                         d[3]))
+            else:
+                # the range compare dispatches while it stages: its
+                # launch hangs under the stage span
+                _, f, op, value = d
+                leaves.append(_perfobs.launch(
+                    self._raw_engine(self._query_mesh(None)),
+                    lambda: f.device_range_stack(op, value, shards)))
+            _stagecheck.leaf_done(mark)
+            slots_of.append(("leaf", len(leaves) - 1))
+        if not deltas:
+            # no overlay: slot i holds leaf i, the shape as prepared
+            return tree.shape, leaves
+        rec = _observe.current()
+        if rec is not None:
+            rec.note_delta(deltas)
+        return _with_slots(tree.shape, slots_of), leaves
 
-    def _fused_eval(self, idx, call: Call, shards: tuple[int, ...],
+    def _fused_eval(self, idx, tree: _prep.Prepared,
+                    shards: tuple[int, ...],
                     use_delta: bool = True, mesh=None):
         """Evaluate a supported tree -> uint32 [n_shards, words] device
         stack, as ONE compiled program over the leaf stacks (ops.expr) —
@@ -1230,7 +1273,7 @@ class Executor:
         ``mesh`` (``_query_mesh``) routes the shard_map program so the
         one launch spans every mesh device; None is the pre-mesh
         single-device program (?nomesh=1 / [mesh] disabled)."""
-        shape, leaves = self._fused_expr(idx, call, shards, use_delta)
+        shape, leaves = self._fused_expr(idx, tree, shards, use_delta)
         with _observe.span("launch") as sp:
             out = expr.evaluate(shape, leaves, mesh=mesh)
             sp.note_engine()
@@ -1266,10 +1309,10 @@ class Executor:
 
     # ------------------------------------------- result cache (read paths)
 
-    def _rc_collect_gens(self, f, view_name: str,
-                         shards: tuple[int, ...], out: dict) -> None:
-        """Record the invalidation stamp for one (field, view) pair
-        over the shard set: the aggregate ``(count, sum_gen, sum_seq,
+    @staticmethod
+    def _rc_view_stamp(f, view_name: str, shards: tuple[int, ...]):
+        """The invalidation stamp of one (field, view) pair over the
+        shard set: the aggregate ``(count, sum_gen, sum_seq,
         sum_uid, max_uid)`` of the participating fragments' generation
         tokens — ``(base_gen, delta_seq)`` per fragment, the streaming-
         ingest extension (pilosa_tpu.ingest).
@@ -1293,20 +1336,28 @@ class Executor:
         state change flips at least one component, while an unchanged
         view reproduces the stamp exactly.
 
-        Memoized per (field name, view) of the one index a probe
-        reads: ``Intersect(Row(f=a), Row(f=b))``
+        One per (field name, view) of the one index a probe reads
+        (``Prepared.views``): ``Intersect(Row(f=a), Row(f=b))``
         touches the same view twice but needs one stamp.  The single
         pass keeps a probe that misses to one walk over the shards: the
         common fully-populated case batches all dict lookups into one
         C-level ``itemgetter`` call, falling back to the filtering loop
-        only when some shard has no fragment."""
-        mkey = ("" if f is None else f.name, view_name)
-        if mkey in out:
-            return
+        only when some shard has no fragment.
+
+        And the walk is made only when the view's write token
+        (stagecheck.py) has moved since the last one over this shard
+        set: the token is read FIRST, and it changes after every event
+        that could change any fragment's (uid, gen, delta_seq) or the
+        view's map of fragments, so an aggregate remembered under the
+        token that still stands is the one a walk would give now."""
         view = None if f is None else f.view(view_name)
         if view is None:
-            out[mkey] = 0
-            return
+            return 0
+        token = view.write_token
+        memo = view.rc_stamps
+        hit = memo.get(shards)
+        if hit is not None and hit[0] == token:
+            return hit[1]
         frags = view.fragments
         fs = None
         if len(shards) > 1:
@@ -1325,91 +1376,38 @@ class Executor:
             su += u
             if u > mu:
                 mu = u
-        out[mkey] = (len(fs), sg, sq, su, mu)
-
-    def _rc_sig(self, idx, call: Call, shards: tuple[int, ...],
-                gens_out: dict, moved: list):
-        """Canonical identity of one fused-supported bitmap tree: the
-        expression shape with leaf identities (field, view, row /
-        op+value) substituted at the slots — distinct queries over the
-        same shape get distinct keys, unlike the coalescer's value-
-        erased bucket key.  Canonical in operand order too: the
-        operands of Union / Intersect / Xor, and those of Difference
-        after its first, join the tuple sorted, so
-        ``Intersect(a, b)`` and ``Intersect(b, a)`` are one key and
-        one entry.  Nothing else is rewritten (no flattening, no De
-        Morgan).  The order is that of the operands' ``repr``: total
-        over anything a level can hold (a row beside a range, a nested
-        operator, an int row id beside a string one: tuple comparison
-        raises there), and the same in every process.  A level whose
-        operands were written in another order appends to ``moved``.
-        Collects every participating fragment's
-        generation token into ``gens_out``; the caller captures this
-        stamp BEFORE any fragment data is read (resultcache
-        stamp-before-read discipline — the reverse order could stamp
-        fresh generations onto stale data)."""
-        name = call.name
-        if name == "Row":
-            cond = call.condition_arg()
-            if cond is not None:
-                fname, condition = cond
-                f = idx.field(fname)
-                self._rc_collect_gens(f, f.bsi_view_name, shards,
-                                      gens_out)
-                value = (condition.int_slice_value()
-                         if condition.op == "><" else condition.value)
-                if isinstance(value, list):
-                    value = tuple(value)
-                return ("range", fname, condition.op, value)
-            fname = call.field_arg()
-            f = idx.field(fname)
-            if "from" in call.args or "to" in call.args:
-                # the covering views are part of the identity: a new
-                # time view (first write into a fresh quantum) changes
-                # the cover, so the old entry simply stops being
-                # addressed
-                views = tuple(self._time_range_views(f, call) or ())
-                for vn in views:
-                    self._rc_collect_gens(f, vn, shards, gens_out)
-                return ("time", fname, call.args[fname], views)
-            self._rc_collect_gens(f, VIEW_STANDARD, shards, gens_out)
-            return ("row", fname, call.args[fname])
-        if name in ("Union", "Intersect", "Difference", "Xor"):
-            sigs = [self._rc_sig(idx, c, shards, gens_out, moved)
-                    for c in call.children]
-            # Difference is its first operand minus all the others
-            keep = name == "Difference"
-            rest = sigs[keep:]
-            tail = sorted(rest, key=repr)
-            if tail != rest:
-                moved.append(name)
-            return (name, *sigs[:keep], *tail)
-        if name == "Not":
-            ef = idx.existence_field()
-            self._rc_collect_gens(ef, VIEW_STANDARD, shards, gens_out)
-            return ("not", ef.name,
-                    self._rc_sig(idx, call.children[0], shards,
-                                 gens_out, moved))
-        if name == "Shift":
-            n = call.int_arg("n")
-            return ("shift", 1 if n is None else n,
-                    self._rc_sig(idx, call.children[0], shards,
-                                 gens_out, moved))
-        raise ExecutionError(f"uncacheable call: {name}")
+        stamp = (len(fs), sg, sq, su, mu)
+        if len(memo) >= 16:
+            memo.clear()  # shard sets are few: a server has one or two
+        memo[shards] = (token, stamp)
+        return stamp
 
     def _rc_probe(self, idx, kind: str, shards: tuple[int, ...],
-                  opt: ExecOptions | None, span, tree: Call | None = None,
+                  opt: ExecOptions | None,
+                  tree: _prep.Prepared | None = None,
                   extra=None, gen_fields=()):
         """(cache, key, gens) for one fused read, or None when caching
-        is off (process config or the request's ?nocache=1) or the
-        tree has no canonical signature.  ``extra`` joins the key
-        (e.g. the TopN field and truncation args); ``gen_fields`` is
-        (field, view_name) pairs whose fragments participate beyond
-        the tree leaves (e.g. the scanned TopN matrix).  Stamps the
-        key digest onto the active flight record so every record
-        carries its cacheKey, hit or miss.  A tree whose operands the
-        signature had to reorder counts in ``cache.reordered`` and
-        notes ``reordered`` on ``span``, the open ``cache.probe``.
+        is off (process config or the request's ?nocache=1).  The
+        tree's part of the key is ``tree.sig``, the canonical identity
+        its one walk left: the expression shape with leaf identities
+        (field, view, row / op+value) substituted at the slots —
+        distinct queries over the same shape get distinct keys, unlike
+        the coalescer's value-erased bucket key — and canonical in
+        operand order: ``Intersect(a, b)`` and ``Intersect(b, a)`` are
+        one key and one entry.  ``extra`` joins the key (e.g. the TopN
+        field and truncation args); ``gen_fields`` is (field,
+        view_name) pairs whose fragments participate beyond the tree
+        leaves (e.g. the scanned TopN matrix).  Puts the key on the
+        active flight record, whose ``cacheKey`` is its digest, hit or
+        miss.
+
+        The stamp is captured HERE, before any fragment data is read
+        (resultcache stamp-before-read discipline — the reverse order
+        could stamp fresh generations onto stale data): one aggregate
+        a (field, view), in the order of their names and NOT in the
+        order the leaves were met: two written orders of one tree
+        share a key, and a stamp that followed the traversal would
+        read the other order's fill as invalidated.
 
         ``?nodelta=1`` bypasses the probe too: its contract is an
         up-front compaction and a REAL pure-base read — a cached value
@@ -1420,11 +1418,11 @@ class Executor:
         if not rc.enabled or (opt is not None
                               and not (opt.cache and opt.delta)):
             return None
-        gens_out: dict = {}
-        moved: list = []
-        try:
-            sig = (None if tree is None
-                   else self._rc_sig(idx, tree, shards, gens_out, moved))
+        if tree is not None and not tree.fused:
+            return None  # no canonical identity: never one key for two
+        views = () if tree is None else tree.views
+        if gen_fields:
+            merged = {(fn, vn): f for fn, vn, f in views}
             for f, vn in gen_fields:
                 # gen_fields means a whole-matrix read (TopN refresh,
                 # GroupBy Rows scan), and those merge pending deltas
@@ -1433,13 +1431,10 @@ class Executor:
                 # just invalidated (dead on arrival: the next identical
                 # query would re-execute instead of hitting)
                 f.flush_deltas(shards)
-                self._rc_collect_gens(f, vn, shards, gens_out)
-        except (ExecutionError, ValueError, KeyError, TypeError,
-                AttributeError):
-            return None
-        if moved:
-            rc.note_reordered()
-            span.note(reordered=1)
+                merged[(f.name, vn)] = f
+            views = [(k[0], k[1], merged[k]) for k in sorted(merged)]
+        stamp = self._rc_view_stamp
+        gens = tuple([stamp(f, vn, shards) for _, vn, f in views])
         # the active placement flavor joins the key (PR 12 follow-up):
         # a [mesh] toggle or axis resize must not serve fills staged
         # under the previous device layout — and when the operator
@@ -1448,17 +1443,13 @@ class Executor:
         placement = meshexec.placement_token(
             opt is None or opt.mesh)
         key = resultcache.Key(
-            (self.holder.uid, idx.name, kind, sig, extra, shards,
+            (self.holder.uid, idx.name, kind,
+             None if tree is None else tree.sig, extra, shards,
              placement))
         rec = _observe.current()
         if rec is not None:
-            rec.cache_key = resultcache.key_digest(key)
-        # one stamp a (field, view), in the order of the memo's keys
-        # and NOT in the order the leaves were met: two written orders
-        # of one tree share a key, and a stamp that followed the
-        # traversal would read the other order's fill as invalidated.
-        # The keys are unique, so the sort never compares a stamp
-        return rc, key, tuple([gens_out[k] for k in sorted(gens_out)])
+            rec.cache_key = key
+        return rc, key, gens
 
     @staticmethod
     def _rc_mark_hit() -> None:
@@ -1468,18 +1459,24 @@ class Executor:
             rec.note_path("cached")
 
     def _rc_get(self, idx, kind: str, shards: tuple[int, ...], opt,
-                **probe_kw):
+                tree: _prep.Prepared | None = None, **probe_kw):
         """The ``cache.probe`` span: key + generation stamp, then the
         lookup (single-flight wait on another reader's fill included)
         -> ``(hit, value, probe)``; ``probe`` is None with caching off
         and otherwise what :meth:`_rc_put` fills."""
         with _observe.span("cache.probe") as sp:
-            probe = self._rc_probe(idx, kind, shards, opt, sp,
+            probe = self._rc_probe(idx, kind, shards, opt, tree,
                                    **probe_kw)
             if probe is None:
                 return False, None, None
             rc, key, gens = probe
-            hit, val = rc.get(key, gens, self._rc_wait(opt))
+            moved = tree is not None and tree.moved
+            if moved:
+                # a tree whose operands the key reordered counts in
+                # ``cache.reordered`` (under the lookup's own lock)
+                sp.note(reordered=1)
+            hit, val = rc.get(key, gens, self._rc_wait(opt),
+                              reordered=moved)
             sp.note(hit=bool(hit))
             if hit:
                 self._rc_mark_hit()
@@ -1506,18 +1503,20 @@ class Executor:
         return max(0.0, min(resultcache.FLIGHT_WAIT_S, dl.remaining()))
 
     def _execute_bitmap_call(self, idx, call: Call, shards, opt: ExecOptions) -> Row:
+        _prep.note_walk()
         self._validate_call_fields(idx, call)
         shards = self._target_shards(idx, shards, opt)
         row = Row()
 
         with _observe.span("plan"):
-            fused_ok = self._fuse_eligible(idx, shards, call)
+            tree = self._prepare(idx, call)
+            fused_ok = self._fuse_eligible(shards, tree)
 
         def batch_fn(group):
             # probe the result cache FIRST (stamp captured before any
             # fragment read); a hit skips the device entirely
             g = tuple(group)
-            hit, val, probe = self._rc_get(idx, "row", g, opt, tree=call)
+            hit, val, probe = self._rc_get(idx, "row", g, opt, tree=tree)
             if hit:
                 # copies both ways (fill and hit): cached words
                 # must never alias a Row a caller may mutate
@@ -1528,7 +1527,7 @@ class Executor:
             # per-shard words here
             with _observe.span("plan"):
                 m = self._query_mesh(opt)
-                cplan = _containers.plan_fused(self, idx, call, g, opt,
+                cplan = _containers.plan_fused(tree, g, opt,
                                                counts=False)
 
             def _dispatch():
@@ -1536,7 +1535,7 @@ class Executor:
                 # under the shared RESOURCE_EXHAUSTED evict-and-retry
                 if cplan is not None:
                     return cplan.row_words(mesh=m)
-                stack = self._fused_eval(idx, call, g,
+                stack = self._fused_eval(idx, tree, g,
                                          use_delta=opt.delta, mesh=m)
                 with _observe.span("reduce"):
                     # copies: a view would pin the whole stack in
@@ -1564,6 +1563,7 @@ class Executor:
                 return shard, self._bitmap_words_shard(idx, call, shard,
                                                         opt.delta)
 
+            _prep.note_walk(len(shards))  # the tree, shard by shard
             partials = self._map_shards(
                 map_fn, shards, idx=idx, call=call, opt=opt,
                 adapt=lambda r: list(r.segments.items()),
@@ -1762,9 +1762,20 @@ class Executor:
         if len(call.children) != 1:
             raise ExecutionError("Count() requires a single bitmap query")
         shards = self._target_shards(idx, shards, opt)
-        child = call.children[0]
         with _observe.span("plan"):
-            fused_ok = self._fuse_eligible(idx, shards, child)
+            # the tree's ONE walk.  Key translation happens in it, once,
+            # at the originating node (a tree that came translated, as
+            # under Options, holds no string key any more)
+            tree = self._prepare(idx, call.children[0],
+                                 translate=not opt.remote)
+            fused_ok = self._fuse_eligible(shards, tree)
+        child = tree.call
+        if child is not call.children[0]:
+            call = Call("Count", call.args, [child])
+        scope = getattr(_tls, "scope", None)
+        if scope is not None:
+            tree.books = scope.books
+            scope.books.count(self.stats, "plan.prepared", 1)
 
         def compute_counts_once(group):
             # the whole tree INCLUDING the popcount root as one compiled
@@ -1779,11 +1790,10 @@ class Executor:
             # container blocks are ever read
             with _observe.span("plan"):
                 m = self._query_mesh(opt)
-                cplan = _containers.plan_fused(self, idx, child,
-                                               tuple(group), opt)
+                cplan = _containers.plan_fused(tree, tuple(group), opt)
             if cplan is not None:
                 return cplan.counts(mesh=m)
-            shape, leaves = self._fused_expr(idx, child, tuple(group),
+            shape, leaves = self._fused_expr(idx, tree, tuple(group),
                                              use_delta=opt.delta)
             with _observe.span("launch") as sp:
                 counts = expr.evaluate(shape, leaves, counts=True, mesh=m)
@@ -1810,7 +1820,7 @@ class Executor:
             # the single-node branch below when the sub-query arrives
             g = tuple(group)
             hit, val, probe = self._rc_get(idx, "count_shards", g, opt,
-                                           tree=child)
+                                           tree=tree)
             if hit:
                 return list(val)
             vals = compute_counts(group)
@@ -1823,7 +1833,7 @@ class Executor:
             # result-cache probe BEFORE the coalescer: a hit answers
             # pre-window and never occupies a batch slot
             hit, val, probe = self._rc_get(idx, "count", tuple(shards),
-                                           opt, tree=child)
+                                           opt, tree=tree)
             if hit:
                 return val
             if (self.coalescer is not None
@@ -1833,7 +1843,7 @@ class Executor:
                 # this entry from the batch if its deadline dies in
                 # the window, and fills the cache for every flushed
                 # batch member
-                return self.coalescer.count(self, idx, child,
+                return self.coalescer.count(self, idx, tree,
                                             tuple(shards),
                                             deadline=opt.deadline,
                                             cache_fill=probe,
@@ -1857,6 +1867,7 @@ class Executor:
                 return 0
             return int(bm.popcount(words))
 
+        _prep.note_walk(len(shards))  # the tree, shard by shard
         return sum(
             self._map_shards(
                 map_fn, shards, idx=idx, call=call, opt=opt,
@@ -1939,13 +1950,14 @@ class Executor:
         remote_call.args.pop("tanimotoThreshold", None)
 
         with _observe.span("plan"):
-            fused_ok = self._fuse_eligible(idx, shards, filter_call)
+            filt = self._prepare(idx, filter_call)
+            fused_ok = self._fuse_eligible(shards, filt)
         self._note_route(fused_ok)
 
         def batch_fn(group):
             # same hook shape as the Count/Row fused paths: one stacked
             # dispatch for the whole locally-owned group
-            return [self._fused_topn_counts(idx, f, filter_call,
+            return [self._fused_topn_counts(idx, f, filt,
                                             tuple(group), opt=opt)]
 
         if fused_ok and not self._cluster_active(opt):
@@ -2022,27 +2034,28 @@ class Executor:
             pairs = pairs[:n]
         return pairs
 
-    def _fused_topn_counts(self, idx, f, filter_call,
+    def _fused_topn_counts(self, idx, f, filt,
                            shards: tuple[int, ...],
                            opt: ExecOptions | None = None
                            ) -> dict[int, int]:
         """All shards' TopN row counts, answered from the result cache
         when the scan (field matrix + filter leaves) is still at the
         stamped generations, else in ONE device dispatch — the per-
-        fragment TopNCache generalized to the whole cross-shard scan."""
+        fragment TopNCache generalized to the whole cross-shard scan.
+        ``filt`` is the filter tree as prepared (None: no filter)."""
         hit, val, probe = self._rc_get(
-            idx, "topn", shards, opt, tree=filter_call, extra=f.name,
+            idx, "topn", shards, opt, tree=filt, extra=f.name,
             gen_fields=((f, VIEW_STANDARD),))
         if hit:
             return dict(val)
-        totals = self._fused_topn_counts_uncached(idx, f, filter_call,
+        totals = self._fused_topn_counts_uncached(idx, f, filt,
                                                   shards, opt=opt)
         if probe is not None and self._rc_fill_ok(opt):
             self._rc_put(probe, opt, dict(totals),
                          resultcache.result_nbytes(totals))
         return totals
 
-    def _fused_topn_counts_uncached(self, idx, f, filter_call,
+    def _fused_topn_counts_uncached(self, idx, f, filt,
                                     shards: tuple[int, ...],
                                     opt: ExecOptions | None = None
                                     ) -> dict[int, int]:
@@ -2054,7 +2067,7 @@ class Executor:
         totals: dict[int, int] = {}
         if view is None:
             return totals
-        if filter_call is None:
+        if filt is None:
             # whole-scan short-circuit: every fragment's cache complete
             cached_parts = []
             for s in shards:
@@ -2085,14 +2098,14 @@ class Executor:
             if mat_dev is None:
                 return stack, None
             engine = self._raw_engine(self._query_mesh(opt))
-            if filter_call is not None:
-                filt = self._fused_eval(
-                    idx, filter_call, shards,
+            if filt is not None:
+                words = self._fused_eval(
+                    idx, filt, shards,
                     use_delta=opt is None or opt.delta,
                     mesh=self._query_mesh(opt))
                 return stack, _perfobs.launch(
                     engine, lambda: bm.row_counts_gathered(
-                        mat_dev, filt, pos_dev))
+                        mat_dev, words, pos_dev))
             return stack, _perfobs.launch(
                 engine, lambda: bm.row_counts(mat_dev))
 
@@ -2101,18 +2114,18 @@ class Executor:
         if counts is None:
             return totals
         with _observe.span("reduce"):
-            return self._topn_totals(view, shards, filter_call, gens,
+            return self._topn_totals(view, shards, filt, gens,
                                      row_ids, shard_pos, counts)
 
     @staticmethod
-    def _topn_totals(view, shards, filter_call, gens, row_ids,
+    def _topn_totals(view, shards, filt, gens, row_ids,
                      shard_pos, counts) -> dict[int, int]:
         """The host tail of the fused TopN scan: counts to the host,
         summed per row, and every fragment's TopN cache warmed."""
         totals: dict[int, int] = {}
         n_rows = len(row_ids)
         counts = np.asarray(counts, dtype=np.int64)[:n_rows]
-        if filter_call is not None:
+        if filt is not None:
             for rid, c in zip(row_ids, counts):
                 if c > 0:
                     rid = int(rid)
@@ -2243,6 +2256,7 @@ class Executor:
                 raise ExecutionError("GroupBy() children must be Rows queries")
         limit = call.uint_arg("limit")
         filter_call = call.call_arg("filter")
+        filt = self._prepare(idx, filter_call)
         shards = self._target_shards(idx, shards, opt)
         # result cache: a GroupBy's value depends on EVERY row of its
         # child fields, so the stamp covers the whole standard view of
@@ -2252,7 +2266,7 @@ class Executor:
         # key, so the post-limit result caches directly
         probe = None
         if not self._cluster_active(opt):
-            key_args = self._groupby_cache_args(idx, call, filter_call)
+            key_args = self._groupby_cache_args(idx, call, filt)
             if key_args is not None:
                 hit, val, probe = self._rc_get(idx, "groupby",
                                                tuple(shards), opt,
@@ -2298,15 +2312,14 @@ class Executor:
         # the cartesian walk is a per-shard map whatever the filter does
         self._note_route(False)
         engine = self._raw_engine(self._query_mesh(opt))
-        if (filter_call is not None
-                and self._fuse_eligible(idx, shards, filter_call)):
+        if filt is not None and self._fuse_eligible(shards, filt):
             if self._cluster_active(opt):
                 group = sorted(self.cluster.local_shards(idx.name, shards))
             else:
                 group = list(shards)
             if len(group) > 1:
                 shard_pos = {s: i for i, s in enumerate(group)}
-                filt_stack = self._fused_eval(idx, filter_call,
+                filt_stack = self._fused_eval(idx, filt,
                                               tuple(group),
                                               use_delta=opt.delta,
                                               mesh=self._query_mesh(opt))
@@ -2450,7 +2463,7 @@ class Executor:
                          resultcache.result_nbytes(out) * 2)
         return out
 
-    def _groupby_cache_args(self, idx, call: Call, filter_call):
+    def _groupby_cache_args(self, idx, call: Call, filt):
         """What keys and stamps a GroupBy in the result cache (the
         ``_rc_probe`` arguments), or None when ineligible: every
         child must be a plain standard-view Rows (time-view covers and
@@ -2475,12 +2488,11 @@ class Executor:
                                  child.uint_arg("column"),
                                  child.uint_arg("previous")))
             gen_fields.append((f, VIEW_STANDARD))
-        if filter_call is not None and not self._fused_supported(
-                idx, filter_call):
+        if filt is not None and not filt.fused:
             return None
         extra = (tuple(sig_children), call.uint_arg("limit"),
                  call.uint_arg("offset"))
-        return {"tree": filter_call, "extra": extra,
+        return {"tree": filt, "extra": extra,
                 "gen_fields": gen_fields}
 
     @staticmethod
@@ -2512,17 +2524,20 @@ class Executor:
         f = self._field(idx, fname)
         shards = self._target_shards(idx, shards, opt)
 
+        filt = self._prepare(idx,
+                             call.children[0] if call.children else None)
         fused_ok = self._fuse_eligible(
-            idx, shards, call.children[0] if call.children else None,
-            extra=f.options.type == FieldType.INT)
+            shards, filt, extra=f.options.type == FieldType.INT)
         if call.name == "Sum":
             def batch_fn(group):
-                return [self._fused_sum(idx, f, call, tuple(group),
+                return [self._fused_sum(idx, f, filt, tuple(group),
                                         use_delta=opt.delta,
                                         mesh=self._query_mesh(opt))]
         else:
             def batch_fn(group):
-                return [self._fused_extreme(idx, f, call, tuple(group),
+                return [self._fused_extreme(idx, f, filt,
+                                            call.name == "Min",
+                                            tuple(group),
                                             use_delta=opt.delta,
                                             mesh=self._query_mesh(opt))]
 
@@ -2566,7 +2581,7 @@ class Executor:
             out = getattr(out, reducer)(vc)
         return out
 
-    def _fused_sum(self, idx, f, call: Call, shards: tuple[int, ...],
+    def _fused_sum(self, idx, f, tree, shards: tuple[int, ...],
                    use_delta: bool = True, mesh=None) -> ValCount:
         """Sum over all shards in one stacked dispatch: plane counts from
         the [S, planes, W] BSI stack, exact assembly in Python ints
@@ -2577,8 +2592,8 @@ class Executor:
         with _observe.span("stage"):
             P = f.device_plane_stack(shards)
         filt = None
-        if call.children:
-            filt = self._fused_eval(idx, call.children[0], shards,
+        if tree is not None:
+            filt = self._fused_eval(idx, tree, shards,
                                     use_delta=use_delta, mesh=mesh)
 
         def scan():
@@ -2597,7 +2612,7 @@ class Executor:
                         for i, (p, n) in enumerate(zip(pos, neg)))
         return ValCount(total + total_count * f.options.base, total_count)
 
-    def _fused_extreme(self, idx, f, call: Call,
+    def _fused_extreme(self, idx, f, tree, is_min: bool,
                        shards: tuple[int, ...],
                        use_delta: bool = True, mesh=None) -> ValCount:
         """Min/Max over all shards from one stacked dispatch: the
@@ -2609,10 +2624,9 @@ class Executor:
         with _observe.span("stage"):
             P = f.device_plane_stack(shards)
         filt = None
-        if call.children:
-            filt = self._fused_eval(idx, call.children[0], shards,
+        if tree is not None:
+            filt = self._fused_eval(idx, tree, shards,
                                     use_delta=use_delta, mesh=mesh)
-        is_min = call.name == "Min"
         want = "min" if is_min else "max"
 
         def scan():
@@ -2658,13 +2672,14 @@ class Executor:
         shards = self._target_shards(idx, shards, opt)
         is_min = call.name == "MinRow"
         filter_call = call.children[0] if call.children else None
-        fused_ok = self._fuse_eligible(idx, shards, filter_call)
+        filt = self._prepare(idx, filter_call)
+        fused_ok = self._fuse_eligible(shards, filt)
 
         def batch_fn(group):
             # ONE stacked dispatch for the whole group (the TopN scan),
             # then a host argmin/argmax over the row totals — replaces
             # the per-row device round-trips of the old walk
-            totals = self._fused_topn_counts(idx, f, filter_call,
+            totals = self._fused_topn_counts(idx, f, filt,
                                              tuple(group), opt=opt)
             live = [r for r, c in totals.items() if c > 0]
             if not live:
@@ -3136,6 +3151,7 @@ class Executor:
         """Rewrite string keys to uint64 ids on a clone of the call tree
         (reference translateCalls, executor.go:2610).  Read-path misses
         become _Empty/_Noop sentinels; write paths create keys."""
+        _prep.note_walk()
         call = call.clone()
         return self._translate_call_rec(idx, call)
 
@@ -3184,18 +3200,25 @@ class Executor:
         v = call.args.get(arg_key)
         if not isinstance(v, str):
             return True
-        f = idx.field(arg_key)
-        if f is None:
-            raise ExecutionError(f"field not found: {arg_key}")
-        if not f.options.keys:
-            raise ExecutionError(
-                f"field {arg_key!r} does not use string keys (option keys=true)"
-            )
-        id = self._translate_one(idx, arg_key, v, create)
+        id = self._translate_row_id(idx, arg_key, v, create)
         if id is None:
             return False
         call.args[arg_key] = id
         return True
+
+    def _translate_row_id(self, idx, fname: str, key: str,
+                          create: bool = False):
+        """The id of a string row key on field ``fname``, or None when
+        nobody wrote that key and a read asks (the caller's
+        ``_Empty``)."""
+        f = idx.field(fname)
+        if f is None:
+            raise ExecutionError(f"field not found: {fname}")
+        if not f.options.keys:
+            raise ExecutionError(
+                f"field {fname!r} does not use string keys (option keys=true)"
+            )
+        return self._translate_one(idx, fname, key, create)
 
     def _translate_call_rec(self, idx, call: Call) -> Call:
         name = call.name

@@ -740,16 +740,25 @@ class ResidencyManager:
 
     def touch(self, cache: dict, key) -> None:
         """Mark an entry recently used (cache hit)."""
-        eid = self._id(cache, key)
+        self.touch_many(cache, (key,))
+
+    def touch_many(self, cache: dict, keys: list) -> None:
+        """:meth:`touch` for every entry one read hit, in the order
+        given, under one take of the lock: the LRU order afterwards is
+        that of the single touches."""
+        eids = [self._id(cache, key) for key in keys]
         with self._lock:
-            e = self._entries.pop(eid, None)
-            if e is not None:
-                self._entries[eid] = e
-                if eid in self._prefetched:
-                    # a query read an entry the prefetcher promoted:
-                    # the prediction was useful, count it once
-                    self._prefetched.discard(eid)
-                    self.prefetch_useful += 1
+            entries = self._entries
+            for eid in eids:
+                e = entries.pop(eid, None)
+                if e is not None:
+                    entries[eid] = e
+                    if eid in self._prefetched:
+                        # a query read an entry the prefetcher
+                        # promoted: the prediction was useful, count
+                        # it once
+                        self._prefetched.discard(eid)
+                        self.prefetch_useful += 1
 
     def forget(self, cache: dict, key) -> None:
         """Stop tracking an entry the owner removed itself (overwrite,

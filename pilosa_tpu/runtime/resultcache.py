@@ -19,7 +19,7 @@ identities ``(field, view, row)`` substituted at the slots, shard set)
 — to its result, stamped with the participating fragments' generation
 state: per (field, view) an aggregate ``(count, sum_gen, sum_uid,
 max_uid)`` over the shard set (change-detecting under the monotone
-uid/gen discipline — see ``Executor._rc_collect_gens``).
+uid/gen discipline — see ``Executor._rc_view_stamp``).
 **Invalidation is free**: every mutation path bumps the fragment
 generation (import, import-value, import-roaring, Set/Clear, Store,
 ClearRow, BSI set/clear-value — audited by tests/test_resultcache.py),
@@ -141,6 +141,12 @@ class Key:
     def __repr__(self) -> str:  # key_digest / debug stability
         return repr(self.k)
 
+    def digest(self) -> str:
+        """``key_digest`` of this key, for the flight record that
+        carries it: drawn when a record is first rendered, not when
+        the cache is probed."""
+        return key_digest(self)
+
 
 class _Entry:
     __slots__ = ("gens", "value", "nbytes", "t", "hits", "tenant")
@@ -212,7 +218,7 @@ class ResultCache:
         self.flight_joins = 0
         self.flight_served = 0
         #: probes whose tree was written in another operand order than
-        #: its key's (Executor._rc_sig)
+        #: its key's (parallel/prepared.py)
         self.reordered = 0
         # ---------------- per-tenant accounting ([tenants]) --------
         # tenant -> live bytes; tenant -> ordered key set (per-tenant
@@ -294,7 +300,8 @@ class ResultCache:
 
     def get(self, key: Any, gens: Any,
             wait_s: float = FLIGHT_WAIT_S,
-            tenant: str | None = None) -> tuple[bool, object]:
+            tenant: str | None = None,
+            reordered: bool = False) -> tuple[bool, object]:
         """(hit, value).  ``gens`` is the CURRENT generation tuple the
         caller just computed from the live fragments; a stored stamp
         that differs means some participating fragment mutated (or was
@@ -312,6 +319,11 @@ class ResultCache:
         budget = wait_s
         while True:
             with self._lock:
+                if reordered:
+                    # the key put this tree's operands in another order
+                    # than the query wrote them (``cache.reordered``)
+                    self.reordered += 1
+                    reordered = False
                 e = self._entries.get(key)
                 if e is not None:
                     if e.gens == gens and not (
@@ -449,10 +461,6 @@ class ResultCache:
                 if ve.tenant is not None:
                     self._tc_locked(ve.tenant)[3] += 1
             return True
-
-    def note_reordered(self) -> None:
-        with self._lock:
-            self.reordered += 1
 
     def _resolve_flight_locked(self, key: Any) -> None:
         fl = self._flights.pop(key, None)

@@ -97,6 +97,16 @@ def leaf_done(since: int) -> None:
         _counters["stage.fast" if fast else "stage.walk"] += 1
 
 
+def leaves_fast(n: int) -> None:
+    """``n`` leaves of one read whose every builder validated in O(1)
+    (``Field.stage_rows`` proves them good together): ``leaf_done``'s
+    accounting for all of them, under one take of the lock."""
+    _tally.leaves += n
+    _tally.fast_leaves += n
+    with _lock:
+        _counters["stage.fast"] += n
+
+
 def fast_leaves() -> int:
     """Leaves this thread has staged without a walk, ever: a ``stage``
     span notes the difference over its own extent as ``fast=``."""

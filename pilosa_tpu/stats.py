@@ -56,9 +56,82 @@ class StatsClient:
     def tags(self) -> list[str]:
         return []
 
+    def settle(self, ops: list) -> None:
+        """Write one read's collected metrics (:class:`Batch`): ``ops``
+        holds ``(client, kind, name, tags, value, exemplar)`` with kind
+        ``c`` (count), ``h`` (histogram) or ``t`` (timing, ns); the
+        client is the batch's own business.  The same values the
+        single calls would have written; a registry takes its lock
+        once for all of them."""
+        for _, kind, name, tags, value, exemplar in ops:
+            if kind == "c":
+                if tags:
+                    self.count_with_tags(name, value, 1.0, tags)
+                else:
+                    self.count(name, value)
+            elif kind == "h":
+                self.histogram(name, value, exemplar=exemplar)
+            else:
+                self.timing(name, value, exemplar=exemplar)
+
 
 #: Shared no-op (reference NopStatsClient)
 NOP = StatsClient()
+
+
+class Batch:
+    """One read's counters, histograms and timings, collected where
+    they arise (``Executor.execute``, the coalescer's flush and wait)
+    and written in ONE place at the end of the read's ``exec`` span
+    (``Executor.execute``): a registry's lock is taken once for them,
+    not once a metric.  Each entry names its client, since an
+    executor, its coalescer and its recorder may each have been given
+    their own; on a server they are one.  Owned by one thread."""
+
+    __slots__ = ("ops",)
+
+    def __init__(self) -> None:
+        self.ops: list[tuple] = []
+
+    def count(self, client: StatsClient, name: str, value: int = 1,
+              tags: tuple = ()) -> None:
+        self.ops.append((client, "c", name, tags, value, None))
+
+    def histogram(self, client: StatsClient, name: str, value: float,
+                  exemplar: str | None = None) -> None:
+        self.ops.append((client, "h", name, (), value, exemplar))
+
+    def timing(self, client: StatsClient, name: str, value_ns: float,
+               exemplar: str | None = None) -> None:
+        self.ops.append((client, "t", name, (), value_ns, exemplar))
+
+    def timer(self, client: StatsClient) -> "_BatchTimer":
+        """What ``observe.span(timer=(this, name))`` feeds a span's
+        duration to, in place of the client itself."""
+        return _BatchTimer(self, client)
+
+    def settle(self) -> None:
+        """Write everything collected, in order, and start over."""
+        ops, self.ops = self.ops, []
+        while ops:
+            client = ops[0][0]
+            rest = [op for op in ops if op[0] is not client]
+            if rest:  # more than one client: a test's, not a server's
+                ops = [op for op in ops if op[0] is client]
+            if client is not NOP:
+                client.settle(ops)
+            ops = rest
+
+
+class _BatchTimer:
+    __slots__ = ("batch", "client")
+
+    def __init__(self, batch: Batch, client: StatsClient) -> None:
+        self.batch = batch
+        self.client = client
+
+    def timing(self, name: str, value_ns: float) -> None:
+        self.batch.timing(self.client, name, value_ns)
 
 
 class MemStatsClient(StatsClient):
@@ -93,6 +166,9 @@ class MemStatsClient(StatsClient):
 
     def with_tags(self, *tags):
         return MemStatsClient(self._registry, (*self._tags, *tags))
+
+    def settle(self, ops):
+        self._registry.settle(ops, self._tags)
 
     def tags(self):
         return list(self._tags)
@@ -138,6 +214,10 @@ class MultiStatsClient(StatsClient):
 
     def with_tags(self, *tags):
         return MultiStatsClient([c.with_tags(*tags) for c in self.clients])
+
+    def settle(self, ops):
+        for c in self.clients:
+            c.settle(ops)
 
     def snapshot(self) -> dict:
         """Merged view across EVERY snapshot-capable backend, so a
@@ -252,6 +332,23 @@ class _Registry:
             if h is None:
                 h = self._hists[(name, tags)] = _Hist()
             h.observe(value, exemplar)
+
+    def settle(self, ops, base: tuple = ()) -> None:
+        """One read's metrics (``StatsClient.settle``'s ``ops``, each
+        under the client's ``base`` tags and its own) under one take
+        of the lock."""
+        counters, hists = self._counters, self._hists
+        with self._lock:
+            for _, kind, name, tags, value, exemplar in ops:
+                key = (name, tuple(sorted({*base, *tags})) if tags
+                       else base)
+                if kind == "c":
+                    counters[key] += value
+                    continue
+                h = hists.get(key)
+                if h is None:
+                    h = hists[key] = _Hist()
+                h.observe(value, exemplar)
 
     def snapshot(self) -> dict:
         with self._lock:

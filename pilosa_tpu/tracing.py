@@ -107,7 +107,15 @@ class ContextSpan(Span):
 
     def __init__(self, trace_id: str, span_id: str | None = None):
         self.trace_id = trace_id
-        self.span_id = span_id or uuid.uuid4().hex[:16]
+        self._span_id = span_id
+
+    @property
+    def span_id(self) -> str:
+        # drawn when an outgoing request first asks for it: a read that
+        # sends none (every single-node query) draws none
+        if self._span_id is None:
+            self._span_id = uuid.uuid4().hex[:16]
+        return self._span_id
 
 
 def inject_headers(span: Span | None = None) -> dict[str, str]:
@@ -335,18 +343,34 @@ def propagate(trace_id):
     No-ops (zero allocation) for a falsy id, and defers to an already-
     active traced span — an explicit propagate never clobbers real
     span parentage established by a recording tracer."""
+    cs = push_context(trace_id)
+    try:
+        # an active traced span keeps its parentage: it is what the
+        # scope runs under
+        yield cs if cs is not None or not trace_id else current_span()
+    finally:
+        if cs is not None:
+            _pop(cs)
+
+
+def push_context(trace_id) -> "ContextSpan | None":
+    """:func:`propagate`'s entry, for a scope that is an object of the
+    caller's own (``Executor.execute``'s): make ``trace_id`` this
+    thread's active trace and return the span to hand to
+    :func:`pop_context`, or None where nothing was pushed (a falsy id,
+    or a traced span is active already and keeps its parentage)."""
     if not trace_id:
-        yield None
-        return
+        return None
     span = current_span()
     if span is not None and span.trace_id:
-        yield span
-        return
+        return None
     cs = ContextSpan(normalize_trace_id(trace_id))
     _push(cs)
-    try:
-        yield cs
-    finally:
+    return cs
+
+
+def pop_context(cs: "ContextSpan | None") -> None:
+    if cs is not None:
         _pop(cs)
 
 

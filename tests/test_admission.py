@@ -350,7 +350,7 @@ class TestCoalescerDeadline:
         co = Coalescer(window_s=0.3, max_batch=8, enabled=True,
                        stats=stats)
         idx = ex.holder.index("i")
-        child = parse(QUERY).calls[0].children[0]
+        child = ex._prepare(idx, parse(QUERY).calls[0].children[0])
         shards = tuple(sorted(idx.available_shards()))
         results: dict = {}
         errs: dict = {}

@@ -9,7 +9,8 @@ share is the 28.86% that the server's own counter read at this seed
 tail of andnot, in one order it is 41.14%.  A later change to the
 generator that empties the mechanism (operands drawn in one order, say)
 moves these numbers without a chip run, and so does a change to
-``Executor._rc_sig`` that stops telling the two apart or starts
+the tree's one walk (``parallel/prepared.py``: its ``sig``) that stops
+telling the two apart or starts
 telling more apart.  Counts of keys, not device numbers."""
 
 from __future__ import annotations
@@ -77,8 +78,9 @@ def test_hit_share_by_the_generator_alone(seed):
 
 
 def test_the_programs_key_gives_the_reckoned_share(tmp_path):
-    """The same stream through ``Executor._rc_sig`` itself (an empty
-    ``demo`` field: a signature reads no data)."""
+    """The same stream through the program's own key, the ``sig`` the
+    tree's one walk leaves (an empty ``demo`` field: a signature reads
+    no data)."""
     holder = Holder(str(tmp_path / "h"))
     idx = holder.create_index("i")
     idx.create_field("demo")
@@ -86,7 +88,7 @@ def test_the_programs_key_gives_the_reckoned_share(tmp_path):
 
     def key(q):
         tree = parse(oracle.pql(q)).calls[0].children[0]
-        return ex._rc_sig(idx, tree, (0,), {}, [])
+        return ex._prepare(idx, tree).sig
 
     seed = 3000003721
     warm, window = _streams(seed)

@@ -574,18 +574,18 @@ class TestVMKinds:
         holder, ex, f = _mk_holder(rows, 2)
         idx = holder.index("i")
         call = parse("Count(Intersect(Row(f=0), Row(f=1)))").calls[0]
-        inner = call.children[0]
+        inner = ex._prepare(idx, call.children[0])
         shards = (0, 1)
         try:
             snap0 = dict(tape.counters())
             ct.configure(enabled=False)
-            assert ct.stage_vm(idx, inner, shards) is None
+            assert ct.stage_vm(inner, shards) is None
             ct.configure(enabled=True)
-            assert ct.stage_vm(idx, inner, shards, max_leaves=1) is None
-            assert ct.stage_vm(idx, inner, shards,
+            assert ct.stage_vm(inner, shards, max_leaves=1) is None
+            assert ct.stage_vm(inner, shards,
                                max_prefetch=1) is None
             # min-domain floor alone blows the budget: its own cell
-            assert ct.stage_vm(idx, inner, shards, min_domain=1 << 14,
+            assert ct.stage_vm(inner, shards, min_domain=1 << 14,
                                max_prefetch=1 << 12) is None
             # a kind byte with no decode arm (forward compatibility)
             leaf = f.device_container_leaf(0, shards)
@@ -594,7 +594,7 @@ class TestVMKinds:
                 if k is not None and len(k):
                     k[0] = 7
                     break
-            assert ct.stage_vm(idx, inner, shards) is None
+            assert ct.stage_vm(inner, shards) is None
             snap = tape.counters()
             for reason in ("disabled", "oversize", "max_prefetch",
                            "min_domain", "kind_unsupported"):

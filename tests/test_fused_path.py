@@ -100,13 +100,13 @@ class TestFusedEquivalence:
         idx.create_field("v", FieldOptions.int_field(0, 100))
         idx.create_field("t", FieldOptions.time_field("YMD"))
         parse = __import__("pilosa_tpu.pql", fromlist=["parse"]).parse
-        assert ex._fused_supported(
-            idx, parse("Shift(Row(f0=1), n=1)").calls[0])
-        assert ex._fused_supported(idx, parse(
+        assert ex._prepare(
+            idx, parse("Shift(Row(f0=1), n=1)").calls[0]).fused
+        assert ex._prepare(idx, parse(
             "Row(t=1, from='2020-01-01T00:00', to='2021-01-01T00:00')"
-        ).calls[0])
-        assert ex._fused_supported(idx, parse("Row(v > 3)").calls[0])
-        assert ex._fused_supported(idx, parse("Row(v >< [1, 5])").calls[0])
+        ).calls[0]).fused
+        assert ex._prepare(idx, parse("Row(v > 3)").calls[0]).fused
+        assert ex._prepare(idx, parse("Row(v >< [1, 5])").calls[0]).fused
 
     def test_fused_shift_matches_per_shard(self, ex):
         for q in ["Shift(Row(f0=1), n=1)",

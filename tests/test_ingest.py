@@ -441,7 +441,8 @@ def _nodelta(e, q):
 class TestExecutorDeltaFusion:
     def test_dfuse_staged_only_for_touched_rows(self, ex):
         e, idx, f = ex
-        call = parse("Count(Row(f=1))").calls[0].children[0]
+        call = e._prepare(
+            idx, parse("Count(Row(f=1))").calls[0].children[0])
         shards = tuple(range(N_SHARDS))
         shape, _ = e._fused_expr(idx, call, shards)
         assert "dfuse" not in repr(shape)
@@ -450,7 +451,8 @@ class TestExecutorDeltaFusion:
         assert "dfuse" in repr(shape)
         assert len(leaves) == 3  # base + set + clear stacks
         # an untouched row's tree stays the plain leaf (no recompile)
-        other = parse("Count(Row(f=2))").calls[0].children[0]
+        other = e._prepare(
+            idx, parse("Count(Row(f=2))").calls[0].children[0])
         shape2, _ = e._fused_expr(idx, other, shards)
         assert "dfuse" not in repr(shape2)
 

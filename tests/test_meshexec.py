@@ -164,7 +164,8 @@ class TestFusedMesh:
             from pilosa_tpu.pql import parse as pql_parse
 
             call = pql_parse(self.Q).calls[0].children[0]
-            shape, leaves = ex._fused_expr(holder.index("i"), call,
+            idx = holder.index("i")
+            shape, leaves = ex._fused_expr(idx, ex._prepare(idx, call),
                                            tuple(range(N_SHARDS)))
             m = meshexec.active_mesh()
             out = expr.evaluate(shape, leaves, counts=True, mesh=m)

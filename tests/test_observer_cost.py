@@ -87,6 +87,18 @@ def _calls(monkeypatch, owner, name) -> Calls:
     return c
 
 
+def _method_calls(monkeypatch, cls, name) -> Calls:
+    """Count the calls of a method of ``cls`` (a plain function in its
+    place, so that it still binds)."""
+    c = Calls(getattr(cls, name))
+
+    def method(self, *args, **kwargs):
+        return c(self, *args, **kwargs)
+
+    monkeypatch.setattr(cls, name, method)
+    return c
+
+
 def _lock(monkeypatch, owner, name) -> CountingLock:
     c = CountingLock(getattr(owner, name))
     monkeypatch.setattr(owner, name, c)
@@ -391,6 +403,84 @@ def test_recorder_on_lone_dense_count_clock_reads_and_spans(
     assert len(names) <= spans, names
     assert clock.n <= reads, clock.n
     assert locks == (2, 1, 0)
+
+
+#: what the bookkeeping of a served lone dense Count (two leaves of one
+#: field) costs since ISSUE 44, as counts: walks of its tree after the
+#: parser; takes of each owner's lock from ``Executor.execute`` down
+#: (the parent took the field's, the residency manager's, the access
+#: table's and the staging tally's once a LEAF, the registry's nine
+#: times and the program caches' six; the registry's second take is the
+#: recorder's latency histogram, which needs the end of ``exec``); and
+#: the scopes ``execute``
+#: enters with a server's defaults (the parent entered five).
+#: Ceilings: they may only fall.
+BOOKS = {
+    "walks": 1,
+    "locks": {"field": 1, "residency": 1, "access": 1, "tally": 1,
+              "cost table": 1, "stats": 2, "result cache": 0,
+              # the VM's offer declined, and a flush of one shape
+              "tape": 2, "containers": 1},
+    "scopes": {"execute": 1, "attach": 1, "no_tiers": 0, "tenant": 0,
+               "tracer span": 0},
+}
+
+
+def test_lone_dense_count_walks_once_and_settles_once(ex, monkeypatch):
+    """The read of ``seg-dense`` through the coalescer, warm: ONE walk
+    of its tree, one take of each owner's lock whatever the number of
+    leaves, and one scope around the execution."""
+    from pilosa_tpu import stagecheck, tracing
+    from pilosa_tpu.ops import containers as ct
+    from pilosa_tpu.ops import tape
+    from pilosa_tpu.parallel import executor as exmod
+    from pilosa_tpu.parallel import prepared
+    from pilosa_tpu.runtime import residency
+    stats = _stats.MemStatsClient()
+    ex.stats = ex.recorder.stats = stats
+    ex.coalescer = Coalescer(enabled=True, stats=stats)
+    assert _count(ex) > 0  # compiled through the coalescer, verdicts in
+    f = ex.holder.index("i").field("f")
+    before = stats.snapshot()
+    locks = {
+        "field": _lock(monkeypatch, f, "_lock"),
+        "residency": _lock(monkeypatch, residency.manager(), "_lock"),
+        "access": _lock(monkeypatch, observe.access_stats(), "_lock"),
+        "tally": _lock(monkeypatch, stagecheck, "_lock"),
+        "cost table": _lock(monkeypatch, perfobs, "_lock"),
+        "stats": _lock(monkeypatch, stats._registry, "_lock"),
+        "result cache": _lock(monkeypatch, resultcache.cache(), "_lock"),
+        "tape": _lock(monkeypatch, tape, "_lock"),
+        "containers": _lock(monkeypatch, ct, "_lock"),
+    }
+    scopes = {
+        "execute": _method_calls(monkeypatch, exmod._Scope, "__enter__"),
+        "attach": _method_calls(monkeypatch, observe.attach, "__enter__"),
+        "no_tiers": _method_calls(monkeypatch, residency.no_tiers,
+                                  "__init__"),
+        "tenant": _method_calls(monkeypatch, tenant.scope, "__init__"),
+        "tracer span": _method_calls(monkeypatch, tracing.Span,
+                                     "__enter__"),
+    }
+    walks = prepared.walks()
+    with bm.dispatch_counter() as dc:
+        assert _count(ex) > 0
+    assert dc.n == 1
+    assert prepared.walks() - walks <= BOOKS["walks"]
+    assert {k: v.n for k, v in locks.items()} == BOOKS["locks"]
+    assert {k: v.n for k, v in scopes.items()} == BOOKS["scopes"]
+    after = stats.snapshot()
+    moved = {k: after[k] - before.get(k, 0) for k in after
+             if isinstance(after[k], (int, float))
+             and after[k] != before.get(k, 0)}
+    # every book the read kept was written, once
+    assert moved == {"plan.prepared": 1, "plan.walks": 1,
+                     "query[call:Count,index:i]": 1,
+                     "coalescer.dispatches": 1, "coalescer.flush_idle": 1}
+    for hist in ("coalescer.batch_occupancy", "coalescer.shape_distinct",
+                 "coalescer.query_ns", "coalescer.launch_ns",
+                 "execute.Count", "pilosa_query_latency"):
+        assert (after[hist]["count"] - before[hist]["count"]) == 1, hist
 
 
 class _Sends:

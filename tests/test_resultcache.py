@@ -707,7 +707,6 @@ class TestProgramEvictionTelemetry:
                                  logger="pilosa_tpu.ops.expr"):
                 for shape in shapes:
                     expr._compiled(shape, False)
-                    expr._note_program_cache_pressure()
             # EXACT count: 4 shapes through a 2-slot cache = 2 popped
             # residents.  (misses - currsize inference would also say 2
             # here, but over-counts under racing same-shape builds or a

@@ -1,6 +1,6 @@
 """The result cache's key is canonical in operand order (ISSUE 42).
 
-``Executor._rc_sig`` sorts the operands of Union / Intersect / Xor, and
+The tree's one walk (``parallel/prepared.py``) sorts the operands of Union / Intersect / Xor, and
 those of Difference after its first, before they join the key, so the
 two written orders of one tree are one entry and one launch.  Every
 case goes through ``Executor.execute`` on a small holder and reads the
@@ -269,19 +269,21 @@ def test_mixed_siblings_sort_without_raising(ex, seed):
 
 
 def test_int_and_string_row_ids_sort_without_raising(ex):
-    """What ``_rc_sig`` can hold at one level and Python cannot
-    compare: an int row id beside a string one, a range value None
-    beside an int.  The order is total and the same for every written
+    """What a tree's ``sig`` can hold at one level and Python cannot
+    compare: a range value None beside an int (a string row id never
+    reaches a key: a key is translated or the tree does not fuse).  The order is total and the same for every written
     order."""
     from pilosa_tpu.pql import parse
 
     idx = ex.holder.index("i")
-    kids = ["Row(f=1)", 'Row(f="b")', "Row(v > 5)", "Row(v != null)",
+    kids = ["Row(f=1)", "Row(v != 5)", "Row(v > 5)", "Row(v != null)",
             "Row(v >< [1, 9])", "Not(Row(g=1))"]
     sigs = set()
     for perm in itertools.permutations(kids):
         call = parse(f"Intersect({', '.join(perm)})").calls[0]
-        sigs.add(ex._rc_sig(idx, call, (0, 1), {}, []))
+        tree = ex._prepare(idx, call)
+        assert tree.fused
+        sigs.add(tree.sig)
     assert len(sigs) == 1
     with pytest.raises(TypeError):
         sorted(next(iter(sigs))[1:])

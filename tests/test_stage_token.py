@@ -378,7 +378,7 @@ def test_warm_four_leaf_count_touches_no_fragment(monkeypatch):
     monkeypatch.setattr(
         View, "fragment",
         lambda self, shard: calls.append(shard) or fragment(self, shard))
-    child = parse(q).calls[0].children[0]
+    child = ex._prepare(idx, parse(q).calls[0].children[0])
     shape, leaves = ex._fused_expr(idx, child, shards)
     assert len(leaves) == 4 and calls == []
     assert ex.execute("i", q, opt=opt)[0] == 2 * n

@@ -20,7 +20,9 @@ result cache's key put a tree's operands in another order than the
 query wrote them: the probes whose `cache.probe` span notes
 `reordered`, and how many of them hit, beside `cache.hits`,
 `cache.reordered` and `cache.invalidations` over the window
-(`/debug/vars`; absent likewise).
+(`/debug/vars`; absent likewise), and the walks of call trees a
+prepared read took: `plan.walks` over `plan.prepared` (absent likewise;
+1.0 where every read is a fused Count).
 
 Then the gap table (`perfbench/gaps.py`): by route, every piece of the
 root that only an envelope covers, keyed (envelope, the span that ended
@@ -68,6 +70,10 @@ WAITING = ("unattributed_ms", "compile_stall_ms", "compile_cold_in_window",
 
 PHASES = ("http.parse", "stage", "coalesce.wait", "launch", "launch.stack",
           "launch.dispatch", "launch.ready", "reduce")
+#: the host's named pieces around them, a second table by route (the
+#: glue PR 43 named and the phases a cached read has too)
+GLUE = ("api.open", "pql.parse", "exec.open", "translate", "plan",
+        "cache.probe", "route", "cache.fill", "api.close", "exec")
 
 
 def route_of(profile: dict) -> str:
@@ -83,6 +89,7 @@ def route_of(profile: dict) -> str:
 
 def say_table(records) -> None:
     rows: dict[str, list] = {}
+    glue: dict[str, list] = {}
     why: dict[str, int] = {}
     fetched: dict[str, int] = {}
     fast = leaves = probes = moved = moved_hits = 0
@@ -102,6 +109,8 @@ def say_table(records) -> None:
                 if s.get("reordered"):
                     moved += 1
                     moved_hits += bool(s.get("hit"))
+        glue.setdefault(route_of(r.profile), []).append(
+            [sp.total(spans, name) for name in GLUE])
         rows.setdefault(route_of(r.profile), []).append(
             [sp.total(spans, "http.parse"), sp.self_total(spans, "stage")]
             + [sp.total(spans, name) for name in PHASES[2:]]
@@ -116,6 +125,10 @@ def say_table(records) -> None:
         med = [statistics.median(col) for col in zip(*vals)]
         harness.say(f"  {route}: {len(vals)} | "
                     + " | ".join(f"{m:.3f}" for m in med))
+    harness.say("named glue, medians in ms: " + " | ".join(GLUE))
+    for route, vals in sorted(glue.items(), key=lambda kv: -len(kv[1])):
+        harness.say(f"  {route}: " + " | ".join(
+            f"{statistics.median(col):.3f}" for col in zip(*vals)))
     flushes = sum(why.values())
     harness.say("flushes by why: " + ", ".join(
         f"{k} {n} ({100 * n / flushes:.1f}%)"
@@ -235,6 +248,12 @@ def measure(ses, *args):
             "engine.launches", "launch.fetched", "launch.refetched"))
         harness.say(f"launches: engine.launches +{launches} "
                     f"launch.fetched +{got} launch.refetched +{again}")
+    if "plan.prepared" in after:
+        walks, prepared = (after[k] - before.get(k, 0)
+                           for k in ("plan.walks", "plan.prepared"))
+        harness.say(f"plan: plan.walks +{walks} plan.prepared "
+                    f"+{prepared} ({walks / max(prepared, 1):.4f} walks "
+                    "a prepared read)")
     harness.say("cache: " + " ".join(
         f"{k} +{after[k] - before[k]}" for k in (
             "cache.hits", "cache.misses", "cache.reordered",
